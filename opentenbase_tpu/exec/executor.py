@@ -376,7 +376,11 @@ class Executor:
         (frozenset(batch.nulls)) is part of the compiled program."""
         pe = self._prep(e)
         if batch.lazy:
-            batch.ensure(_cols_of(pe))
+            # sorted: a set of names iterates in string-hash order, which
+            # differs from process to process — the gathers would be
+            # traced in another order, the program text would change, and
+            # the persistent compilation cache would miss after a restart
+            batch.ensure(sorted(_cols_of(pe)))
         return pe
 
     def _eval(self, e: E.Expr, batch: DBatch):
@@ -1196,15 +1200,27 @@ class Executor:
             elif ac.func == "avg":
                 scale = ac.arg.type.scale \
                     if ac.arg.type.kind == TypeKind.DECIMAL else 0
-                kinds.append("sumf")
-                inputs.append(b.col(name + "__s") if final
-                              else non_null(arg_arr, 0))
+                # integers and scaled decimals sum EXACTLY in int64 and
+                # become a float only at the final division: a device-
+                # float running sum is f32 on a TPU, and over SF1's
+                # 1.5M-row groups Q1's averages came back 3.8e-4 off
+                # (first chip run, CHANGES.md PR 22)
+                exact = ac.arg.type.kind != TypeKind.FLOAT64
+                kinds.append("sum" if exact else "sumf")
+                if final:
+                    inputs.append(b.col(name + "__s"))
+                elif exact:
+                    inputs.append(non_null(arg_arr, 0).astype(jnp.int64))
+                else:
+                    inputs.append(non_null(arg_arr, 0))
                 kinds.append("sum")
                 inputs.append(b.col(name + "__c") if final
                               else base.astype(jnp.int64))
                 if node.mode == "partial":
                     # components travel separately to the final agg
-                    out_specs.append((name + "__s", T.FLOAT64, None))
+                    out_specs.append((name + "__s",
+                                      T.INT64 if exact else T.FLOAT64,
+                                      None))
                     out_specs.append((name + "__c", T.INT64, None))
                 else:
                     out_specs.append((name, T.FLOAT64, ("avg", scale)))
@@ -1436,10 +1452,10 @@ class Executor:
             elif ac.func in ("sum", "avg"):
                 v = jnp.where(contrib, fval,
                               jnp.zeros((), fval.dtype))
-                kinds2 = ("sumf" if (is_float or ac.func == "avg")
-                          else "sum", "sum")
-                ins2 = (v.astype(device_float()) if ac.func == "avg"
-                        else v, contrib.astype(jnp.int64))
+                # non-float values sum exactly in int64 (AVG divides
+                # at the end; see _agg_inputs)
+                kinds2 = ("sumf" if is_float else "sum", "sum")
+                ins2 = (v, contrib.astype(jnp.int64))
             elif ac.func in ("min", "max"):
                 if is_float:
                     neutral = np.inf if ac.func == "min" else -np.inf

@@ -47,12 +47,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as PS
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from ..catalog.schema import NUM_SHARDS
 from ..catalog.types import TypeKind
@@ -798,13 +794,32 @@ class MeshRunner:
                 with obs_trace.span("gather", tier="mesh"):
                     for gi, (cols, valid, nulls) in out.items():
                         gmeta = meta[gi]
+                        # only the live rows go on to the CN fragment,
+                        # re-padded to their OWN size class: its eager
+                        # kernels (final agg, sort) then compile and run
+                        # at that size, not at the gather class — on a
+                        # v5e the 12-operand final sort of Q1's 4 groups
+                        # in a 65536-row buffer compiled in 443 s
+                        valid = np.asarray(valid)
+                        live = np.flatnonzero(valid)
+                        rows = next_pow2(len(live))
+                        if rows >= len(valid):
+                            live, rows = None, len(valid)
+
+                        def to_cn(a):
+                            a = np.asarray(a)
+                            if live is not None:
+                                t = np.zeros((rows,) + a.shape[1:],
+                                             a.dtype)
+                                t[:len(live)] = a[live]
+                                a = t
+                            return jnp.asarray(a)
+
                         result[gi] = DBatch(
-                            {n: jnp.asarray(np.asarray(a))
-                             for n, a in cols.items()},
-                            jnp.asarray(np.asarray(valid)),
+                            {n: to_cn(a) for n, a in cols.items()},
+                            to_cn(valid),
                             dict(gmeta["types"]), dict(gmeta["dicts"]),
-                            {n: jnp.asarray(np.asarray(a))
-                             for n, a in nulls.items()})
+                            {n: to_cn(a) for n, a in nulls.items()})
                 return result, included
             obs_trace.event("retrace", tier="mesh",
                             joins=len(over_jids),
@@ -1107,13 +1122,7 @@ class MeshRunner:
                                         PS(self.axis))
                                        for _ in gather_idx),
                                  PS(), PS(), PS()))
-        try:
-            smapped = shard_map(prog, check_vma=False, **kwargs)
-        except TypeError:
-            try:
-                smapped = shard_map(prog, check_rep=False, **kwargs)
-            except TypeError:
-                smapped = shard_map(prog, **kwargs)
+        smapped = shard_map(prog, check_vma=False, **kwargs)
         fn = jax.jit(smapped)
         plancache.MESH.put(prog_key, (fn, meta))
         self._programs[prog_key] = True

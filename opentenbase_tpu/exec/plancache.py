@@ -18,10 +18,11 @@ segfaulted XLA:CPU at a few hundred programs.  Four pieces:
    constant — or the same fragment over a different-but-same-size-class
    batch — reuses the compiled executable.
 
-2. Persistent compilation cache — enable_persistent_cache() points
-   jax_compilation_cache_dir under the cluster datadir so process
-   restarts, `ctl start`, and repeated bench runs skip the XLA compile
-   entirely (bench.py's warm2 arm measures it).
+2. Persistent compilation cache — enable_persistent_cache(), called
+   by the entry points (ctl, bench.py, chip_smoke.py), keeps compiled
+   programs where $JAX_COMPILATION_CACHE_DIR says, else at one fixed
+   path inside the checkout, so process restarts, `ctl start`, and
+   repeated runs skip the XLA compile entirely.
 
 3. AOT warmup — warm_async() runs lower-and-compile jobs on a
    background daemon thread, off the query path: PREPARE warms its
@@ -492,53 +493,39 @@ _METRICS.register_collector("plancache", _metrics_samples)
 # ---------------------------------------------------------------------------
 # persistent XLA compilation cache
 # ---------------------------------------------------------------------------
-_persist_dir: Optional[str] = None
+_REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point jax's compilation cache at `path` (or $OTB_COMPILE_CACHE)
-    so XLA compiles survive process restarts.  First caller wins — the
-    cache dir is process-global; later calls with a different path are
-    no-ops (the already-armed dir keeps serving)."""
-    global _persist_dir
-    env = os.environ.get("OTB_COMPILE_CACHE", "").strip()
-    if env.lower() in ("0", "off", "none"):
-        return None            # explicit operator opt-out
-    if env:
-        path = env             # env pins one dir across every caller
-    if not path:
-        return _persist_dir
-    if _persist_dir is not None:
-        return _persist_dir
+def enable_persistent_cache() -> str:
+    """Arm jax's persistent compilation cache so XLA compiles survive
+    process restarts; returns the directory in force.  Called by the
+    entry points (ctl, bench.py, chip_smoke.py), never as a side effect
+    of opening a session or a cluster.
+
+    Where $JAX_COMPILATION_CACHE_DIR is set, jax reads it itself and no
+    directory is set here; otherwise the cache lives at one fixed,
+    git-ignored path inside the checkout: a directory that moves between
+    runs (a datadir, a temp name) is a cache that never hits."""
     import jax
-    try:
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if not path:
+        path = _REPO_CACHE_DIR
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        # default thresholds skip sub-second/small programs — exactly
-        # the fragment programs this engine compiles by the hundreds
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-    except Exception:
-        return _persist_dir
-    try:
-        jax.config.update("jax_persistent_cache_enable_xla_caches",
-                          "all")
-    except Exception:
-        pass      # older jax: the executable cache alone still works
-    _persist_dir = path
-    return _persist_dir
+    # default thresholds skip sub-second/small programs — exactly the
+    # fragment programs this engine compiles by the hundreds
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
+    return path
 
 
 def persistent_cache_dir() -> Optional[str]:
-    return _persist_dir
-
-
-# $OTB_COMPILE_CACHE arms the cache for ANY deployment shape (bench
-# children, ad-hoc scripts, datadir-less sessions) without a call site
-if os.environ.get("OTB_COMPILE_CACHE", "").strip():
-    enable_persistent_cache()
+    """The compilation-cache directory jax has in force (None = off)."""
+    import jax
+    return jax.config.jax_compilation_cache_dir
 
 
 # ---------------------------------------------------------------------------

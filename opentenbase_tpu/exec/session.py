@@ -197,10 +197,6 @@ class LocalNode:
         }
         if datadir:
             os.makedirs(datadir, exist_ok=True)
-            # restarts of a durable node skip XLA compiles entirely:
-            # the compiled-program store lives next to the data
-            from .plancache import enable_persistent_cache
-            enable_persistent_cache(os.path.join(datadir, "xla-cache"))
             self._recover()
             self.wal = Wal(os.path.join(datadir, "wal.log"))
 

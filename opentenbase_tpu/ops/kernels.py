@@ -242,27 +242,24 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
         return perm, s_valid, boundary
 
     def exact(_):
-        if len(key_cols) > 1:
-            packed = jnp.zeros(n, dtype=jnp.int64)
-            for ki, mn, mx in zip(ints, mns, mxs):
-                packed = packed * (mx - mn + 1) + \
-                    jnp.where(valid, ki - mn, 0)
-            sort_keys = [invalid, packed, *ints]
-            key_off = 2
-        else:
-            sort_keys = [invalid, *ints]
-            key_off = 1
-        sorted_all = jax.lax.sort([*sort_keys, iota],
-                                  num_keys=len(sort_keys))
-        perm = sorted_all[-1]
-        s_keys = sorted_all[key_off:key_off + len(key_cols)]
+        # stable single-key passes, least significant key first and the
+        # invalid flag last: equal tuples end up adjacent, invalid rows
+        # last.  Each pass is a 2-operand sort — the TPU compiler's time
+        # for a sort grows with every operand and key, and this branch
+        # is compiled into every program even when the fast path runs
+        # (one 6-operand sort at 163840 rows was most of Q3's compile;
+        # CHANGES.md, PR 22)
+        perm = jnp.arange(n, dtype=jnp.int32)
+        for ki in (*reversed(ints), invalid):
+            perm = jax.lax.sort([ki[perm], perm], num_keys=1)[1]
         s_valid = valid[perm]
         first = jnp.arange(n) == 0
         differs = jnp.zeros(n, dtype=bool)
-        for k in s_keys:
+        for ki in ints:
+            k = ki[perm]
             differs = differs | (k != jnp.roll(k, 1))
         boundary = s_valid & (first | differs)
-        return perm, s_valid, boundary
+        return perm.astype(jnp.int64), s_valid, boundary
 
     perm, s_valid, boundary = jax.lax.cond(pack_ok, fast, exact, None)
     n_groups = jnp.sum(boundary)
@@ -491,14 +488,17 @@ def sort_rows(key_cols: tuple, valid, payload_cols: tuple,
     TEXT keys must be pre-mapped to order-preserving ranks by the operator
     (dictionary codes are not ordered)."""
     keys = [_order_key(k, d) for k, d in zip(key_cols, descs)]
-    operands = [~valid] + keys + list(payload_cols) + [valid]
-    out = jax.lax.sort(operands, num_keys=1 + len(keys))
-    payload = out[1 + len(keys):-1]
-    s_valid = out[-1]
+    # only the flag, the keys and a row index ride the (stable) variadic
+    # sort; payloads are gathered through the permutation afterwards.
+    # The TPU compiler's time for a sort grows with every operand — an
+    # 8-operand top-10 at 65536 rows was most of Q3's 251 s compile
+    # (CHANGES.md, PR 22) — and a LIMIT gathers only `limit` rows.
+    iota = jnp.arange(valid.shape[0], dtype=jnp.int32)
+    perm = jax.lax.sort([~valid] + keys + [iota],
+                        num_keys=1 + len(keys))[-1]
     if limit is not None:
-        payload = tuple(p[:limit] for p in payload)
-        s_valid = s_valid[:limit]
-    return tuple(payload), s_valid
+        perm = perm[:limit]
+    return tuple(p[perm] for p in payload_cols), valid[perm]
 
 
 # ---------------------------------------------------------------------------

@@ -714,10 +714,6 @@ class Cluster:
         gtm_path = os.path.join(datadir, "gtm.json") if datadir else None
         if datadir:
             os.makedirs(datadir, exist_ok=True)
-            # durable deployments keep compiled XLA programs next to the
-            # data: ctl start / process restarts skip the compile wall
-            from ..exec.plancache import enable_persistent_cache
-            enable_persistent_cache(os.path.join(datadir, "xla-cache"))
         self.gtm = GtmCore(gtm_path)
         catpath = os.path.join(datadir, "catalog.json") if datadir else None
         recovered = False
@@ -854,8 +850,6 @@ class Cluster:
         from ..net.dn_server import RemoteDataNode
         self = object.__new__(cls)
         self.datadir = os.path.dirname(catalog_path) or "."
-        from ..exec.plancache import enable_persistent_cache
-        enable_persistent_cache(os.path.join(self.datadir, "xla-cache"))
         self.catalog = Catalog.load(catalog_path) \
             if os.path.exists(catalog_path) else Catalog()
         if not self.catalog.datanodes():

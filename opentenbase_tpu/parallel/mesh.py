@@ -32,12 +32,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from ..utils.hashing import splitmix64_jax
 
@@ -94,17 +90,12 @@ def _pack_for_a2a(key_hash, arrs, valid, n_dev: int, bucket: int):
     return packed, mask, overflow
 
 
-def redistribute(mesh: Mesh, cols: dict, valid, key_col: str,
-                 bucket: int):  # otblint: sync-boundary
-    """Hash-redistribute sharded columns by cols[key_col] so each row
-    lands on its owner device: ONE all_to_all per column over ICI.
-
-    Returns (new cols dict, new valid, overflow_total).  overflow > 0
-    means some source had more than `bucket` rows for one destination —
-    re-run with a larger bucket (size-class growth)."""
+def redistribute_program(mesh: Mesh, names: list, key_col: str,
+                         bucket: int):
+    """The jitted shard_map exchange program behind redistribute():
+    fn(valid, *cols in `names` order) -> (mask, overflow_total, *cols)."""
     axis = mesh.axis_names[0]
     n_dev = mesh.devices.size
-    names = list(cols.keys())
 
     def prog(valid_l, *arrs):
         h = splitmix64_jax(arrs[names.index(key_col)].astype(jnp.uint64))
@@ -119,11 +110,23 @@ def redistribute(mesh: Mesh, cols: dict, valid, key_col: str,
                                    0, 0).reshape(-1)
         return (omask, jax.lax.psum(overflow, axis), *out)
 
-    smapped = shard_map(
+    return jax.jit(shard_map(
         prog, mesh=mesh,
         in_specs=(P(axis), *[P(axis)] * len(names)),
-        out_specs=(P(axis), P(), *[P(axis)] * len(names)))
-    res = jax.jit(smapped)(valid, *[cols[n] for n in names])
+        out_specs=(P(axis), P(), *[P(axis)] * len(names))))
+
+
+def redistribute(mesh: Mesh, cols: dict, valid, key_col: str,
+                 bucket: int):  # otblint: sync-boundary
+    """Hash-redistribute sharded columns by cols[key_col] so each row
+    lands on its owner device: ONE all_to_all per column over ICI.
+
+    Returns (new cols dict, new valid, overflow_total).  overflow > 0
+    means some source had more than `bucket` rows for one destination —
+    re-run with a larger bucket (size-class growth)."""
+    names = list(cols.keys())
+    res = redistribute_program(mesh, names, key_col, bucket)(
+        valid, *[cols[n] for n in names])
     omask, overflow = res[0], int(jax.device_get(res[1]))
     return dict(zip(names, res[2:])), omask, overflow
 

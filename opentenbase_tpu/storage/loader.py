@@ -1,8 +1,10 @@
 """Native bulk loader binding — C++ parse loop via ctypes, pandas fallback.
 
 Reference analog: commands/copy.c's C attribute parser.  The native library
-is built on demand with g++ from native/loader.cpp (no pip/pybind — plain
-ctypes over a C ABI); any failure falls back to the pandas C engine.
+is built on demand with g++ from native/loader.cpp into the git-ignored
+native/build/ (no pip/pybind — plain ctypes over a C ABI); a failed build
+falls back to the pandas C engine, which callers can see through
+native_available() and the SERVED counters.
 """
 
 from __future__ import annotations
@@ -25,7 +27,10 @@ _tried = False
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native", "loader.cpp")
-_SO = os.path.join(os.path.dirname(_SRC), "libotbloader.so")
+_SO = os.path.join(os.path.dirname(_SRC), "build", "libotbloader.so")
+
+# which parser served each load_tbl call (process-wide)
+SERVED = {"native": 0, "escaped": 0, "pandas": 0}  # guarded_by: _lock
 
 _KIND = {TypeKind.INT32: 0, TypeKind.INT64: 0, TypeKind.FLOAT64: 1,
          TypeKind.DECIMAL: 2, TypeKind.DATE: 3, TypeKind.TEXT: 4,
@@ -44,9 +49,14 @@ def _get_lib():  # otblint: disable=lock-blocking
         try:
             if (not os.path.exists(_SO)
                     or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
+                os.makedirs(os.path.dirname(_SO), exist_ok=True)
+                # build beside the target, then rename: concurrent
+                # processes (test workers) never load a half-written file
+                tmp = f"{_SO}.{os.getpid()}.tmp"
                 subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", _SO],
+                    ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", tmp],
                     check=True, capture_output=True, timeout=120)
+                os.replace(tmp, _SO)
             lib = ctypes.CDLL(_SO)
             lib.otb_count_rows.restype = ctypes.c_longlong
             lib.otb_count_rows.argtypes = [ctypes.c_char_p]
@@ -57,8 +67,8 @@ def _get_lib():  # otblint: disable=lock-blocking
                 ctypes.POINTER(ctypes.c_int),
                 ctypes.POINTER(ctypes.c_void_p), ctypes.c_longlong]
             _lib = lib
-        except Exception:
-            _lib = None
+        except (OSError, subprocess.SubprocessError):
+            _lib = None    # no compiler / failed build: pandas serves
         return _lib
 
 
@@ -74,14 +84,19 @@ def load_tbl(path: str, td: TableDef, columns: list[str],
     falls back to pandas otherwise (vectors, unbounded text, over-length
     values, missing compiler)."""
     out = _load_native(path, td, columns, delimiter)
+    served = "native"
     if out is None:
         # the native parser refuses backslashes (\N NULLs / escapes of
         # the COPY text format) along with its other unsupported inputs;
         # files carrying them take the escape-aware python path
         if _file_has_backslash(path):
             out = _load_text_escaped(path, td, columns, delimiter)
+            served = "escaped"
         else:
             out = _load_pandas(path, td, columns, delimiter)
+            served = "pandas"
+    with _lock:
+        SERVED[served] += 1
     return out
 
 

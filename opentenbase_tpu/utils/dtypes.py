@@ -46,14 +46,17 @@ _ENV_MODE = os.environ.get("OTB_DTYPE_MODE", "").strip().lower()
 # side of the first trace — never per-execution of a compiled program.
 def mode() -> str:  # otblint: disable=trace-purity
     """'x64' or 'tpu'.  Resolved once per process: OTB_DTYPE_MODE wins,
-    else follows the selected jax backend (utils/backend.connect)."""
+    else follows `jax.default_backend()` — whatever platform JAX picked
+    from its environment."""
     global _mode
     if _mode is None:
         if _ENV_MODE in ("x64", "tpu"):
             _mode = _ENV_MODE
         else:
-            from .backend import connect
-            _mode = "tpu" if connect() == "tpu" else "x64"
+            import jax
+            # a platform name (host string), not a traced value
+            _mode = ("tpu" if jax.default_backend() == "tpu"  # otblint: disable=host-sync
+                     else "x64")
     return _mode
 
 

@@ -85,6 +85,34 @@ class TestGroupedAggSort:
                for i in range(ng)}
         assert got == {k: tuple(v) for k, v in oracle.items()}
 
+    def test_exact_branch_full_range_keys(self):
+        """Keys spanning more than 62 bits cannot pack into one sort
+        word: the exact multi-pass branch groups them (mixed signs,
+        duplicates, invalid rows interleaved)."""
+        n = 1024
+        pool = rng.integers(np.iinfo(np.int64).min, np.iinfo(np.int64).max,
+                            12)
+        k1 = rng.choice(pool, n)
+        k2 = rng.choice(pool[:3], n)
+        k3 = rng.integers(-2, 2, n).astype(np.int64)
+        vals = rng.integers(0, 1000, n).astype(np.int64)
+        valid = rng.random(n) > 0.3
+        gkeys, (s, c, mx), ng = K.grouped_agg_sort(
+            tuple(jnp.asarray(k) for k in (k1, k2, k3)), jnp.asarray(valid),
+            (jnp.asarray(vals),) * 3, 256, ("sum", "count", "max"))
+        oracle = {}
+        for i in range(n):
+            if valid[i]:
+                acc = oracle.setdefault((k1[i], k2[i], k3[i]), [0, 0, 0])
+                acc[0] += vals[i]
+                acc[1] += 1
+                acc[2] = max(acc[2], vals[i])
+        ng = int(ng)
+        assert ng == len(oracle)
+        got = {tuple(int(g[i]) for g in gkeys):
+               (int(s[i]), int(c[i]), int(mx[i])) for i in range(ng)}
+        assert got == {k: tuple(v) for k, v in oracle.items()}
+
     def test_empty_input(self):
         gkeys, (s,), ng = K.grouped_agg_sort(
             (jnp.zeros(16, jnp.int64),), jnp.zeros(16, bool),

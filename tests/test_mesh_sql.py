@@ -123,3 +123,32 @@ class TestMeshTpch:
                "where k = tk and grp = id "
                "group by label order by rev desc")
         both(cs, sql)
+
+
+class TestGatherTrim:
+    def test_cn_fragment_runs_at_the_live_size_class(self, monkeypatch):
+        """Gathered fragment outputs reach the CN fragment re-padded to
+        the size class of their LIVE rows, not the (worst-case) gather
+        class: the CN's eager kernels compile at that size — on a v5e a
+        final sort over a 65536-row buffer compiled for minutes."""
+        from opentenbase_tpu.exec.mesh_exec import MeshRunner
+        s = ClusterSession(Cluster(n_datanodes=2))
+        s.execute("create table g (k bigint primary key, grp int, "
+                  "v bigint) distribute by shard(k)")
+        s.execute("insert into g values " + ", ".join(
+            f"({i}, {i % 3}, {i})" for i in range(5000)))
+        seen = []
+        orig = MeshRunner.run
+
+        def spy(self, *a, **kw):
+            result, included = orig(self, *a, **kw)
+            seen.extend(int(b.valid.shape[0]) for b in result.values())
+            return result, included
+
+        monkeypatch.setattr(MeshRunner, "run", spy)
+        s.execute("set enable_mesh_exchange = on")
+        got = s.query("select grp, count(*), sum(v) from g "
+                      "group by grp order by grp")
+        assert s.last_tier == "mesh"
+        assert [r[:2] for r in got] == [(0, 1667), (1, 1667), (2, 1666)]
+        assert seen and max(seen) == 256, seen   # 6 live rows, not 2x2560

@@ -1,0 +1,149 @@
+"""Main-path kernels compiled by the TPU's own compiler for a DESCRIBED
+v5e:2x2 (no chip attached): what the chip's compiler would refuse is
+refused here, at no chip time.  Nothing runs, so these say nothing about
+results or times.  Full SF1 size-class compile times are in CHANGES.md
+(PR 22); the 64-bit sort family needs minutes there, so the sort-based
+kernels are kept at a small class here.
+
+The topology is described inside a module-scoped fixture — never at
+import — and every compile happens in this process, in this one file
+(only one process at a time may load the TPU's library).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from opentenbase_tpu.ops import kernels as K
+from opentenbase_tpu.parallel import mesh as M
+from opentenbase_tpu.utils import dtypes
+
+HBM_BYTES = 16 << 30          # one v5e chip
+BIG = 1 << 23                 # lineitem at SF1, padded to its size class
+SMALL = 1 << 12               # sort-family kernels: seconds, not minutes
+
+I64, I32, BOOL = jnp.int64, jnp.int32, jnp.bool_
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — any failure means "skip"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def tpu_mode(topo):
+    """dtype mode forced to 'tpu' (the env override is read once at
+    import) and the persistent compile cache off around these compiles:
+    an entry written for a described chip cannot be read back here."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    mp = pytest.MonkeyPatch()
+    mp.setattr(dtypes, "_mode", "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+    mp.undo()
+    jax.clear_caches()          # no tpu-mode trace outlives this module
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, tpu_mode):
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,) if n else (), dtype, sharding=chip)
+    return shape
+
+
+def _compile(fn, *args):
+    compiled = fn.lower(*args).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes)
+    assert total < HBM_BYTES, f"{total} bytes do not fit one chip"
+    text = compiled.as_text()
+    assert "f64[" not in text, "float64 reached the chip's program"
+    return text
+
+
+def test_fused_q1_step_at_sf1(one_chip):
+    """The flagship fused TPC-H Q1 fragment (__graft_entry__.entry) at
+    lineitem's SF1 size class."""
+    import __graft_entry__ as G
+    step, _ = G.entry()
+    s = one_chip
+    cols = {"qty": s(BIG, I64), "price": s(BIG, I64), "disc": s(BIG, I64),
+            "tax": s(BIG, I64), "ship": s(BIG, I32), "rf": s(BIG, I32),
+            "ls": s(BIG, I32), "orderkey": s(BIG, I64)}
+    _compile(jax.jit(step), cols)
+
+
+def test_visibility_mask_at_sf1(one_chip):
+    s = one_chip
+    _compile(jax.jit(K.visibility_mask), s(BIG, I64), s(BIG, I64),
+             s(BIG, I64), s(BIG, I64), s(0, I64), s(0, I64), s(0, I64))
+
+
+def test_join_build(one_chip):
+    s = one_chip
+    _compile(K.join_build, s(SMALL, I64), s(SMALL, BOOL))
+
+
+def test_join_probe_counts(one_chip):
+    s = one_chip
+    _compile(K.join_probe_counts, s(SMALL, I64), s(4 * SMALL, I64),
+             s(4 * SMALL, BOOL))
+
+
+def test_join_expand(one_chip):
+    s = one_chip
+    _compile(jax.jit(lambda lo, counts, perm: K.join_expand(
+        lo, counts, perm, out_size=4 * SMALL)),
+        s(4 * SMALL, I32), s(4 * SMALL, I32), s(SMALL, I32))
+
+
+def test_grouped_agg_sort(one_chip):
+    s = one_chip
+    _compile(jax.jit(lambda k, v, a: K.grouped_agg_sort(
+        (k,), v, (a,), max_groups=SMALL, agg_kinds=("sum",))),
+        s(SMALL, I64), s(SMALL, BOOL), s(SMALL, I64))
+
+
+def test_sort_rows_top10(one_chip):
+    """Payloads stay out of the variadic sort (flag + 2 keys + row index
+    = 4 operands): the chip's compile time grows with every operand."""
+    import re
+    s = one_chip
+    fn = jax.jit(lambda k1, k2, v, p1, p2: K.sort_rows(
+        (k1, k2), v, (p1, p2), descs=(True, False), limit=10))
+    args = (s(SMALL, I64), s(SMALL, I32), s(SMALL, BOOL), s(SMALL, I64),
+            s(SMALL, I64))
+    _compile(fn, *args)
+    sorts = re.findall(r'stablehlo\.sort"?\(([^)]*)\)',
+                       fn.lower(*args).as_text())
+    assert [len(ops.split(",")) for ops in sorts] == [4], sorts
+
+
+def test_redistribute_is_one_all_to_all_program(topo, tpu_mode):
+    """The exchange of parallel/mesh.py on a 4-chip mesh, two int64
+    columns: the compiled text carries the ICI collective.  (Its pack
+    step sorts, so it too is kept small; 2^23 rows compiled in 37 s.)"""
+    mesh = Mesh(topo.devices, ("dn",))
+    sh = NamedSharding(mesh, P("dn"))
+
+    def s(dtype):
+        return jax.ShapeDtypeStruct((16 * SMALL,), dtype, sharding=sh)
+    text = _compile(M.redistribute_program(mesh, ["k", "v"], "k", SMALL),
+                    s(BOOL), s(I64), s(I64))
+    assert "all-to-all" in text
