@@ -38,7 +38,12 @@ obs-purity
     traced closure (spans would be captured at trace time, re-execute
     never, and their timers would read as zero — silently wrong).
     Spans/events belong at host boundaries only; eager-only regions
-    (``if not self._traced:`` branches) are exempt.
+    (``if not self._traced:`` branches) are exempt.  Two names draw the
+    line: ``jax.named_scope`` IS allowed inside a traced closure (it is
+    metadata on the ops being traced, the device-side half of the
+    naming), and ``jax.profiler.TraceAnnotation`` lives only in
+    ``obs/``, host side (every span enters one there; a second place
+    that writes on the profiler's clock is a second tracing system).
 
 net-deadline
     Network conversations in the RPC-bearing modules (net/, gtm/,
@@ -623,10 +628,19 @@ class ObsPurityPass:
     inside a jitted closure is captured once at trace time, never
     re-executed, and times nothing — and ``event()`` would mutate the
     thread-local stack mid-trace.  Flags (a) any call in the traced
-    closure resolving into ``<pkg>.obs.`` and (b) any ``obs`` module
-    function that becomes reachable from a traced root at all."""
+    closure resolving into ``<pkg>.obs.``, (b) any ``obs`` module
+    function that becomes reachable from a traced root at all, and (c)
+    any function outside ``<pkg>.obs`` that names a profiler annotation
+    (``jax.profiler.TraceAnnotation`` / ``StepTraceAnnotation``): the
+    profiler's clock is written from ``obs/`` only.  ``jax.named_scope``
+    inside a traced closure is not instrumentation in this sense — it
+    names the ops being traced and runs nothing — and is never flagged."""
 
     rule = "obs-purity"
+    ANNOTATIONS = ("jax.profiler.TraceAnnotation",
+                   "jax.profiler.StepTraceAnnotation",
+                   "jax._src.profiler.TraceAnnotation",
+                   "jax._src.profiler.StepTraceAnnotation")
 
     def __init__(self, project: Project, closure: TracedClosure):
         self.project = project
@@ -644,6 +658,18 @@ class ObsPurityPass:
                         f"part of the program")
                 continue
             self._check(fi, em)
+        for mi in self.project.modules.values():
+            if mi.dotted == self.obs_root or \
+                    mi.dotted.startswith(self.obs_root + "."):
+                continue
+            for fi in mi.functions.values():
+                for node in ast.walk(fi.node):
+                    if isinstance(node, (ast.Name, ast.Attribute)) and \
+                            _dotted(node, mi) in self.ANNOTATIONS:
+                        em.emit(fi, node.lineno,
+                                f"profiler annotation {_dotted(node, mi)} "
+                                f"outside {self.obs_root}: spans go "
+                                f"through obs.trace")
         return em.findings
 
     def _check(self, fi: FuncInfo, em: _Emitter):

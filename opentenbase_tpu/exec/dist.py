@@ -26,6 +26,7 @@ import dataclasses
 import time as _time
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -570,8 +571,11 @@ class DistExecutor:
             ctx = ExecContext({}, self.snapshot_ts, self.txid,
                               DeviceTableCache(),
                               params=dict(self.params))
+            # what the CN fragment compiles reads `otb.finalize` on the
+            # device, whatever step or kernel scope lies further in
             with obs_trace.span("execute", fragment=frag.index,
-                                where="cn"):
+                                where="cn"), \
+                    jax.named_scope("otb.finalize"):
                 out = Executor(ctx).exec_node(plan)
             if self.instrument:
                 self.stats[(frag.index, where)] = {

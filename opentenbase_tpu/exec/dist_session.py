@@ -105,6 +105,7 @@ class ClusterSession:
         # host fallbacks
         self.tier_counts: dict[str, int] = {}
         self.fallbacks: list[str] = []
+        self._last_trace = None     # see last_query_stats
         # named prepared statements + plan-cache telemetry
         self.prepared: dict[str, Prepared] = {}
         self.plan_cache_hits = 0
@@ -149,22 +150,31 @@ class ClusterSession:
 
     # ------------------------------------------------------------------
     def execute(self, sql: str) -> list[Result]:
+        """Parse and run one message's SQL under ONE statement trace,
+        so that the parse is inside it.  The trace is the CN server's
+        when the message came over the wire (it opened at the message's
+        arrival), else it opens here; each statement's
+        `_exec_retryable` joins it."""
         out = []
         self._cur_sql = sql.strip()
         self._arm_deadline()
         audit = getattr(self.cluster, "audit", None) \
             if self.cluster.gucs.get("audit_enabled", "off") == "on" \
             else None
-        for s in parse_sql(sql):
-            try:
-                r = self.execute_ast(s)
-            except Exception as e:
+        with obs_trace.trace_query(self._cur_sql[:200]):
+            with obs_trace.span("parse"):
+                stmts = parse_sql(sql)
+            for s in stmts:
+                try:
+                    r = self.execute_ast(s)
+                except Exception as e:
+                    if audit:
+                        audit.record(type(s).__name__, str(e), ok=False)
+                    raise
                 if audit:
-                    audit.record(type(s).__name__, str(e), ok=False)
-                raise
-            if audit:
-                audit.record(type(s).__name__, r.command, r.rowcount)
-            out.append(r)
+                    audit.record(type(s).__name__, r.command,
+                                 r.rowcount)
+                out.append(r)
         return out
 
     def query(self, sql: str) -> list[tuple]:
@@ -175,8 +185,12 @@ class ClusterSession:
         statement on this session (plan/stage/execute/exchange/
         finalize ms, tier, rows, bytes, pool hit counts) — the unified
         replacement for the last_tier/last_stage_ms attribute pairs.
-        Empty when OTB_TRACE=0."""
-        qt = getattr(self, "_last_trace", None)
+        Empty when OTB_TRACE=0.  The trace of a statement that came
+        over the wire is the CN server's and is still open while the
+        reply is on its way: read then, every span counts as of now and
+        `wire_ms` lacks part of the send; the finished trace in
+        `obs.trace.recent()` has it all."""
+        qt = self._last_trace
         return qt.summary() if qt is not None else {}
 
     def metrics_text(self) -> str:
@@ -1084,12 +1098,6 @@ class ClusterSession:
             qt.rows = len(rows)
             if ex.fallback_reason:
                 qt.root.attrs.setdefault("fallback", ex.fallback_reason)
-            for (fidx, where), st in sorted(
-                    ex.stats.items(),
-                    key=lambda kv: (kv[0][0], str(kv[0][1]))):
-                obs_trace.event("fragment", index=fidx,
-                                where=str(where), rows=st["rows"],
-                                ms=round(st["ms"], 3))
         return Result("SELECT", names=names, rows=rows,
                       rowcount=len(rows)), ex
 
@@ -1138,7 +1146,8 @@ class ClusterSession:
                 or c.gucs.get("enable_spm", "off") == "on" \
                 or c.gucs.get("spm_capture", "off") == "on":
             return None
-        prep, params = self._autoprep_template(stmt)
+        with obs_trace.span("autoprep"):
+            prep, params = self._autoprep_template(stmt)
         if prep is None or prep.mode != "plan" or params is None:
             return None     # normal plan path (original stmt)
         self.plan_cache_hits += 1

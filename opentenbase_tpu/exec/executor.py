@@ -305,6 +305,12 @@ class ExecContext:
     join_factors: Optional[dict] = None
 
 
+# the device-side name of each plan node's own ops (ops/kernels.py has
+# the vocabulary); a node not listed scans, filters or projects
+_NODE_SCOPES = {"Agg": "otb.agg", "HashJoin": "otb.join_expand",
+                "Sort": "otb.sort", "Window": "otb.sort"}
+
+
 class Executor:
     #: True inside a jit trace (exec/fused.py): host-sync shortcuts like
     #: count()-sized output classes switch to static worst-case shapes
@@ -420,10 +426,15 @@ class Executor:
             out = try_fused(self, node)
             if out is not None:
                 return out
-        m = getattr(self, f"_exec_{type(node).__name__.lower()}", None)
+        kind = type(node).__name__
+        m = getattr(self, f"_exec_{kind.lower()}", None)
         if m is None:
-            raise ExecError(f"no executor for {type(node).__name__}")
-        return m(node)
+            raise ExecError(f"no executor for {kind}")
+        # a program step: the ops this node traces (its expressions,
+        # masks, late gathers) carry the step's scope; a kernel's or a
+        # child node's own scope, further in, wins (ops/kernels.py)
+        with jax.named_scope(_NODE_SCOPES.get(kind, "otb.scan")):
+            return m(node)
 
     # ---- scan ----
     def _scan_base(self, table, alias: str, filters, outputs,
@@ -817,8 +828,10 @@ class Executor:
                                        right_keys=node.left_keys)
             left, right = right, left
 
-        lkey, lhashed, lcheck = self._join_key(node.left_keys, left)
-        rkey, rhashed, rcheck = self._join_key(node.right_keys, right)
+        with jax.named_scope("otb.join_probe"):
+            lkey, lhashed, lcheck = self._join_key(node.left_keys, left)
+        with jax.named_scope("otb.join_build"):
+            rkey, rhashed, rcheck = self._join_key(node.right_keys, right)
         skeys, perm = K.join_build(rkey, right.valid)
         lo, counts = K.join_probe_counts(skeys, lkey, left.valid)
 
@@ -1948,51 +1961,62 @@ def materialize(b: DBatch, names: Optional[list[str]] = None):
 
 
 def _materialize(b: DBatch, names: Optional[list[str]] = None):
+    """Three kinds of work, a span each: the lazy columns' gathers
+    (dispatched, not waited for), every device-to-host copy, and the
+    numpy-to-Python decode.  `finalize.fetch` adds no sync: it times the
+    copies this function has always made, so the first of them also
+    waits for the gathers and for whatever program produced the batch."""
     if names is None:
         names = b.names()
-    b.ensure(names)
-    valid = np.asarray(b.valid)
-    rows_idx = np.nonzero(valid)[0]
-    out_cols = []
-    for n in names:
-        arr = np.asarray(b.cols[n])[rows_idx]
-        t = b.types[n]
-        nullm = np.asarray(b.nulls[n])[rows_idx] if n in b.nulls else None
-        if t.kind == TypeKind.TEXT:
-            d = b.dicts.get(n, [])
-            if d:
-                table = np.asarray(list(d) + [None], dtype=object)
-                codes = np.where((arr >= 0) & (arr < len(d)), arr, len(d))
-                vals = table[codes].tolist()
-            else:
-                vals = [None] * len(arr)
-        elif t.kind == TypeKind.DECIMAL:
-            vals = (arr / 10 ** t.scale).tolist()
-        elif t.kind == TypeKind.DATE:
-            epoch = np.datetime64("1970-01-01", "D")
-            vals = [str(v) for v in
-                    (epoch + arr.astype("timedelta64[D]"))]
-        elif t.kind == TypeKind.BOOL:
-            vals = arr.astype(bool).tolist()
-        elif t.kind == TypeKind.FLOAT64:
-            vals = arr.astype(np.float64).tolist()
-        elif t.kind == TypeKind.VECTOR:
-            vals = [tuple(float(x) for x in v) for v in arr]
-        else:
-            vals = arr.astype(np.int64).tolist() \
-                if arr.dtype.kind in "iu" else arr.tolist()
-        if nullm is not None:
-            vals = [None if m else v for v, m in zip(vals, nullm)]
-        out_cols.append(vals)
-    rows = list(zip(*out_cols)) if out_cols else []
-    if obs_trace.active():
-        # nbytes is array metadata (never a device sync); the columns
-        # were just ensured, so this is the statement's true
-        # host-materialized footprint
-        nb = sum(int(getattr(b.cols[n], "nbytes", 0)) for n in names
-                 if n in b.cols)
-        obs_trace.annotate(rows=len(rows), bytes=int(nb))
+    with obs_trace.span("finalize.gather"):
+        b.ensure(names)
+    with obs_trace.span("finalize.fetch") as sp:
+        valid = np.asarray(b.valid)
+        cols = [np.asarray(b.cols[n]) for n in names]
+        nulls = {n: np.asarray(b.nulls[n]) for n in names if n in b.nulls}
+        col_bytes = sum(a.nbytes for a in cols)
+        sp.set(fetches=1 + len(cols) + len(nulls),
+               bytes=valid.nbytes + col_bytes
+               + sum(a.nbytes for a in nulls.values()))
+    with obs_trace.span("finalize.decode"):
+        rows_idx = np.nonzero(valid)[0]
+        out_cols = [
+            _decode_column(arr[rows_idx], b.types[n], b.dicts.get(n, []),
+                           nulls[n][rows_idx] if n in nulls else None)
+            for n, arr in zip(names, cols)]
+        rows = list(zip(*out_cols)) if out_cols else []
+    # the statement's host-materialized footprint (its columns, full
+    # width), on `finalize`
+    obs_trace.annotate(rows=len(rows), bytes=col_bytes)
     return names, rows
+
+
+def _decode_column(arr, t: SqlType, d, nullm) -> list:
+    """One fetched column (numpy, live rows only) as Python values."""
+    if t.kind == TypeKind.TEXT:
+        if d:
+            table = np.asarray(list(d) + [None], dtype=object)
+            codes = np.where((arr >= 0) & (arr < len(d)), arr, len(d))
+            vals = table[codes].tolist()
+        else:
+            vals = [None] * len(arr)
+    elif t.kind == TypeKind.DECIMAL:
+        vals = (arr / 10 ** t.scale).tolist()
+    elif t.kind == TypeKind.DATE:
+        epoch = np.datetime64("1970-01-01", "D")
+        vals = [str(v) for v in (epoch + arr.astype("timedelta64[D]"))]
+    elif t.kind == TypeKind.BOOL:
+        vals = arr.astype(bool).tolist()
+    elif t.kind == TypeKind.FLOAT64:
+        vals = arr.astype(np.float64).tolist()
+    elif t.kind == TypeKind.VECTOR:
+        vals = [tuple(float(x) for x in v) for v in arr]
+    else:
+        vals = arr.astype(np.int64).tolist() \
+            if arr.dtype.kind in "iu" else arr.tolist()
+    if nullm is not None:
+        vals = [None if m else v for v, m in zip(vals, nullm)]
+    return vals
 
 
 class InstrumentedExecutor(Executor):

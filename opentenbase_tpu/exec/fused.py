@@ -530,7 +530,10 @@ def _build_program(ctx, frag_plan, baked, traced_names, lits, factors,
     all_traced = list(traced_names) + [nm for nm, _v, _t in lits]
     join_factors = dict(factors)
 
-    def run(arrs_in, snap, txid, pvals, ns_in):
+    # the programs' names are what the device trace's "XLA Modules" line
+    # shows (jit_otb_fragment, jit_otb_fragment_batch; the mesh tier's
+    # is jit_otb_mesh)
+    def otb_fragment(arrs_in, snap, txid, pvals, ns_in):
         sub_params = dict(baked)
         for name, pv, t in zip(all_traced, pvals, traced_types):
             sub_params[name] = (pv, t)
@@ -544,7 +547,8 @@ def _build_program(ctx, frag_plan, baked, traced_names, lits, factors,
         b = sub.exec_node(frag_plan)
         # the single deferred materialization pass: program outputs are
         # real columns (only what survived projection/agg)
-        b.ensure_all()
+        with jax.named_scope("otb.finalize"):
+            b.ensure_all()
         meta["types"] = b.types
         meta["dicts"] = b.dicts
         meta["join_caps"] = tuple(
@@ -558,18 +562,18 @@ def _build_program(ctx, frag_plan, baked, traced_names, lits, factors,
         return b.cols, b.valid, b.nulls, join_req
 
     if not batch:
-        return jax.jit(run), meta
+        return jax.jit(otb_fragment), meta
 
-    def run_batch(arrs_in, snaps, txids, pvals, ns_in):
+    def otb_fragment_batch(arrs_in, snaps, txids, pvals, ns_in):
         # lax.map traces the fragment body ONCE and scans it over the
         # batch axis — one executable, one dispatch, K queries; staged
         # tables are closed over (shared), snapshot/txid/literals are
         # the mapped leaves so every query keeps its own visibility
         return jax.lax.map(
-            lambda q: run(arrs_in, q[0], q[1], q[2], ns_in),
+            lambda q: otb_fragment(arrs_in, q[0], q[1], q[2], ns_in),
             (snaps, txids, tuple(pvals)))
 
-    return jax.jit(run_batch), meta
+    return jax.jit(otb_fragment_batch), meta
 
 
 # ---------------------------------------------------------------------------

@@ -1031,7 +1031,7 @@ class MeshRunner:
 
         meta: dict = {"traced": traced_names}
 
-        def prog(snap, txn, *flat):
+        def otb_mesh(snap, txn, *flat):
             pvals = flat[:len(traced_names)]
             flat = flat[len(traced_names):]
             run_params = dict(baked)
@@ -1070,13 +1070,16 @@ class MeshRunner:
                     if ex.source_fragment != frag.index:
                         continue
                     if ex.kind == "redistribute":
-                        rb, over = self._a2a_batch(
-                            b, ex.keys, mults.get(ex.index, 1))
+                        with jax.named_scope("otb.exchange"):
+                            rb, over = self._a2a_batch(
+                                b, ex.keys, mults.get(ex.index, 1))
                         ex_batches[ex.index] = rb
                         meta["ex_order"].append(ex.index)
                         overflows.append(over)
                     elif ex.kind == "broadcast":
-                        ex_batches[ex.index] = self._broadcast_batch(b)
+                        with jax.named_scope("otb.exchange"):
+                            ex_batches[ex.index] = \
+                                self._broadcast_batch(b)
                     else:  # gather / gather_one: program output
                         ob = b
                         if ex.kind == "gather_one":
@@ -1085,8 +1088,11 @@ class MeshRunner:
                                 ob, valid=ob.valid & keep1)
                         meta[ex.index] = {"types": ob.types,
                                           "dicts": ob.dicts}
-                        cols, valid, nulls, gov = self._compact_local(
-                            ob, gathers[ex.index])
+                        # the gather exchange's device side: only
+                        # the compacted rows cross to the CN
+                        with jax.named_scope("otb.exchange"):
+                            cols, valid, nulls, gov = \
+                                self._compact_local(ob, gathers[ex.index])
                         spec = self._topk_spec(ob, ex)
                         if spec is not None:
                             cols, valid, nulls = self._topk_local(
@@ -1122,7 +1128,7 @@ class MeshRunner:
                                         PS(self.axis))
                                        for _ in gather_idx),
                                  PS(), PS(), PS()))
-        smapped = shard_map(prog, check_vma=False, **kwargs)
+        smapped = shard_map(otb_mesh, check_vma=False, **kwargs)
         fn = jax.jit(smapped)
         plancache.MESH.put(prog_key, (fn, meta))
         self._programs[prog_key] = True

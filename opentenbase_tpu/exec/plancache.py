@@ -610,12 +610,9 @@ def get_or_build(holder, attr: str, stmt, gen, build,
     if hit is not None and hit[0] == gen:
         with _LOCK:
             PLAN.hits += 1
-        if obs_trace.ENABLED:
-            obs_trace.event("plancache", hit=True)
         return hit[1]
     with _LOCK:
         PLAN.misses += 1
-    obs_trace.event("plancache", hit=False)
     obj = build()
     if obj is None or not cacheable(obj):
         return obj

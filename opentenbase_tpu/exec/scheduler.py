@@ -68,6 +68,7 @@ from .fused import (batch_signature, finish_fused_batch,
                     launch_fused_batch, run_fused_batch,
                     stage_fused_batch)
 from .session import Result
+from ..obs import trace as obs_trace
 from ..obs import xray as obs_xray
 from ..utils import locks, snapcheck
 
@@ -304,12 +305,15 @@ class _Item:
                  "t_submit", "ev", "error", "results", "batch",
                  "out_names", "is_write", "deadline", "cancel_event",
                  "lk", "cv", "detached", "degraded", "lits",
-                 "snap", "vkey", "aid")
+                 "snap", "vkey", "aid", "trace")
 
     def __init__(self, session, sql):
         self.session = session
         self.sql = sql
         self.aid = 0              # otb_stat_activity handle (0 = none)
+        # the statement's trace, open on the submitting (connection)
+        # thread: the serial lane's dispatcher thread adopts it
+        self.trace = obs_trace.current_trace()
         self.planned = None
         self.info = None          # FragSig when batchable, else None
         self.group = "default"
@@ -1203,28 +1207,32 @@ class Scheduler:
                                     thread=threading.get_ident())
             try:
                 shield.serial_guard(item.lits)
-                if item.is_write:
-                    with self._write_lock:
-                        # may-acquire: storage.store.TableStore._mu
-                        # may-acquire: storage.lockmgr.LockManager._cond
-                        # may-acquire: obs.metrics.Registry._lock
-                        # may-acquire: obs.metrics.metric._lock
-                        # may-acquire: obs.trace._LOCK
+                # this thread's spans go under the statement's trace,
+                # which the connection thread opened and will finish
+                with obs_trace.adopt(item.trace):
+                    if item.is_write:
+                        with self._write_lock:
+                            # may-acquire: storage.store.TableStore._mu
+                            # may-acquire: storage.lockmgr.LockManager._cond
+                            # may-acquire: obs.metrics.Registry._lock
+                            # may-acquire: obs.metrics.metric._lock
+                            # may-acquire: obs.trace._LOCK
+                            res = item.session.execute(item.sql)
+                    else:
+                        if item.info is not None:
+                            # versions BEFORE execution, GTS tag AFTER:
+                            # a DML racing the statement leaves the
+                            # entry keyed at a tuple that no longer
+                            # matches, and the late tag only narrows
+                            # servability
+                            item.vkey = item.info.version_key()
                         res = item.session.execute(item.sql)
-                else:
-                    if item.info is not None:
-                        # versions BEFORE execution, GTS tag AFTER: a
-                        # DML racing the statement leaves the entry
-                        # keyed at a tuple that no longer matches, and
-                        # the late tag only narrows servability
-                        item.vkey = item.info.version_key()
-                    res = item.session.execute(item.sql)
-                    if item.info is not None and len(res) == 1 \
-                            and res[0].command == "SELECT":
-                        node = item.session.node
-                        item.snap = node.gts.next_gts()
-                        self._cache_result(item, res[0].names,
-                                           res[0].rows)
+                        if item.info is not None and len(res) == 1 \
+                                and res[0].command == "SELECT":
+                            node = item.session.node
+                            item.snap = node.gts.next_gts()
+                            self._cache_result(item, res[0].names,
+                                               res[0].rows)
                 self._complete(item, results=res)
             except BaseException as e:
                 self._complete(item, error=e)

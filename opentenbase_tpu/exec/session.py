@@ -437,32 +437,38 @@ class Session:
         return time.monotonic() + ms / 1e3 if ms > 0 else None
 
     def execute(self, sql: str) -> list[Result]:
+        """Parse and run one message's SQL under ONE statement trace
+        (the CN server's when the message came over the wire, else it
+        opens here), so that the parse is inside it."""
         out = []
         self._cur_sql = sql.strip()
         deadline = self._stmt_deadline()
-        for s in parse_sql(sql):
-            self._check_interrupts(deadline)
-            if self.txn is not None and self.txn_aborted \
-                    and not isinstance(s, A.TxnStmt) \
-                    and not (isinstance(s, A.SavepointStmt)
-                             and s.op == "rollback_to"):
-                raise ExecError(
-                    "current transaction is aborted, commands ignored "
-                    "until end of transaction block")
-            try:
-                out.append(self._exec_retryable(s))
-            except Exception:
-                if self.txn is not None and not self.txn_aborted \
-                        and not isinstance(s, A.TxnStmt):
-                    self.txn_aborted = True
-                    if not self.txn.savepoints:
-                        # abort NOW: writes revert and locks release
-                        # immediately (PG: AbortCurrentTransaction).
-                        # With live savepoints the txn must survive
-                        # for ROLLBACK TO, so only poison it.
-                        self._abort(self.txn)
-                        self.txn.rolled_back = True
-                raise
+        with obs_trace.trace_query(self._cur_sql[:200]):
+            with obs_trace.span("parse"):
+                stmts = parse_sql(sql)
+            for s in stmts:
+                self._check_interrupts(deadline)
+                if self.txn is not None and self.txn_aborted \
+                        and not isinstance(s, A.TxnStmt) \
+                        and not (isinstance(s, A.SavepointStmt)
+                                 and s.op == "rollback_to"):
+                    raise ExecError(
+                        "current transaction is aborted, commands ignored "
+                        "until end of transaction block")
+                try:
+                    out.append(self._exec_retryable(s))
+                except Exception:
+                    if self.txn is not None and not self.txn_aborted \
+                            and not isinstance(s, A.TxnStmt):
+                        self.txn_aborted = True
+                        if not self.txn.savepoints:
+                            # abort NOW: writes revert and locks release
+                            # immediately (PG: AbortCurrentTransaction).
+                            # With live savepoints the txn must survive
+                            # for ROLLBACK TO, so only poison it.
+                            self._abort(self.txn)
+                            self.txn.rolled_back = True
+                    raise
         return out
 
     def _exec_retryable(self, s: A.Node) -> Result:

@@ -30,9 +30,11 @@ import secrets
 import socket
 import socketserver
 import threading
+import time
 from typing import Optional
 
-from .wire import recv_msg, send_msg
+from .wire import decode_msg, recv_frame, recv_msg, send_msg
+from ..obs import trace as obs_trace
 from ..obs import xray
 from ..utils import locks
 
@@ -183,52 +185,9 @@ class CnServer:
                 # be silently dropped instead of canceling the
                 # statement it targeted.
                 sess.cancel_event.clear()
-                msg = recv_msg(sock)
-                if msg is None or msg.get("op") == "terminate":
+                blob = recv_frame(sock)
+                if blob is None or not self._serve(sess, sock, blob):
                     return
-                if msg.get("op") == "metrics":
-                    # Prometheus text exposition over the wire (the
-                    # reference exposes pg_stat_* via SQL only; a
-                    # scrape endpoint is table stakes here)
-                    try:
-                        send_msg(sock, {"ok": sess.metrics_text()})
-                    except Exception as e:
-                        send_msg(sock, {"error":
-                                        f"{type(e).__name__}: {e}"})
-                    continue
-                if msg.get("op") == "workshare":
-                    # cross-query work-sharing counters (otbshare):
-                    # shared-stream fan-in and result-cache hit/miss/
-                    # invalidation totals, queryable out-of-band so a
-                    # load driver can prove sublinearity without a
-                    # full metrics scrape
-                    from ..exec import share as workshare
-                    send_msg(sock, {"ok": workshare.stats_snapshot()})
-                    continue
-                if msg.get("op") == "flight":
-                    # flight-recorder retrieval: the ringed postmortem
-                    # bundles (quarantine / timeout / breaker / OOM),
-                    # so an operator can pull forensics off a live CN
-                    # without filesystem access
-                    from ..obs import xray
-                    send_msg(sock, {"ok": xray.flights()})
-                    continue
-                if msg.get("op") != "query":
-                    send_msg(sock, {"error":
-                                    f"unknown op {msg.get('op')!r}"})
-                    continue
-                try:
-                    if self.scheduler is not None:
-                        results = self.scheduler.run(sess, msg["sql"])
-                    else:
-                        results = sess.execute(msg["sql"])
-                    send_msg(sock, {"ok": [
-                        {"command": r.command, "names": r.names,
-                         "rows": r.rows, "rowcount": r.rowcount,
-                         "text": r.text} for r in results]})
-                except Exception as e:   # statement error: report, keep
-                    send_msg(sock, {"error":
-                                    f"{type(e).__name__}: {e}"})
         finally:
             # disconnect aborts any open transaction (reference:
             # backend exit path, AbortOutOfAnyTransaction)
@@ -239,6 +198,71 @@ class CnServer:
                 pass
             with self._lock:
                 self._sessions.pop(pid, None)
+
+    def _serve(self, sess, sock: socket.socket, blob: bytes) -> bool:
+        """One message of a session, whose frame has arrived; False
+        when the client is done.  A statement's trace starts at the
+        frame's arrival, not after the parse, so that the wire's share
+        (decode, and the reply's encode and send) and the parse are
+        inside it; with a scheduler the dispatcher thread adopts it
+        (one trace a statement, whichever thread runs it)."""
+        t0 = time.perf_counter()
+        msg = decode_msg(blob)
+        recv_ms = (time.perf_counter() - t0) * 1e3
+        op = msg.get("op")
+        if op != "query":
+            return self._serve_op(sess, sock, op)
+        sig = str(msg.get("sql", "")).strip()[:200]
+        with obs_trace.trace_query(sig, since=t0) as qt:
+            obs_trace.record("wire.recv", recv_ms, bytes=len(blob))
+            try:
+                if self.scheduler is not None:
+                    results = self.scheduler.run(sess, msg["sql"])
+                else:
+                    results = sess.execute(msg["sql"])
+                reply = {"ok": [
+                    {"command": r.command, "names": r.names,
+                     "rows": r.rows, "rowcount": r.rowcount,
+                     "text": r.text} for r in results]}
+            except Exception as e:   # statement error: report, keep
+                reply = {"error": f"{type(e).__name__}: {e}"}
+                if qt is not None:
+                    qt.failed = True
+            # the client has its reply before this span ends: a
+            # `last_query_stats()` read then sees the trace still open
+            with obs_trace.span("wire.send") as sp:
+                sp.set(bytes=send_msg(sock, reply))
+        return True
+
+    @staticmethod
+    def _serve_op(sess, sock: socket.socket, op) -> bool:
+        if op == "terminate":
+            return False
+        try:
+            if op == "metrics":
+                # Prometheus text exposition over the wire (the
+                # reference exposes pg_stat_* via SQL only; a scrape
+                # endpoint is table stakes here)
+                reply = {"ok": sess.metrics_text()}
+            elif op == "workshare":
+                # cross-query work-sharing counters (otbshare):
+                # shared-stream fan-in and result-cache hit/miss/
+                # invalidation totals, queryable out-of-band so a load
+                # driver can prove sublinearity without a full scrape
+                from ..exec import share as workshare
+                reply = {"ok": workshare.stats_snapshot()}
+            elif op == "flight":
+                # flight-recorder retrieval: the ringed postmortem
+                # bundles (quarantine / timeout / breaker / OOM), so an
+                # operator can pull forensics off a live CN without
+                # filesystem access
+                reply = {"ok": xray.flights()}
+            else:
+                reply = {"error": f"unknown op {op!r}"}
+        except Exception as e:
+            reply = {"error": f"{type(e).__name__}: {e}"}
+        send_msg(sock, reply)
+        return True
 
 
 # ---------------------------------------------------------------------------

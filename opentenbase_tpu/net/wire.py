@@ -71,14 +71,16 @@ def _apply_send_fault(sock: socket.socket, point: str,
     return bytes(bad)
 
 
-def send_msg(sock: socket.socket, obj, fault: str = None) -> None:
+def send_msg(sock: socket.socket, obj, fault: str = None) -> int:
+    """Encode and send one frame; returns the payload's bytes."""
     blob = pickle.dumps(obj, protocol=4)
     hdr = _HDR.pack(len(blob), zlib.crc32(blob))
     if fault is not None:
         blob = _apply_send_fault(sock, fault, blob)
         if blob is None:
-            return              # dropped: peer waits, deadline fires
+            return 0            # dropped: peer waits, deadline fires
     sock.sendall(hdr + blob)
+    return len(blob)
 
 
 def _recv_exact(sock: socket.socket, n: int, expect: bool = False) -> bytes:
@@ -99,10 +101,23 @@ def _recv_exact(sock: socket.socket, n: int, expect: bool = False) -> bytes:
 
 def recv_msg(sock: socket.socket, expect_reply: bool = False,
              fault: str = None):
-    """Receive one frame.  Returns None on a clean close at a message
-    boundary — unless ``expect_reply`` is set, in which case a close is
-    a WireError (the caller just sent a request and is owed an answer).
-    """
+    """Receive one frame and decode it.  Returns None on a clean close
+    at a message boundary — unless ``expect_reply`` is set, in which
+    case a close is a WireError (the caller just sent a request and is
+    owed an answer)."""
+    blob = recv_frame(sock, expect_reply, fault)
+    return None if blob is None else decode_msg(blob)
+
+
+def decode_msg(blob: bytes):
+    return pickle.loads(blob)
+
+
+def recv_frame(sock: socket.socket, expect_reply: bool = False,
+               fault: str = None):
+    """Wait for one whole frame and return its checked payload, still
+    encoded (None on a clean close, as `recv_msg`): a server that times
+    a statement from the arrival of its message decodes it itself."""
     if fault is not None:
         act = FI.wire_action(fault)
         if act is not None:
@@ -126,4 +141,4 @@ def recv_msg(sock: socket.socket, expect_reply: bool = False,
     blob = _recv_exact(sock, length, expect=True)
     if zlib.crc32(blob) != crc:
         raise WireError("message checksum mismatch")
-    return pickle.loads(blob)
+    return blob

@@ -17,6 +17,11 @@ Design constraints (TPU-first):
 - Thread-safe by construction: the active span stack is thread-local
   (each CN server session is a thread); only trace FINISH touches the
   shared ring, under ``_LOCK``.
+- One clock with the device: every real span lives inside a
+  ``jax.profiler.TraceAnnotation("otb:<name>")``, so a profiler session
+  started by anyone records the span on the host plane of the same
+  ``.xplane.pb`` as the device's "XLA Ops".  Outside a session the
+  annotation is a no-op in the profiler; there is no flag.
 
 Env vars: ``OTB_TRACE`` (default on), ``OTB_SLOW_MS`` (slow-query log
 threshold, 0 = off), ``OTB_TRACE_RING`` (recent-trace ring size).
@@ -26,12 +31,16 @@ from __future__ import annotations
 
 import itertools
 import json
+import operator
 import os
 import sys
 import threading
 import time
 from collections import deque
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
+
 from ..utils import locks
 
 ENABLED = os.environ.get("OTB_TRACE", "1").strip().lower() \
@@ -52,40 +61,93 @@ _SEED = os.urandom(4).hex()
 
 # canonical phase names summarized per query (otb_stat_query columns)
 PHASES = ("plan", "stage", "execute", "exchange", "finalize")
+# every span name `summary()` sums into a `*_ms` key
+_SUMMED = PHASES + ("wire.recv", "wire.send", "parse", "autoprep", "wait",
+                    "finalize.gather", "finalize.fetch", "finalize.decode")
+_BY_START = operator.attrgetter("t0_ms")
 
 
 class Span:
-    """One timed region.  Context-manager protocol only: creation via
-    ``span()`` attaches nothing — ``__enter__`` pushes onto the
-    thread's stack, ``__exit__`` pops and stamps ``ms``."""
+    """One timed region: name, start (``t0_ms``, an offset from the
+    statement root's start), duration (``ms``) and, through the tree,
+    its parent.  Context-manager protocol only: creation via ``span()``
+    attaches nothing — ``__enter__`` pushes onto the thread's stack and
+    enters the profiler annotation, ``__exit__`` stamps ``ms`` and
+    pops."""
 
-    __slots__ = ("name", "attrs", "ms", "children", "_t0")
+    __slots__ = ("name", "attrs", "t0_ms", "ms", "children", "_t0",
+                 "_ann")
 
     def __init__(self, name: str, attrs: Optional[dict] = None):
         self.name = name
         self.attrs = attrs if attrs else {}
+        self.t0_ms = 0.0
         self.ms = 0.0
         self.children: list = []
         self._t0 = 0.0
+        self._ann = None
 
     def set(self, **kw) -> "Span":
         self.attrs.update(kw)
         return self
 
+    def _start(self, st: list, since: Optional[float] = None) -> None:
+        """Stamp the start (`since`, a `time.perf_counter()` reading,
+        where the caller took it earlier), as an offset from the root's
+        when there is one below, and enter the profiler's annotation."""
+        self._ann = TraceAnnotation("otb:" + self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter() if since is None else since
+        if st:
+            self.t0_ms = (self._t0 - st[0]._t0) * 1e3
+        st.append(self)
+
+    def _stop(self) -> None:
+        self.ms = (time.perf_counter() - self._t0) * 1e3
+        self._ann.__exit__(None, None, None)
+        self._ann = None
+
+    def elapsed_ms(self) -> float:
+        """`ms` once the span has ended; until then, the time so far."""
+        if self._ann is None:
+            return self.ms
+        return (time.perf_counter() - self._t0) * 1e3
+
     def __enter__(self) -> "Span":
         st = _TLS.stack
         st[-1].children.append(self)
-        st.append(self)
-        self._t0 = time.perf_counter()
+        self._start(st)
         return self
 
     def __exit__(self, et, ev, tb):
-        self.ms = (time.perf_counter() - self._t0) * 1e3
+        self._stop()
         _TLS.stack.pop()
         return False
 
+    def shift(self, by_ms: float) -> None:
+        """Move the subtree on the statement's timeline (a grafted
+        remote subtree arrives with offsets from its own root)."""
+        work = [self]
+        while work:
+            s = work.pop()
+            s.t0_ms += by_ms
+            work.extend(s.children)
+
+    def self_ms(self) -> float:
+        """The span's duration less the part its children cover."""
+        ms = self.elapsed_ms()
+        covered, end = 0.0, self.t0_ms
+        for c in sorted(self.children, key=_BY_START):
+            lo = max(c.t0_ms, end)
+            hi = min(c.t0_ms + c.elapsed_ms(), self.t0_ms + ms)
+            if hi > lo:
+                covered += hi - lo
+                end = hi
+        return max(ms - covered, 0.0)
+
     def to_dict(self) -> dict:
-        d = {"name": self.name, "ms": round(self.ms, 4)}
+        d = {"name": self.name, "t0_ms": round(self.t0_ms, 4),
+             "ms": round(self.ms, 4)}
         if self.attrs:
             d["attrs"] = dict(self.attrs)
         if self.children:
@@ -116,6 +178,11 @@ def _stack() -> Optional[list]:
     return getattr(_TLS, "stack", None)
 
 
+def _now_ms(st: list) -> float:
+    """Now, on the timeline of the statement whose root is st[0]."""
+    return (time.perf_counter() - st[0]._t0) * 1e3
+
+
 def active() -> bool:
     """True when a query trace is open on THIS thread."""
     return bool(getattr(_TLS, "stack", None))
@@ -135,7 +202,20 @@ def event(name: str, **attrs) -> None:
     """Record a zero-duration child (cache hit/miss, retrace, upload)."""
     st = getattr(_TLS, "stack", None)
     if st:
-        st[-1].children.append(Span(name, attrs))
+        ev = Span(name, attrs)
+        ev.t0_ms = _now_ms(st)
+        st[-1].children.append(ev)
+
+
+def record(name: str, ms: float, **attrs) -> None:
+    """Record a child that has just ENDED and took `ms` (a wait the
+    caller timed itself: obs/xray.py), on the statement's timeline."""
+    st = getattr(_TLS, "stack", None)
+    if st:
+        sp = Span(name, attrs)
+        sp.ms = ms
+        sp.t0_ms = _now_ms(st) - ms
+        st[-1].children.append(sp)
 
 
 def annotate(**kw) -> None:
@@ -159,13 +239,12 @@ def push_root(name: str, **attrs) -> Span:
     sp = Span(name, attrs)
     if st:                           # nested server op: ride the stack
         st[-1].children.append(sp)
-    st.append(sp)
-    sp._t0 = time.perf_counter()
+    sp._start(st)
     return sp
 
 
 def pop_root(sp: Span) -> Span:
-    sp.ms = (time.perf_counter() - sp._t0) * 1e3
+    sp._stop()
     st = getattr(_TLS, "stack", None)
     if st and st[-1] is sp:
         st.pop()
@@ -175,6 +254,7 @@ def pop_root(sp: Span) -> Span:
 def span_from_dict(d: dict) -> Span:
     """Rehydrate a shipped span subtree (inverse of Span.to_dict)."""
     sp = Span(str(d.get("name", "?")), dict(d.get("attrs") or {}))
+    sp.t0_ms = float(d.get("t0_ms") or 0.0)
     sp.ms = float(d.get("ms") or 0.0)
     sp.children = [span_from_dict(c) for c in d.get("children") or ()]
     return sp
@@ -186,14 +266,18 @@ def graft(d: dict) -> None:
     outermost-only rule never double-counts them)."""
     st = getattr(_TLS, "stack", None)
     if st:
-        st[-1].children.append(span_from_dict(d))
+        sp = span_from_dict(d)
+        # the subtree's offsets count from its own root: lay it so that
+        # it ends now, when its reply has arrived
+        sp.shift(_now_ms(st) - sp.ms - sp.t0_ms)
+        st[-1].children.append(sp)
 
 
 class QueryTrace:
     """One statement's span tree plus identity/summary fields."""
 
     __slots__ = ("qid", "signature", "root", "tier", "rows", "started",
-                 "trace_id")
+                 "trace_id", "failed")
 
     def __init__(self, signature: str):
         self.qid = next(_IDS)
@@ -203,10 +287,13 @@ class QueryTrace:
         self.rows = 0
         self.started = time.time()
         self.trace_id = f"{_SEED}-{self.qid:x}"
+        # set by whoever catches the statement's error INSIDE the trace
+        # (the CN server, which still has the reply to send)
+        self.failed = False
 
     @property
     def total_ms(self) -> float:
-        return self.root.ms
+        return self.root.elapsed_ms()
 
     def phase_ms(self, name: str) -> float:
         """Sum of ms over spans named `name`, counting only the
@@ -243,7 +330,60 @@ class QueryTrace:
             work.extend(s.children)
         return n
 
+    def self_ms(self, name: str) -> float:
+        """Self time of the spans named `name` (outermost of nested
+        same-name runs, as `phase_ms`): duration less what their
+        children cover.  `self_ms("query")` is the root's: what no
+        span of the statement accounts for."""
+        if name == self.root.name:
+            return self.root.self_ms()
+        total = 0.0
+        work = [self.root]
+        while work:
+            s = work.pop()
+            for c in s.children:
+                if c.name == name:
+                    total += c.self_ms()
+                else:
+                    work.append(c)
+        return total
+
     def summary(self) -> dict:
+        """The statement's numbers by key (`last_query_stats()`, the
+        `otb_stat_query` view, the slow log).  One walk of the tree:
+        `<span>_ms` sums the outermost spans of that name.  Of a trace
+        still open (the CN server's, read by `last_query_stats()` while
+        the reply is on its way) every span reads as of now."""
+        ms = dict.fromkeys(_SUMMED, 0.0)
+        staged = materialized = overlapped = 0.0
+        fetches = fetch_bytes = 0
+        hits = misses = 0
+        work = [(self.root, ())]
+        while work:
+            s, inside = work.pop()
+            name = s.name
+            inside_c = inside
+            if name in ms and name not in inside:
+                ms[name] += s.elapsed_ms()
+                inside_c = inside + (name,)     # nested runs count once
+            a = s.attrs
+            if a:
+                if name == "upload":
+                    staged += a.get("bytes", 0) or 0
+                elif name == "finalize":
+                    materialized += a.get("bytes", 0) or 0
+                elif name == "stage":
+                    overlapped += a.get("overlapped_ms", 0) or 0
+                elif name == "finalize.fetch":
+                    fetches += a.get("fetches", 0) or 0
+                    fetch_bytes += a.get("bytes", 0) or 0
+                elif name == "pool":
+                    if a.get("hit") is True:
+                        hits += 1
+                    elif a.get("hit") is False:
+                        misses += 1
+            for c in s.children:
+                work.append((c, inside_c))
         d = {
             "qid": self.qid,
             "trace_id": self.trace_id,
@@ -251,22 +391,29 @@ class QueryTrace:
             "tier": self.tier or "single",
             "total_ms": self.total_ms,
             "rows": self.rows,
-            "bytes_staged": int(self.sum_attr("upload", "bytes")),
-            "bytes_materialized": int(
-                self.sum_attr("finalize", "bytes")),
-            "pool_hits": self.count_events("pool", hit=True),
-            "pool_misses": self.count_events("pool", hit=False),
+            "bytes_staged": int(staged),
+            "bytes_materialized": int(materialized),
+            "pool_hits": hits,
+            "pool_misses": misses,
         }
         for ph in PHASES:
-            d[f"{ph}_ms"] = self.phase_ms(ph)
+            d[f"{ph}_ms"] = ms[ph]
         # overlap-adjusted staging (otbpipe): wall time the dispatch
         # path actually WAITED on staging.  Producers mark staging that
         # ran behind device compute with an `overlapped_ms` attr on the
         # stage span; without overlap this equals stage_ms, so the new
         # pipeline doesn't misread as staging going to zero.
-        d["stage_wait_ms"] = max(
-            d["stage_ms"] - self.sum_attr("stage", "overlapped_ms"),
-            0.0)
+        d["stage_wait_ms"] = max(d["stage_ms"] - overlapped, 0.0)
+        d["wire_ms"] = ms["wire.recv"] + ms["wire.send"]
+        d["parse_ms"] = ms["parse"]
+        d["autoprep_ms"] = ms["autoprep"]
+        d["wait_ms"] = ms["wait"]
+        d["finalize_gather_ms"] = ms["finalize.gather"]
+        d["finalize_fetch_ms"] = ms["finalize.fetch"]
+        d["finalize_decode_ms"] = ms["finalize.decode"]
+        d["finalize_fetches"] = int(fetches)
+        d["finalize_fetch_bytes"] = int(fetch_bytes)
+        d["unattributed_ms"] = self.root.self_ms()
         return d
 
     def to_dict(self) -> dict:
@@ -280,10 +427,11 @@ class _TraceCtx:
     already active on this thread (nested statements — triggers, the
     EXPLAIN ANALYZE inner run — ride the enclosing trace)."""
 
-    __slots__ = ("signature", "owned")
+    __slots__ = ("signature", "since", "owned")
 
-    def __init__(self, signature: str):
+    def __init__(self, signature: str, since: Optional[float]):
         self.signature = signature
+        self.since = since
         self.owned = None
 
     def __enter__(self) -> Optional[QueryTrace]:
@@ -297,17 +445,16 @@ class _TraceCtx:
         qt = QueryTrace(self.signature)
         self.owned = qt
         _TLS.trace = qt
-        st.append(qt.root)
-        qt.root._t0 = time.perf_counter()
+        qt.root._start(st, self.since)
         return qt
 
     def __exit__(self, et, ev, tb):
         qt = self.owned
         if qt is not None:
-            qt.root.ms = (time.perf_counter() - qt.root._t0) * 1e3
+            qt.root._stop()
             _TLS.stack.pop()
             _TLS.trace = None
-            _finish(qt, failed=et is not None)
+            _finish(qt, failed=qt.failed or et is not None)
         return False
 
 
@@ -326,10 +473,40 @@ class _NullTraceCtx:
 _NULL_CTX = _NullTraceCtx()
 
 
-def trace_query(signature: str = ""):
+def trace_query(signature: str = "", since: Optional[float] = None):
+    """The statement's trace; `since` (a `time.perf_counter()` reading)
+    backdates its start to when the caller says the statement began:
+    the CN server decodes a message before it knows it is one."""
     if not ENABLED:
         return _NULL_CTX
-    return _TraceCtx(signature)
+    return _TraceCtx(signature, since)
+
+
+class _Adopted:
+    """`adopt` context: this thread's spans go under another thread's
+    open trace (the serving tier runs a statement on a dispatcher
+    thread while its connection thread, which owns the trace, waits).
+    Both threads append children to the root; the owner finishes it."""
+
+    __slots__ = ("qt", "_prev")
+
+    def __init__(self, qt: QueryTrace):
+        self.qt = qt
+        self._prev = None
+
+    def __enter__(self) -> QueryTrace:
+        self._prev = (_stack(), getattr(_TLS, "trace", None))
+        _TLS.stack = [self.qt.root]
+        _TLS.trace = self.qt
+        return self.qt
+
+    def __exit__(self, et, ev, tb):
+        _TLS.stack, _TLS.trace = self._prev
+        return False
+
+
+def adopt(qt: Optional[QueryTrace]):
+    return _Adopted(qt) if qt is not None else _NULL_CTX
 
 
 def current_trace() -> Optional[QueryTrace]:

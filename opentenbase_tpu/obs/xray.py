@@ -24,7 +24,9 @@ Three legs:
   register plus cumulative log-bucket histograms (``otb_wait_ms``
   {event=...}) over the engine's named blocking points.  The register
   joins the activity view (below) so a live query shows WHAT it is
-  waiting on, not just that it is slow.
+  waiting on, not just that it is slow.  A wait is also a ``wait``
+  child span of the statement it delayed (``wait_ms`` of its summary)
+  and an ``otb:wait:<event>`` annotation on the profiler's clock.
 
 - **Flight recorder** (`flight`): guard-rail trips (quarantine,
   statement timeout, OOM downshift, breaker trip, poison bisection)
@@ -52,6 +54,9 @@ import threading
 import time
 from collections import deque
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
+
 from ..utils import locks
 from . import trace as _trace
 from .metrics import REGISTRY
@@ -311,14 +316,18 @@ _EVENTS: set = set()                    # guarded_by: _WLOCK
 
 
 class _WaitCtx:
-    __slots__ = ("event", "_t0", "_prev")
+    __slots__ = ("event", "_t0", "_prev", "_ann")
 
     def __init__(self, event: str):
         self.event = event
         self._t0 = 0.0
         self._prev = None
+        self._ann = TraceAnnotation("otb:wait:" + event) \
+            if _trace.ENABLED else None
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         ident = threading.get_ident()
         with _WLOCK:
@@ -335,9 +344,13 @@ class _WaitCtx:
                 _WAITING.pop(ident, None)
             else:
                 _WAITING[ident] = self._prev
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         REGISTRY.histogram("otb_wait_ms", event=self.event).observe(ms)
-        if _trace.active():
-            _trace.event("wait", event=self.event, ms=round(ms, 4))
+        if self._prev is None:
+            # the statement it delayed: a `wait` child on its timeline
+            # (a wait nested in another is covered by the outer one)
+            _trace.record("wait", ms, event=self.event)
         return False
 
 
@@ -356,8 +369,7 @@ def mark(event: str, **detail) -> None:
     with _WLOCK:
         _EVENTS.add(event)
     REGISTRY.histogram("otb_wait_ms", event=event).observe(0.0)
-    if _trace.active():
-        _trace.event("wait", event=event, ms=0.0)
+    _trace.record("wait", 0.0, event=event)
 
 
 def wait_rows() -> list:

@@ -32,11 +32,29 @@ from ..utils.dtypes import device_float
 INT64_MAX = np.int64(2**63 - 1)
 
 
+def _scoped(scope: str):
+    """Run the kernel's body under `jax.named_scope(scope)`: every op it
+    traces carries the scope in its metadata, so a device trace can say
+    which kernel an XLA op came from, whatever number XLA gave it.  One
+    flat vocabulary, shared with the program steps of exec/: otb.scan,
+    otb.agg, otb.join_build, otb.join_probe, otb.join_expand, otb.sort,
+    otb.exchange, otb.finalize.  A scope is metadata only: no op, no
+    cost at run time, no part of the persistent cache's key."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(scope):
+                return fn(*args, **kwargs)
+        return scoped
+    return deco
+
+
 # ---------------------------------------------------------------------------
 # visibility (reference: HeapTupleSatisfiesMVCC, utils/time/tqual.c:1203 —
 # per-tuple; here one vector compare fused into the scan)
 # ---------------------------------------------------------------------------
 
+@_scoped("otb.scan")
 def visibility_mask(xmin_ts, xmax_ts, xmin_txid, xmax_txid,
                     snap_ts, my_txid, aborted_ts):
     ins = (xmin_ts <= snap_ts) | ((xmin_txid == my_txid)
@@ -52,6 +70,7 @@ def visibility_mask(xmin_ts, xmax_ts, xmin_txid, xmax_txid,
 # final projection needs it.
 # ---------------------------------------------------------------------------
 
+@_scoped("otb.scan")
 def decode_column(codes, aux, family: str):
     """Decode one encoded staged column.  `aux` carries the original
     dtype (pack marker / FOR reference lo-1 / dict LUT); code 0 is the
@@ -66,6 +85,7 @@ def decode_column(codes, aux, family: str):
     return jnp.take(aux, codes.astype(jnp.int32))
 
 
+@_scoped("otb.scan")
 def cmp_on_codes(codes, aux, family: str, op: str, lit):
     """Predicate eval on encoded values without the padding select:
     live rows carry code >= 1 (for) or the exact value (pack), so
@@ -100,6 +120,7 @@ def cmp_on_codes(codes, aux, family: str, op: str, lit):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("out_size",))
+@_scoped("otb.scan")
 def compact(mask, cols: tuple, out_size: int):
     """Returns (count, gathered_cols) where gathered_cols are [out_size]
     arrays holding the selected rows first (padding rows repeat row 0 and
@@ -130,6 +151,7 @@ def _masked_for(kind: str, vals, valid):
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "agg_kinds"))
+@_scoped("otb.agg")
 def grouped_agg_dense(group_id, valid, agg_inputs: tuple,
                       num_groups: int, agg_kinds: tuple):
     """Aggregate with a precomputed dense group id in [0, num_groups).
@@ -175,6 +197,7 @@ def _sortable_int(k, valid):
 
 
 @functools.partial(jax.jit, static_argnames=("max_groups", "agg_kinds"))
+@_scoped("otb.agg")
 def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
                      max_groups: int, agg_kinds: tuple):
     """General grouped aggregation: sort on the key columns (invalid
@@ -300,6 +323,7 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
 # ---------------------------------------------------------------------------
 
 @jax.jit
+@_scoped("otb.join_build")
 def join_build(build_keys, build_valid):
     """Sort the build side; invalid rows get key INT64_MAX so they sort
     last and can never match a (clamped) probe key.
@@ -343,6 +367,7 @@ def join_build(build_keys, build_valid):
 
 
 @jax.jit
+@_scoped("otb.join_probe")
 def join_probe_counts(sorted_keys, probe_keys, probe_valid):
     """Per-probe-row match range in the sorted build side.
 
@@ -412,6 +437,7 @@ def join_probe_counts(sorted_keys, probe_keys, probe_valid):
 
 
 @functools.partial(jax.jit, static_argnames=("out_size", "left_outer"))
+@_scoped("otb.join_expand")
 def join_expand(lo, counts, perm, out_size: int, left_outer: bool = False,
                 probe_valid=None):
     """Materialize (probe_idx, build_idx) pairs into a static out_size.
@@ -446,6 +472,7 @@ def join_expand(lo, counts, perm, out_size: int, left_outer: bool = False,
 
 
 @jax.jit
+@_scoped("otb.join_expand")
 def compose_index(prior, take):
     """Late-materialization index composition: `prior` maps an operator's
     output positions to source rows, `take` re-points a downstream
@@ -457,11 +484,13 @@ def compose_index(prior, take):
 
 
 @jax.jit
+@_scoped("otb.join_probe")
 def semi_mask(counts):
     return counts > 0
 
 
 @jax.jit
+@_scoped("otb.join_probe")
 def anti_mask(counts, probe_valid):
     return probe_valid & (counts == 0)
 
@@ -482,6 +511,7 @@ def _order_key(col, desc: bool):
 
 
 @functools.partial(jax.jit, static_argnames=("descs", "limit"))
+@_scoped("otb.sort")
 def sort_rows(key_cols: tuple, valid, payload_cols: tuple,
               descs: tuple, limit: int | None = None):
     """Lexicographic multi-key sort; invalid rows last; optional limit slice.
@@ -506,6 +536,7 @@ def sort_rows(key_cols: tuple, valid, payload_cols: tuple,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("num_buckets",))
+@_scoped("otb.exchange")
 def bucket_ids(key_cols: tuple, num_buckets: int):
     from ..utils.hashing import hash_columns_jax
     h = hash_columns_jax(list(key_cols))

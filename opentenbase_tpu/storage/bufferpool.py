@@ -779,9 +779,6 @@ class DeviceBufferPool:
             self._chunks[key] = [next(_SEQ), e]
             self._note_pin_locked(e, table, consumer)
             self._watch_store(store)
-        if obs_trace.ENABLED:
-            obs_trace.event("chunk_stage", table=table, start=int(start),
-                            rows=int(live), bytes=int(up))
         self.trim()
         return e
 

@@ -272,6 +272,50 @@ class TestObsPurityPass:
         assert _scan(tmp_path, "obs-purity") == []
 
 
+    def test_named_scope_is_allowed_inside_a_traced_closure(self, tmp_path):
+        # the device-side half of the naming: metadata on the ops being
+        # traced, nothing that runs — not instrumentation in this sense
+        files = dict(self.FILES)
+        files["fixpkg/exec/hot.py"] = """\
+            import jax
+
+            def run(x):
+                with jax.named_scope("otb.scan"):
+                    return jax.numpy.cumsum(x)
+
+            def build():
+                return jax.jit(run)
+        """
+        _write_pkg(tmp_path, files)
+        assert _scan(tmp_path, "obs-purity") == []
+
+    def test_trace_annotation_lives_only_in_obs(self, tmp_path):
+        # the profiler's clock is written from obs/ alone, host side:
+        # an annotation anywhere else is a second tracing system
+        files = dict(self.FILES)
+        files["fixpkg/obs/trace.py"] = """\
+            from jax.profiler import TraceAnnotation
+
+            def span(name, **attrs):
+                return TraceAnnotation("otb:" + name)
+        """
+        files["fixpkg/exec/hot.py"] = """\
+            import jax
+            from jax.profiler import TraceAnnotation
+
+            def host(x):
+                with TraceAnnotation("mine"):
+                    return x
+
+            def other(x):
+                with jax.profiler.StepTraceAnnotation("step"):
+                    return x
+        """
+        _write_pkg(tmp_path, files)
+        got = sorted(_scan(tmp_path, "obs-purity"))
+        assert got == [("obs-purity", "fixpkg/exec/hot.py")] * 2, got
+
+
 class TestNetDeadlinePass:
     FILES = {
         "fixpkg/__init__.py": "",

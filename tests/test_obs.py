@@ -129,6 +129,145 @@ class TestSpans:
                 pass
         assert len(obs_trace.recent()) == obs_trace.RING_CAP
 
+    def test_span_exports_start_and_duration_inside_its_parent(self):
+        with obs_trace.trace_query("q") as qt:
+            time.sleep(0.001)
+            with obs_trace.span("execute"):
+                time.sleep(0.001)
+                with obs_trace.span("stage", table="t"):
+                    time.sleep(0.001)
+                obs_trace.event("pool", hit=True)
+            with obs_trace.span("finalize"):
+                time.sleep(0.001)
+        d = qt.to_dict()["spans"]
+        assert d["name"] == "query" and d["t0_ms"] == 0.0
+
+        def inside(parent, lo, hi):
+            for c in parent.get("children", ()):
+                # name, start and duration are exported; the tree gives
+                # the parent, and a child lies inside it
+                assert {"name", "t0_ms", "ms"} <= set(c)
+                assert lo - 1e-3 <= c["t0_ms"]
+                assert c["t0_ms"] + c["ms"] <= hi + 1e-3, (c, hi)
+                inside(c, c["t0_ms"], c["t0_ms"] + c["ms"])
+
+        inside(d, 0.0, d["ms"])
+        ex, fin = d["children"]
+        assert ex["t0_ms"] >= 1.0                  # after the first sleep
+        assert fin["t0_ms"] >= ex["t0_ms"] + ex["ms"]   # siblings in order
+        stage, pool = ex["children"]
+        assert pool["ms"] == 0.0 and pool["t0_ms"] >= stage["t0_ms"]
+        # every span of the statement shares its trace_id
+        assert qt.summary()["trace_id"] == qt.trace_id
+        # a shipped subtree comes back with its starts
+        back = obs_trace.span_from_dict(ex)
+        assert back.t0_ms == ex["t0_ms"]
+        assert back.children[0].t0_ms == stage["t0_ms"]
+
+    def test_self_ms_of_a_hand_built_tree(self):
+        def mk(name, t0, ms, *children):
+            sp = obs_trace.Span(name)
+            sp.t0_ms, sp.ms = t0, ms
+            sp.children = list(children)
+            return sp
+
+        qt = obs_trace.QueryTrace("hand")
+        # root [0,100): parse [2,5) execute [10,60) finalize [60,95);
+        # execute holds stage [12,20) and an inner execute [30,50);
+        # finalize holds fetch [61,90) and a grafted remote [85,99) that
+        # overlaps fetch and sticks out of its parent
+        qt.root = mk(
+            "query", 0, 100,
+            mk("parse", 2, 3),
+            mk("execute", 10, 50,
+               mk("stage", 12, 8), mk("execute", 30, 20)),
+            mk("finalize", 60, 35,
+               mk("finalize.fetch", 61, 29), mk("remote", 85, 14)))
+        assert qt.self_ms("query") == pytest.approx(100 - 3 - 50 - 35)
+        assert qt.self_ms("parse") == pytest.approx(3)
+        # the outermost execute only, less its two children
+        assert qt.self_ms("execute") == pytest.approx(50 - 8 - 20)
+        # children cover [61,95) of finalize's [60,95): the overlap
+        # counts once and what sticks out does not count
+        assert qt.self_ms("finalize") == pytest.approx(1)
+        assert qt.self_ms("nothing") == 0.0
+        s = qt.summary()
+        assert s["unattributed_ms"] == pytest.approx(12)
+        assert s["parse_ms"] == 3 and s["execute_ms"] == 50
+        assert s["finalize_fetch_ms"] == 29
+
+    def test_summary_keeps_its_keys_and_gains_the_layers(self):
+        with obs_trace.trace_query("q") as qt:
+            with obs_trace.span("finalize") as sp:
+                sp.set(bytes=64)
+                with obs_trace.span("finalize.fetch") as f:
+                    f.set(fetches=3, bytes=96)
+        s = qt.summary()
+        # the keys the ledger's metrics read by name, unchanged
+        assert {"qid", "trace_id", "signature", "tier", "total_ms", "rows",
+                "bytes_staged", "bytes_materialized", "pool_hits",
+                "pool_misses", "plan_ms", "stage_ms", "execute_ms",
+                "exchange_ms", "finalize_ms", "stage_wait_ms"} <= set(s)
+        assert {"wire_ms", "parse_ms", "autoprep_ms", "wait_ms",
+                "finalize_gather_ms", "finalize_fetch_ms",
+                "finalize_decode_ms", "finalize_fetches",
+                "finalize_fetch_bytes", "unattributed_ms"} <= set(s)
+        assert s["bytes_materialized"] == 64
+        assert s["finalize_fetches"] == 3
+        assert s["finalize_fetch_bytes"] == 96
+        for ph in obs_trace.PHASES:
+            assert s[f"{ph}_ms"] == pytest.approx(qt.phase_ms(ph))
+
+    def test_disabled_tracing_allocates_nothing(self, monkeypatch):
+        monkeypatch.setattr(obs_trace, "ENABLED", False)
+        made = []
+        real = obs_trace.Span.__init__
+
+        def counting(self, *a, **kw):
+            made.append(self)
+            real(self, *a, **kw)
+
+        monkeypatch.setattr(obs_trace.Span, "__init__", counting)
+        ctx = obs_trace.trace_query("select 1")
+        assert ctx is obs_trace.trace_query("select 2")     # one shared
+        with ctx as qt:
+            assert qt is None
+            for name in ("wire.recv", "parse", "autoprep", "finalize",
+                         "finalize.gather", "finalize.fetch",
+                         "finalize.decode", "wire.send"):
+                with obs_trace.span(name) as sp:
+                    assert sp is obs_trace.NULL_SPAN
+                    sp.set(bytes=1)
+            obs_trace.event("pool", hit=True)
+            obs_trace.record("wait", 1.0, event="lockmgr")
+            with obs_trace.adopt(qt) as adopted:
+                assert adopted is None
+        assert made == []
+        assert not obs_trace.active()
+
+    def test_a_real_span_is_an_annotation_on_the_profilers_clock(
+            self, tmp_path):
+        # a profiler session started by anyone records the spans, under
+        # otb:<name>, on the host plane of the trace it writes
+        import glob
+
+        import jax
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs_trace.trace_query("q"):
+                with obs_trace.span("execute"):
+                    with obs_trace.span("finalize.fetch"):
+                        time.sleep(0.001)
+        finally:
+            jax.profiler.stop_trace()
+        pb = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                           / "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(pb[0])
+        names = {e.name for p in data.planes if p.name.startswith("/host:")
+                 for ln in p.lines for e in ln.events
+                 if e.name.startswith("otb:")}
+        assert {"otb:query", "otb:execute", "otb:finalize.fetch"} <= names
+
 
 # ---------------------------------------------------------------------------
 # metrics registry
@@ -412,3 +551,288 @@ def test_cn_server_metrics_op():
         c.close()
     finally:
         srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# the spans where the work happens: a point read at SF0.01, in process and
+# through the CN server
+# ---------------------------------------------------------------------------
+
+POINT = ("select o_orderkey, o_custkey, o_totalprice, o_orderdate "
+         "from orders where o_orderkey = {}")
+
+
+@pytest.fixture(scope="module")
+def point_env():
+    cluster = Cluster(n_datanodes=1)
+    s = ClusterSession(cluster)
+    s.execute(SCHEMA)
+    data = datagen.generate(sf=0.01)
+    td = cluster.catalog.table("orders")
+    s._insert_rows(td, data["orders"], len(data["orders"]["o_orderkey"]))
+    keys = [int(k) for k in data["orders"]["o_orderkey"][:40:4]]
+    s.query(POINT.format(keys[0]))          # builds and stages
+    return cluster, s, keys
+
+
+class TestPointReadSpans:
+    def test_finalize_children_sum_to_finalize(self, point_env):
+        _cluster, s, keys = point_env
+        for k in keys[:3]:
+            assert len(s.query(POINT.format(k))) == 1
+            st = s.last_query_stats()
+            parts = (st["finalize_gather_ms"] + st["finalize_fetch_ms"]
+                     + st["finalize_decode_ms"])
+            assert 0 < parts <= st["finalize_ms"]
+            # the three cover finalize but for the spans' own entry and
+            # exit (a fifth of a ms would be a fourth kind of work)
+            assert st["finalize_ms"] - parts < max(
+                0.2, 0.25 * st["finalize_ms"]), st
+            qt = obs_trace.last_trace()
+            fin = [c for c in qt.root.children if c.name == "finalize"]
+            assert [c.name for c in fin[0].children] == [
+                "finalize.gather", "finalize.fetch", "finalize.decode"]
+
+    def test_fetches_are_exact_and_repeat(self, point_env):
+        _cluster, s, keys = point_env
+        seen = set()
+        for k in keys:
+            s.query(POINT.format(k))
+            st = s.last_query_stats()
+            seen.add((st["finalize_fetches"], st["finalize_fetch_bytes"]))
+        # `valid` and the four columns read, no null mask: five copies
+        # of the whole padded table, whatever the key
+        assert len(seen) == 1, seen
+        fetches, nbytes = seen.pop()
+        assert fetches == 5
+        padded = 16384                      # 15000 orders at SF0.01
+        assert nbytes == padded * (1 + 8 + 8 + 8 + 4)
+        assert s.last_query_stats()["bytes_materialized"] == \
+            padded * (8 + 8 + 8 + 4)
+
+    def test_parse_and_autoprep_are_spans_of_the_statement(self, point_env):
+        _cluster, s, keys = point_env
+        s.query(POINT.format(keys[1]))
+        st = s.last_query_stats()
+        assert st["parse_ms"] > 0 and st["autoprep_ms"] > 0
+        assert st["wire_ms"] == 0           # no wire in process
+        assert st["tier"] == "fqs"
+        # what no span covers is the root's self time
+        qt = obs_trace.last_trace()
+        assert st["unattributed_ms"] == pytest.approx(qt.self_ms("query"))
+        assert 0 < st["unattributed_ms"] < st["total_ms"]
+        total = st["unattributed_ms"] + sum(
+            c.ms for c in qt.root.children)
+        assert total == pytest.approx(st["total_ms"], rel=1e-6)
+
+    def test_wire_and_parse_for_a_statement_sent_through_cnserver(
+            self, point_env):
+        from opentenbase_tpu.net.cn_server import CnClient, CnServer
+        cluster, _s, keys = point_env
+        sessions = []
+
+        def make():
+            sessions.append(ClusterSession(cluster))
+            return sessions[-1]
+
+        srv = CnServer(make).start()
+        try:
+            c = CnClient(srv.host, srv.port)
+            last = obs_trace.recent()[-1].qid
+
+            def since():
+                return [q for q in obs_trace.recent() if q.qid > last]
+
+            assert len(c.query(POINT.format(keys[2]))) == 1
+            # read at the reply, as the benchmark does: the server's
+            # trace may still be open (the send is ending), and what
+            # has ended by then is all there
+            st = sessions[0].last_query_stats()
+            assert st["parse_ms"] > 0 and st["finalize_fetches"] == 5
+            assert st["wire_ms"] > 0            # wire.recv: the decode
+            assert st["total_ms"] >= st["finalize_ms"] + st["parse_ms"]
+            assert st["unattributed_ms"] < st["total_ms"] - st["finalize_ms"]
+            c.metrics()                         # not a statement
+            c.close()
+            deadline = time.time() + 5
+            while not since() and time.time() < deadline:
+                time.sleep(0.01)
+        finally:
+            srv.stop()
+        # one trace a message, and the metrics op left none
+        assert [q.signature for q in since()] == [POINT.format(keys[2])]
+        qt = since()[0]
+        names = [c.name for c in qt.root.children]
+        assert names[0] == "wire.recv" and names[1] == "parse"
+        assert names[-1] == "wire.send"
+        done = qt.summary()
+        # the finished trace has all of the reply's send
+        assert done["wire_ms"] >= st["wire_ms"]
+        assert done["total_ms"] >= st["total_ms"]
+        assert sessions[0].last_query_stats() == done
+        for key in ("parse_ms", "plan_ms", "execute_ms", "finalize_ms",
+                    "finalize_fetch_ms", "finalize_fetches", "rows"):
+            assert done[key] == st[key]
+        assert done["wire_ms"] == pytest.approx(
+            qt.root.children[0].ms + qt.root.children[-1].ms)
+
+
+# ---------------------------------------------------------------------------
+# one trace a statement, whichever thread runs it
+# ---------------------------------------------------------------------------
+
+def _queries_total():
+    return sum(r[-1] for r in obs_metrics.REGISTRY.samples()
+               if r[0] == "otb_queries_total")
+
+
+def _traces_since(qid, n, timeout=5.0):
+    """The ring's traces after `qid`, once `n` have finished (the
+    server's trace ends after the client has its reply)."""
+    deadline = time.time() + timeout
+    while True:
+        new = [q for q in obs_trace.recent() if q.qid > qid]
+        if len(new) >= n or time.time() > deadline:
+            return new
+        time.sleep(0.01)
+
+
+def _names(sp):
+    out = {sp.name}
+    for c in sp.children:
+        out |= _names(c)
+    return out
+
+
+class TestOneTraceAStatement:
+    def test_adopt_puts_another_threads_spans_under_the_trace(self):
+        with obs_trace.trace_query("q") as qt:
+            def work():
+                assert not obs_trace.active()
+                with obs_trace.adopt(qt) as same:
+                    assert same is qt and obs_trace.current_trace() is qt
+                    with obs_trace.trace_query("inner") as joined:
+                        assert joined is qt         # joins, opens none
+                        with obs_trace.span("execute"):
+                            time.sleep(0.001)
+                assert not obs_trace.active()
+            t = threading.Thread(target=work)
+            t.start()
+            t.join()
+            assert obs_trace.current_trace() is qt  # the owner's still
+        ex, = qt.root.children
+        assert ex.name == "execute" and 0 < ex.t0_ms < qt.total_ms
+        assert ex.t0_ms + ex.ms <= qt.total_ms
+
+    def test_trace_query_since_backdates_the_root(self):
+        t0 = time.perf_counter()
+        time.sleep(0.002)
+        with obs_trace.trace_query("q", since=t0) as qt:
+            obs_trace.record("wire.recv", 1.0)
+        assert qt.total_ms >= 2.0
+        recv, = qt.root.children
+        assert 0 <= recv.t0_ms and recv.t0_ms + recv.ms <= qt.total_ms
+
+    def test_summary_of_an_open_trace_reads_as_of_now(self):
+        with obs_trace.trace_query("q") as qt:
+            with obs_trace.span("wire.send"):
+                time.sleep(0.002)
+                st = qt.summary()
+                # the open span covers the time up to now
+                assert st["wire_ms"] >= 2.0
+                assert st["unattributed_ms"] < 1.0
+                assert st["total_ms"] >= st["wire_ms"]
+
+    def test_serving_tier_n_statements_n_traces(self):
+        # the serving tier runs a statement on a dispatcher thread while
+        # its connection thread, which opened the trace, waits: still
+        # ONE ring entry and ONE otb_queries_total a statement, holding
+        # the wire and the execution together
+        from opentenbase_tpu.exec import scheduler as sm
+        from opentenbase_tpu.net.cn_server import CnClient
+        node = LocalNode()
+        s = Session(node)
+        s.execute("create table t (a bigint, b double precision, g bigint)")
+        s.execute("insert into t values " + ", ".join(
+            f"({i}, {i * 0.5}, {i % 3})" for i in range(200)))
+        sqls = ["select g, sum(b) as sb from t where a < 100 "
+                "group by g order by g",
+                "select a, b from t where a = 7",
+                "insert into t values (1000, 1.0, 1)",
+                "select a from t where a = 1000; select count(*) from t",
+                "select g, sum(b) as sb from t where a < 100 "
+                "group by g order by g",
+                "select nope from t"]
+        srv, sched = sm.serve(node)
+        try:
+            c = CnClient(srv.host, srv.port)
+            last = obs_trace.recent()[-1].qid
+            before = _queries_total()
+            for sql in sqls[:-1]:
+                c.execute(sql)
+            with pytest.raises(Exception, match="nope"):
+                c.query(sqls[-1])
+            new = _traces_since(last, len(sqls))
+            c.close()
+        finally:
+            srv.stop()
+            sched.stop()
+        assert [q.signature for q in new] == sqls
+        assert _queries_total() == before + len(sqls)
+        assert len({q.trace_id for q in new}) == len(sqls)
+        for q in new:
+            first, last_ = q.root.children[0], q.root.children[-1]
+            assert first.name == "wire.recv" and last_.name == "wire.send"
+            assert "parse" in _names(q.root)
+        for q in (new[0], new[1], new[3]):
+            assert {"execute", "finalize.fetch"} <= _names(q.root)
+        assert new[1].rows == 1 and new[3].rows == 1
+
+    def test_single_node_session_over_cnserver_has_parse_and_wire(self):
+        from opentenbase_tpu.net.cn_server import CnClient, CnServer
+        node = LocalNode()
+        Session(node).execute("create table t1 (a bigint)")
+        sessions = []
+
+        def make():
+            sessions.append(Session(node))
+            return sessions[-1]
+
+        srv = CnServer(make).start()
+        try:
+            c = CnClient(srv.host, srv.port)
+            last = obs_trace.recent()[-1].qid
+            c.execute("insert into t1 values (1), (2)")
+            assert c.query("select a from t1 order by a") == [(1,), (2,)]
+            st = sessions[0].last_query_stats()
+            assert st["parse_ms"] > 0 and st["wire_ms"] > 0
+            new = _traces_since(last, 2)
+            c.close()
+        finally:
+            srv.stop()
+        assert len(new) == 2
+        assert sessions[0].last_query_stats() == new[1].summary()
+        # in process: the session opens the trace, around the parse
+        s = Session(node)
+        s.execute("select a from t1; select count(*) from t1")
+        st = s.last_query_stats()
+        assert st["parse_ms"] > 0 and st["wire_ms"] == 0
+        assert obs_trace.last_trace().signature \
+            == "select a from t1; select count(*) from t1"
+
+    def test_malformed_query_message_gets_an_error_reply(self):
+        # no `sql`, or one that is no string: an error reply, and the
+        # session's thread lives on
+        from opentenbase_tpu.net.cn_server import CnClient, CnServer
+        from opentenbase_tpu.net.wire import recv_msg, send_msg
+        node = LocalNode()
+        srv = CnServer(lambda: Session(node)).start()
+        try:
+            c = CnClient(srv.host, srv.port)
+            for bad in ({"op": "query"}, {"op": "query", "sql": 7}):
+                send_msg(c._sock, bad)
+                assert "error" in recv_msg(c._sock, expect_reply=True)
+            assert c.query("select 1") == [(1,)]
+            c.close()
+        finally:
+            srv.stop()

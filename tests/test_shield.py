@@ -364,18 +364,20 @@ class TestCnServerCancelRace:
         (the old code cleared the flag in that window, dropping it)."""
         from opentenbase_tpu.net import cn_server as cn
         node, _ = _mk_node()
-        real_recv = cn.recv_msg
+        # the server reads a frame, then decodes it inside the
+        # statement's trace: the window opens once the frame is read
+        real_decode = cn.decode_msg
         got_query = threading.Event()
         cancel_landed = threading.Event()
 
-        def gated_recv(sock, **kw):
-            msg = real_recv(sock, **kw)
+        def gated_decode(blob):
+            msg = real_decode(blob)
             if isinstance(msg, dict) and msg.get("op") == "query":
                 got_query.set()
                 cancel_landed.wait(timeout=10)
             return msg
 
-        monkeypatch.setattr(cn, "recv_msg", gated_recv)
+        monkeypatch.setattr(cn, "decode_msg", gated_decode)
         srv = cn.CnServer(lambda: Session(node)).start()
         try:
             cli = cn.CnClient(srv.host, srv.port)
