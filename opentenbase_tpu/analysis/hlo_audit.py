@@ -9,7 +9,12 @@ for
   infeed/outfeed, host callbacks.  (``custom_call @Sharding`` is the
   partitioner's layout annotation, not a transfer, and is not flagged);
 - ``hlo-dynamic-shape``  — dynamic-shape ops / ``?``-dim tensor types,
-  which break AOT compilation caching on TPU.
+  which break AOT compilation caching on TPU;
+- ``hlo-scatter-sort``   — a scatter or a sort in a kernel that declares
+  itself free of both (``export_check(..., no_scatter_sort=True)``:
+  finalize's live-row selection runs in front of every wide read, and a
+  scatter with one update per input row costs more than the copy it
+  saves).
 
 ``python -m opentenbase_tpu.analysis.hlo_audit`` exports the kernel
 battery (add ``--full`` for the live query battery with fused/mesh
@@ -41,19 +46,24 @@ _DYNSHAPE = re.compile(
     r"|dynamic_broadcast_in_dim|dynamic_gather|dynamic_iota"
     r"|dynamic_conv)\b"
     r"|tensor<(\?|\d+x\?|[0-9x]*\?x)")
+_SCATTER_SORT = re.compile(r"stablehlo\.(scatter|sort)\b")
 
 
-def scan_hlo_text(label: str, txt: str) -> list:
+def scan_hlo_text(label: str, txt: str,
+                  no_scatter_sort: bool = False) -> list:
     """Scan one exported program's MLIR text; one finding per rule per
     program, at the first offending line."""
     findings = []
-    for rule, rx, msg in (
-            ("hlo-f64", _F64,
-             "f64 tensor type in exported StableHLO"),
-            ("hlo-host-transfer", _TRANSFER,
-             "host transfer / callback op in exported StableHLO"),
-            ("hlo-dynamic-shape", _DYNSHAPE,
-             "dynamic-shape op in exported StableHLO")):
+    rules = [
+        ("hlo-f64", _F64, "f64 tensor type in exported StableHLO"),
+        ("hlo-host-transfer", _TRANSFER,
+         "host transfer / callback op in exported StableHLO"),
+        ("hlo-dynamic-shape", _DYNSHAPE,
+         "dynamic-shape op in exported StableHLO")]
+    if no_scatter_sort:
+        rules.append(("hlo-scatter-sort", _SCATTER_SORT,
+                      "scatter or sort in a kernel declared free of both"))
+    for rule, rx, msg in rules:
         m = rx.search(txt)
         if m:
             line = txt.count("\n", 0, m.start()) + 1
@@ -71,7 +81,8 @@ def _sds_of(tree):
     return jax.tree.map(leaf, tree)
 
 
-def export_check(fn, args, label: str, report: dict):
+def export_check(fn, args, label: str, report: dict,
+                 no_scatter_sort: bool = False):
     """Export `fn(*args)` for platform 'tpu'; scan the StableHLO and
     record findings (f64 hits also land in the legacy report keys)."""
     import jax
@@ -86,7 +97,7 @@ def export_check(fn, args, label: str, report: dict):
             f"{label}: {type(e).__name__}: {e}")
         return
     report["programs"] = report.get("programs", 0) + 1
-    for f in scan_hlo_text(label, txt):
+    for f in scan_hlo_text(label, txt, no_scatter_sort):
         report.setdefault("findings", []).append(f)
         if f.rule == "hlo-f64":
             report.setdefault("f64", []).append(label)
@@ -103,8 +114,8 @@ def check_kernels(report: dict):
         f = jnp.zeros(n, DF)
         i = jnp.zeros(n, jnp.int64)
         v = jnp.zeros(n, bool)
-        export_check(lambda m, c: K.compact(m, c, out_size=n),
-                     (v, (i, f)), f"compact/{n}", report)
+        export_check(lambda m: K.live_positions(m, out_size=256), (v,),
+                     f"live_positions/{n}", report, no_scatter_sort=True)
         export_check(
             lambda g, m, a: K.grouped_agg_dense(
                 g, m, a, num_groups=64,

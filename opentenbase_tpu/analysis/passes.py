@@ -92,7 +92,7 @@ _IMPURE_CALL_PREFIXES = ("time.", "datetime.", "random.", "secrets.",
 def _dotted(expr, mi) -> Optional[str]:
     """Resolve an attribute chain to a dotted name, mapping the root
     through the module's import aliases (``jnp.sum`` -> ``jax.numpy.sum``,
-    ``K.compact`` -> ``<pkg>.ops.kernels.compact``)."""
+    ``K.join_expand`` -> ``<pkg>.ops.kernels.join_expand``)."""
     parts = []
     while isinstance(expr, ast.Attribute):
         parts.append(expr.attr)

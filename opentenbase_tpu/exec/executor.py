@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import os
 import threading
 from typing import Optional
@@ -35,7 +36,7 @@ from ..plan import exprs as E
 from ..plan import physical as P
 from ..plan.planner import PlannedStmt, rewrite
 from ..storage import codec
-from ..storage.batch import next_pow2
+from ..storage.batch import next_pow2, size_class
 from ..storage.store import ABORTED_TS, TableStore
 from ..utils.dtypes import (bits_to_float, dev_dtype, device_float,
                             float_to_bits)
@@ -150,6 +151,12 @@ class LazyCol:
         if self.null_out is not None:
             m = self.null_out if m is None else (m | self.null_out)
         return m
+
+
+# a pytree, so a whole program can take one (executor._gather_live)
+jax.tree_util.register_dataclass(
+    LazyCol, data_fields=["src", "idx", "null_src", "null_out"],
+    meta_fields=[])
 
 
 @dataclasses.dataclass
@@ -1960,34 +1967,114 @@ def materialize(b: DBatch, names: Optional[list[str]] = None):
         return _materialize(b, names)
 
 
+#: A result batch whose named columns and null masks, at the batch's
+#: padded width, come to at least this many bytes has its live rows
+#: selected on the device and only those copied; a smaller one is
+#: copied whole, which is cheaper than one more program and, per new
+#: padded size, one more executable.  Measured on the chip, PERF.md
+#: section 6 (PR 26).
+_COMPACT_MIN_BYTES = 1 << 20
+
+#: the selection's first output class (storage/batch.size_class's floor)
+_LIVE_FLOOR = 256
+
+
+@functools.partial(jax.jit, static_argnames=("out_size",))
+def _gather_live(valid, cols, nulls, lazy, out_size: int):
+    """One program for a wide batch's way out: the positions of its
+    first `out_size` live rows (ops/kernels.live_positions), and the
+    columns and null masks gathered there, a lazy column straight
+    through its indirection (never at full width).  The dicts are keyed
+    by the column's place in the select list, so the program depends on
+    shapes and not on names.  Returns ONE uint8 buffer: the count
+    (int32), the columns in select-list order, then the null masks; an
+    array a column costs the host more to dispatch, to copy and to free
+    than the program's whole device time (PERF.md section 6, PR 26)."""
+    count, idx = K.live_positions(valid, out_size)
+    with jax.named_scope("otb.finalize"):
+        gcols, gnulls = DBatch(cols, valid, {}, {}, nulls,
+                               lazy).gather_rows(idx)
+        parts = [count, *(gcols[i] for i in sorted(gcols)),
+                 *(gnulls[i] for i in sorted(gnulls))]
+        return jnp.concatenate([
+            jax.lax.bitcast_convert_type(
+                a.astype(jnp.uint8) if a.dtype == jnp.bool_ else a,
+                jnp.uint8).reshape(-1) for a in parts])
+
+
+def _fetch_live(b: DBatch, names: list[str], srcs: list, nullable: list):
+    """(count, cols, nulls) with the live rows first, as numpy, or None
+    where so many rows live that the whole copy is the shorter way.
+    `srcs` holds each named column's array (a lazy one's source),
+    `nullable` the names that carry a null mask."""
+    at = {n: i for i, n in enumerate(names)}
+    parts = [{at[n]: d[n] for n in names if n in d}
+             for d in (b.cols, b.nulls, b.lazy)]
+    out_size = _LIVE_FLOOR
+    while True:
+        with obs_trace.span("finalize.gather"):
+            dev = _gather_live(b.valid, *parts, out_size=out_size)
+        with obs_trace.span("finalize.fetch") as sp:
+            buf = np.asarray(dev)
+            sp.set(fetches=1, bytes=buf.nbytes, compacted=out_size)
+        count = int(buf[:4].view(np.int32)[0])
+        if count <= out_size:
+            break
+        out_size = size_class(count)
+        if 2 * out_size > b.padded:
+            return None
+    cols, lo = [], 4
+    for a in srcs:
+        hi = lo + _arr_bytes(a, out_size)
+        cols.append(buf[lo:hi].view(a.dtype).reshape(
+            (out_size,) + a.shape[1:]))
+        lo = hi
+    nulls = {n: buf[lo + i * out_size:lo + (i + 1) * out_size].view(bool)
+             for i, n in enumerate(nullable)}
+    return count, cols, nulls
+
+
 def _materialize(b: DBatch, names: Optional[list[str]] = None):
-    """Three kinds of work, a span each: the lazy columns' gathers
-    (dispatched, not waited for), every device-to-host copy, and the
-    numpy-to-Python decode.  `finalize.fetch` adds no sync: it times the
-    copies this function has always made, so the first of them also
-    waits for the gathers and for whatever program produced the batch."""
+    """Three kinds of work, a span each: the device programs that ready
+    the columns (dispatched, not waited for), every device-to-host copy,
+    and the numpy-to-Python decode.  `finalize.fetch` adds no sync: the
+    first copy also waits for those programs and for whatever program
+    produced the batch.  A batch under _COMPACT_MIN_BYTES is copied
+    whole and its live rows found on the host; a wider one leaves only
+    its live rows (_fetch_live)."""
     if names is None:
         names = b.names()
-    with obs_trace.span("finalize.gather"):
-        b.ensure(names)
-    with obs_trace.span("finalize.fetch") as sp:
-        valid = np.asarray(b.valid)
-        cols = [np.asarray(b.cols[n]) for n in names]
-        nulls = {n: np.asarray(b.nulls[n]) for n in names if n in b.nulls}
-        col_bytes = sum(a.nbytes for a in cols)
-        sp.set(fetches=1 + len(cols) + len(nulls),
-               bytes=valid.nbytes + col_bytes
-               + sum(a.nbytes for a in nulls.values()))
+    srcs = [b.lazy[n].src if n in b.lazy else b.cols[n] for n in names]
+    nullable = [n for n in names if b.maybe_null(n)]
+    row_bytes = sum(_arr_bytes(a, 1) for a in srcs) + len(nullable)
+    live = None
+    if b.padded * row_bytes >= _COMPACT_MIN_BYTES:
+        live = _fetch_live(b, names, srcs, nullable)
+    if live is None:
+        with obs_trace.span("finalize.gather"):
+            b.ensure(names)
+        with obs_trace.span("finalize.fetch") as sp:
+            valid = np.asarray(b.valid)
+            cols = [np.asarray(b.cols[n]) for n in names]
+            nulls = {n: np.asarray(b.nulls[n])
+                     for n in names if n in b.nulls}
+            sp.set(fetches=1 + len(cols) + len(nulls),
+                   bytes=valid.nbytes + sum(a.nbytes for a in cols)
+                   + sum(a.nbytes for a in nulls.values()),
+                   compacted=0)
+    else:
+        valid = None
+        count, cols, nulls = live
     with obs_trace.span("finalize.decode"):
-        rows_idx = np.nonzero(valid)[0]
+        rows_idx = slice(count) if valid is None else np.nonzero(valid)[0]
         out_cols = [
             _decode_column(arr[rows_idx], b.types[n], b.dicts.get(n, []),
                            nulls[n][rows_idx] if n in nulls else None)
             for n, arr in zip(names, cols)]
         rows = list(zip(*out_cols)) if out_cols else []
-    # the statement's host-materialized footprint (its columns, full
-    # width), on `finalize`
-    obs_trace.annotate(rows=len(rows), bytes=col_bytes)
+    # the statement's host-materialized footprint (its columns, at the
+    # width they were copied), on `finalize`
+    obs_trace.annotate(rows=len(rows), bytes=sum(a.nbytes for a in cols))
     return names, rows
 
 

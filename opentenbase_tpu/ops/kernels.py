@@ -116,18 +116,44 @@ def cmp_on_codes(codes, aux, family: str, op: str, lit):
 
 
 # ---------------------------------------------------------------------------
-# compaction: gather selected rows to the front of a padded buffer
+# finalize: where a wide, sparse result batch keeps its live rows
 # ---------------------------------------------------------------------------
 
+#: flags per block of live_positions' two levels (measured on the chip
+#: at 2**21 flags, PERF.md section 6 PR 26: 512 runs 0.06 ms faster and
+#: compiles three times as long)
+_LIVE_BLOCK = 1024
+
+
 @functools.partial(jax.jit, static_argnames=("out_size",))
-@_scoped("otb.scan")
-def compact(mask, cols: tuple, out_size: int):
-    """Returns (count, gathered_cols) where gathered_cols are [out_size]
-    arrays holding the selected rows first (padding rows repeat row 0 and
-    must be masked by count downstream)."""
-    idx = jnp.nonzero(mask, size=out_size, fill_value=0)[0]
-    count = jnp.sum(mask)
-    return count, tuple(c[idx] for c in cols)
+@_scoped("otb.finalize")
+def live_positions(valid, out_size: int):
+    """(count, idx): how many flags of `valid[P]` are set, and the
+    positions of the first `out_size` of them IN POSITION ORDER (a
+    sorted batch's row order is its position order).  Lanes >= count
+    point at some row in range and are cut by `count` on the host.
+
+    Scatter-free and sort-free: `jnp.nonzero(size=)` lowers to a
+    bincount with one scatter update per INPUT row, the op class that
+    costs seconds per 6 M rows on the chip (PERF.md section 5), and a
+    sort of this size compiles for tens of seconds.  Two levels
+    instead: a count per block of flags, a binary search of each output
+    lane's rank in the blocks' running count, then a running count
+    inside that lane's block alone.  One pass over the flags; a running
+    count over all P of them took 4x the device time and 14 s to
+    compile."""
+    p = valid.shape[0]
+    blocks = jnp.pad(valid, (0, -p % _LIVE_BLOCK)).reshape(-1, _LIVE_BLOCK)
+    per_block = jnp.sum(blocks, axis=1, dtype=jnp.int32)
+    upto = jnp.cumsum(per_block)
+    rank = jnp.arange(1, out_size + 1, dtype=jnp.int32)
+    blk = jnp.minimum(jnp.searchsorted(upto, rank, side="left"),
+                      blocks.shape[0] - 1).astype(jnp.int32)
+    rank_in_blk = rank - (upto[blk] - per_block[blk])
+    inside = jnp.cumsum(blocks[blk], axis=1, dtype=jnp.int32)
+    off = jnp.sum(inside < rank_in_blk[:, None], axis=1, dtype=jnp.int32)
+    idx = blk * _LIVE_BLOCK + jnp.minimum(off, _LIVE_BLOCK - 1)
+    return upto[-1], jnp.minimum(idx, p - 1)
 
 
 # ---------------------------------------------------------------------------

@@ -10,13 +10,13 @@ from opentenbase_tpu.ops import kernels as K
 rng = np.random.default_rng(42)
 
 
-class TestCompact:
+class TestLivePositions:
     def test_basic(self):
         x = np.arange(100, dtype=np.int64)
         mask = (x % 3) == 0
-        cnt, (out,) = K.compact(jnp.asarray(mask), (jnp.asarray(x),), 128)
+        cnt, idx = K.live_positions(jnp.asarray(mask), 128)
         cnt = int(cnt)
-        np.testing.assert_array_equal(np.asarray(out)[:cnt], x[mask])
+        np.testing.assert_array_equal(x[np.asarray(idx)[:cnt]], x[mask])
 
 
 class TestGroupedAggDense:

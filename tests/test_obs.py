@@ -593,22 +593,43 @@ class TestPointReadSpans:
             assert [c.name for c in fin[0].children] == [
                 "finalize.gather", "finalize.fetch", "finalize.decode"]
 
-    def test_fetches_are_exact_and_repeat(self, point_env):
+    @pytest.mark.parametrize("side", ["under", "over"])
+    def test_fetches_are_exact_and_repeat(self, point_env, side,
+                                          monkeypatch):
+        """Each side of finalize's threshold: 15000 orders at SF0.01 pad
+        to 16384 rows of 28 B, under it; with the constant lowered to
+        that width the same reads are over it."""
+        from opentenbase_tpu.exec import executor
         _cluster, s, keys = point_env
+        padded, row = 16384, 8 + 8 + 8 + 4
+        assert padded * row < executor._COMPACT_MIN_BYTES
+        if side == "over":
+            monkeypatch.setattr(executor, "_COMPACT_MIN_BYTES", padded * row)
         seen = set()
         for k in keys:
-            s.query(POINT.format(k))
+            assert len(s.query(POINT.format(k))) == 1
             st = s.last_query_stats()
-            seen.add((st["finalize_fetches"], st["finalize_fetch_bytes"]))
-        # `valid` and the four columns read, no null mask: five copies
-        # of the whole padded table, whatever the key
+            seen.add((st["finalize_fetches"], st["finalize_fetch_bytes"],
+                      st["bytes_materialized"]))
+        # whatever the key
         assert len(seen) == 1, seen
-        fetches, nbytes = seen.pop()
-        assert fetches == 5
-        padded = 16384                      # 15000 orders at SF0.01
-        assert nbytes == padded * (1 + 8 + 8 + 8 + 4)
-        assert s.last_query_stats()["bytes_materialized"] == \
-            padded * (8 + 8 + 8 + 4)
+        fetches, nbytes, materialized = seen.pop()
+        if side == "under":
+            # `valid` and the four columns read, no null mask: five
+            # copies of the whole padded table
+            assert fetches == 5
+            assert nbytes == padded * (1 + row)
+            assert materialized == padded * row
+        else:
+            # one buffer: the count and the four columns at the first
+            # out class; `valid` stays on the device
+            assert fetches == 1
+            assert nbytes == 4 + 256 * row
+            assert materialized == 256 * row
+        (fetch,) = [c for f in obs_trace.last_trace().root.children
+                    if f.name == "finalize"
+                    for c in f.children if c.name == "finalize.fetch"]
+        assert fetch.attrs["compacted"] == (256 if side == "over" else 0)
 
     def test_parse_and_autoprep_are_spans_of_the_statement(self, point_env):
         _cluster, s, keys = point_env
@@ -625,10 +646,15 @@ class TestPointReadSpans:
             c.ms for c in qt.root.children)
         assert total == pytest.approx(st["total_ms"], rel=1e-6)
 
+    @pytest.mark.parametrize("side, fetches, fetch_bytes", [
+        ("under", 5, 16384 * 29), ("over", 1, 4 + 256 * 28)])
     def test_wire_and_parse_for_a_statement_sent_through_cnserver(
-            self, point_env):
+            self, point_env, side, fetches, fetch_bytes, monkeypatch):
+        from opentenbase_tpu.exec import executor
         from opentenbase_tpu.net.cn_server import CnClient, CnServer
         cluster, _s, keys = point_env
+        if side == "over":                  # of finalize's threshold
+            monkeypatch.setattr(executor, "_COMPACT_MIN_BYTES", 16384 * 28)
         sessions = []
 
         def make():
@@ -648,7 +674,9 @@ class TestPointReadSpans:
             # trace may still be open (the send is ending), and what
             # has ended by then is all there
             st = sessions[0].last_query_stats()
-            assert st["parse_ms"] > 0 and st["finalize_fetches"] == 5
+            assert st["parse_ms"] > 0
+            assert st["finalize_fetches"] == fetches
+            assert st["finalize_fetch_bytes"] == fetch_bytes
             assert st["wire_ms"] > 0            # wire.recv: the decode
             assert st["total_ms"] >= st["finalize_ms"] + st["parse_ms"]
             assert st["unattributed_ms"] < st["total_ms"] - st["finalize_ms"]
@@ -671,7 +699,8 @@ class TestPointReadSpans:
         assert done["total_ms"] >= st["total_ms"]
         assert sessions[0].last_query_stats() == done
         for key in ("parse_ms", "plan_ms", "execute_ms", "finalize_ms",
-                    "finalize_fetch_ms", "finalize_fetches", "rows"):
+                    "finalize_fetch_ms", "finalize_fetches",
+                    "finalize_fetch_bytes", "rows"):
             assert done[key] == st[key]
         assert done["wire_ms"] == pytest.approx(
             qt.root.children[0].ms + qt.root.children[-1].ms)

@@ -43,8 +43,8 @@ KERNELS = [
     ("cmp_on_codes", "otb.scan",
      lambda: _lowered(K.cmp_on_codes, I, AUX, family="pack", op="<",
                       lit=3)),
-    ("compact", "otb.scan", lambda: _lowered(K.compact, B, (I,),
-                                             out_size=N)),
+    ("live_positions", "otb.finalize",
+     lambda: _lowered(K.live_positions, B, out_size=N)),
     ("grouped_agg_dense", "otb.agg",
      lambda: _lowered(K.grouped_agg_dense, I % 4, B, (I,), num_groups=4,
                       agg_kinds=("sum",))),

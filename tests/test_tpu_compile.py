@@ -95,6 +95,21 @@ def test_visibility_mask_at_sf1(one_chip):
              s(BIG, I64), s(BIG, I64), s(0, I64), s(0, I64), s(0, I64))
 
 
+def test_finalize_live_rows_at_orders_class(one_chip):
+    """A point read's way out at SF1: `orders` pads to 1,572,864 rows;
+    the selection and the four columns' gathers are one program with
+    one uint8 result, and the chip's compiler puts no scatter and no
+    sort in it."""
+    from opentenbase_tpu.exec import executor as X
+    s, orders = one_chip, 1572864
+    cols = {0: s(orders, I64), 1: s(orders, I64), 2: s(orders, I64),
+            3: s(orders, I32)}
+    text = _compile(jax.jit(lambda v, c: X._gather_live(
+        v, c, {}, {}, out_size=256)), s(orders, BOOL), cols)
+    assert " scatter(" not in text and " sort(" not in text
+    assert "u8[7172]" in text
+
+
 def test_join_build(one_chip):
     s = one_chip
     _compile(K.join_build, s(SMALL, I64), s(SMALL, BOOL))
