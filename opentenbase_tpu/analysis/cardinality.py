@@ -884,6 +884,12 @@ def _codec_class_ok(tok) -> bool:
         return False
     if tok == "raw" or tok in _CODEC_FAMS:
         return True
+    if tok.startswith(("pack32/", "for32/")):
+        # a 32-bit class with its proven code limit (codec._proven_limit):
+        # one of batch.size_class's quarter steps
+        limit = tok.partition("/")[2]
+        return limit.isdigit() and 1 << 16 <= int(limit) < 1 << 32 \
+            and is_ladder_int(int(limit))
     if tok.startswith("dict"):
         base, _, cap = tok.partition("/")
         if base not in ("dict8", "dict16") or not cap.isdigit():

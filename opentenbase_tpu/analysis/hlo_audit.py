@@ -14,7 +14,12 @@ for
   itself free of both (``export_check(..., no_scatter_sort=True)``:
   finalize's live-row selection runs in front of every wide read, and a
   scatter with one update per input row costs more than the copy it
-  saves).
+  saves);
+- ``hlo-conditional``    — a ``case``/``if`` in a kernel that declares
+  itself free of them (``export_check(..., no_conditional=True)``: the
+  join kernels choose their algorithm when the program is built, so a
+  program's device time is a function of its shapes and not of which
+  arm the data took, and only one arm is compiled).
 
 ``python -m opentenbase_tpu.analysis.hlo_audit`` exports the kernel
 battery (add ``--full`` for the live query battery with fused/mesh
@@ -47,10 +52,11 @@ _DYNSHAPE = re.compile(
     r"|dynamic_conv)\b"
     r"|tensor<(\?|\d+x\?|[0-9x]*\?x)")
 _SCATTER_SORT = re.compile(r"stablehlo\.(scatter|sort)\b")
+_CONDITIONAL = re.compile(r"stablehlo\.(case|if)\b")
 
 
-def scan_hlo_text(label: str, txt: str,
-                  no_scatter_sort: bool = False) -> list:
+def scan_hlo_text(label: str, txt: str, no_scatter_sort: bool = False,
+                  no_conditional: bool = False) -> list:
     """Scan one exported program's MLIR text; one finding per rule per
     program, at the first offending line."""
     findings = []
@@ -63,6 +69,9 @@ def scan_hlo_text(label: str, txt: str,
     if no_scatter_sort:
         rules.append(("hlo-scatter-sort", _SCATTER_SORT,
                       "scatter or sort in a kernel declared free of both"))
+    if no_conditional:
+        rules.append(("hlo-conditional", _CONDITIONAL,
+                      "conditional in a kernel declared free of them"))
     for rule, rx, msg in rules:
         m = rx.search(txt)
         if m:
@@ -82,7 +91,8 @@ def _sds_of(tree):
 
 
 def export_check(fn, args, label: str, report: dict,
-                 no_scatter_sort: bool = False):
+                 no_scatter_sort: bool = False,
+                 no_conditional: bool = False):
     """Export `fn(*args)` for platform 'tpu'; scan the StableHLO and
     record findings (f64 hits also land in the legacy report keys)."""
     import jax
@@ -97,7 +107,7 @@ def export_check(fn, args, label: str, report: dict,
             f"{label}: {type(e).__name__}: {e}")
         return
     report["programs"] = report.get("programs", 0) + 1
-    for f in scan_hlo_text(label, txt, no_scatter_sort):
+    for f in scan_hlo_text(label, txt, no_scatter_sort, no_conditional):
         report.setdefault("findings", []).append(f)
         if f.rule == "hlo-f64":
             report.setdefault("f64", []).append(label)
@@ -127,9 +137,19 @@ def check_kernels(report: dict):
                 agg_kinds=("sum", "count", "min", "max", "sumf")),
             ((i, i), v, (i, i, i, f, f)),
             f"grouped_agg_sort/{n}", report)
-        export_check(K.join_build, (i, v), f"join_build/{n}", report)
-        export_check(K.join_probe_counts, (i, i, v),
-                     f"join_probe_counts/{n}", report)
+        # both arms of each join kernel: nothing known of the key's
+        # range (exact sort, binary search) and a host-known span
+        # (packed sort, direct-address table)
+        for span in (None, n // 2):
+            export_check(
+                lambda k, m, span=span: K.join_build(k, m, key_span=span),
+                (i, v), f"join_build/{n}/{span}", report,
+                no_conditional=True)
+            export_check(
+                lambda s_, k, m, span=span: K.join_probe_counts(
+                    s_, k, m, key_span=span),
+                (i, i, v), f"join_probe_counts/{n}/{span}", report,
+                no_conditional=True)
         export_check(
             lambda lo, c, p: K.join_expand(lo, c, p, out_size=2 * n,
                                            left_outer=True,

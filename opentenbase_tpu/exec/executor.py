@@ -170,6 +170,13 @@ class DBatch:
     # indirection (see LazyCol).  `cols`/`nulls` hold only materialized
     # columns; `types`/`dicts` always cover every column.
     lazy: dict[str, LazyCol] = dataclasses.field(default_factory=dict)
+    # what the host KNOWS of a column's range when the program is built:
+    # name -> an upper bound on max - min of its values, for a staged
+    # integer column carried unchanged from its scan (the column's codec
+    # class, storage/codec.span_bound: program-key material).  A join
+    # chooses its algorithm by it (ops/kernels.join_build); an operator
+    # that computes a column leaves it out: nothing is known of it.
+    spans: dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
     def padded(self) -> int:
@@ -576,6 +583,13 @@ class Executor:
          dicts) = self._scan_base(node.table, node.alias, node.filters,
                                   node.outputs)
         out_cols, out_types, out_dicts, out_nulls = {}, {}, {}, {}
+        # what the host knows of each output's range: the class of the
+        # ENCODED array this scan reads (a raw array, e.g. an index
+        # scan's gathered subset, proves nothing whatever the store's
+        # last staging recorded)
+        classes = dict(codec.codec_classes(_store))
+        encm = codec.enc_names(_arrs)
+        spans = {}
         for name, oe in outputs:
             out_cols[name], nm = self._eval_pair(oe, base)
             if nm is not None:
@@ -584,7 +598,16 @@ class Executor:
             d = _dict_for_expr(oe, dicts)
             if d is not None:
                 out_dicts[name] = d
-        return DBatch(out_cols, vis, out_types, out_dicts, out_nulls)
+            plain = oe.name.rsplit(".", 1)[-1] \
+                if isinstance(oe, E.Col) else None
+            cls = classes.get(plain, "")
+            if plain in encm and cls.startswith(
+                    codec.family_of(encm[plain])):
+                bound = codec.span_bound(cls)
+                if bound is not None:
+                    spans[name] = bound
+        return DBatch(out_cols, vis, out_types, out_dicts, out_nulls,
+                      spans=spans)
 
     # Index scans never fuse: neither tier's screen admits P.IndexScan
     # (fused._key_of returns None; mesh _ALLOWED excludes it).
@@ -687,7 +710,8 @@ class Executor:
         valid = b.valid
         for q in node.quals:
             valid = valid & self._eval_pred(q, b)
-        return DBatch(b.cols, valid, b.types, b.dicts, b.nulls, b.lazy)
+        return DBatch(b.cols, valid, b.types, b.dicts, b.nulls, b.lazy,
+                      b.spans)
 
     def _exec_project(self, node: P.Project) -> DBatch:
         b = self.exec_node(node.child)
@@ -713,8 +737,10 @@ class Executor:
         join_probe_counts).  TEXT keys are translated to stable string
         hashes so both sides share a key space (dictionary codes are
         column-local); text pairs are excluded from the hash recheck —
-        the hash IS the equality.  Returns (key, recheck_mask) where
-        recheck_mask[i] says key i can be re-verified by value."""
+        the hash IS the equality.  Returns (key, hashed, recheck_mask,
+        span): recheck_mask[i] says key i can be re-verified by value;
+        span is the host-known bound on the key's range (DBatch.spans)
+        for a single plain column, else None."""
         from .expr_compile import _text_hash_fn
         for k in keys:
             self._ensure_expr(k, b)
@@ -732,19 +758,22 @@ class Executor:
             arrs.append(a)
             if nm is not None:
                 nulls = nm if nulls is None else (nulls | nm)
+        span = None
         if len(arrs) == 1:
             a = arrs[0]
             if a.dtype == jnp.bool_:
                 a = a.astype(jnp.int64)
             a = a.astype(jnp.int64)
             hashed = False
+            if isinstance(keys[0], E.Col) and recheckable[0]:
+                span = b.spans.get(keys[0].name)
         else:
             a = hash_columns_jax([x.astype(jnp.int64) for x in arrs])
             a = a.astype(jnp.int64)
             hashed = True   # hashed: residual recheck needed
         if nulls is not None:
             a = jnp.where(nulls, K.INT64_MAX, a)
-        return a, hashed, recheckable
+        return a, hashed, recheckable, span
 
     def _defer_side(self, batch: DBatch, take, out: DBatch,
                     extra_null=None):
@@ -756,6 +785,7 @@ class Executor:
         output-space mask (outer-join null extension) OR'd onto every
         carried column's null."""
         composed: dict = {}
+        out.spans.update(batch.spans)
         for n_, a in batch.cols.items():
             out.lazy[n_] = LazyCol(a, take, batch.nulls.get(n_),
                                    extra_null)
@@ -785,6 +815,7 @@ class Executor:
         column of one input through `take` — kept as the bit-identical
         baseline (LATE_MAT off)."""
         batch.ensure_all()
+        out.spans.update(batch.spans)
         for n_, a in batch.cols.items():
             out.cols[n_] = a[take]
             out.types[n_] = batch.types[n_]
@@ -836,11 +867,18 @@ class Executor:
             left, right = right, left
 
         with jax.named_scope("otb.join_probe"):
-            lkey, lhashed, lcheck = self._join_key(node.left_keys, left)
+            lkey, lhashed, lcheck, _ = self._join_key(node.left_keys, left)
         with jax.named_scope("otb.join_build"):
-            rkey, rhashed, rcheck = self._join_key(node.right_keys, right)
-        skeys, perm = K.join_build(rkey, right.valid)
-        lo, counts = K.join_probe_counts(skeys, lkey, left.valid)
+            rkey, rhashed, rcheck, span = self._join_key(node.right_keys,
+                                                         right)
+        # one sort and one probe algorithm per program, chosen here, when
+        # the program is built, from what the host knows of the build
+        # key's range; nothing known (a computed or hashed key, a column
+        # whose codec proves no range) takes the algorithms that need no
+        # range
+        skeys, perm = K.join_build(rkey, right.valid, key_span=span)
+        lo, counts = K.join_probe_counts(skeys, lkey, left.valid,
+                                         key_span=span)
 
         hash_recheck = []
         if lhashed or rhashed:
@@ -855,7 +893,7 @@ class Executor:
             mask = K.semi_mask(counts) if node.kind == "semi" \
                 else K.anti_mask(counts, left.valid)
             return DBatch(left.cols, left.valid & mask, left.types,
-                          left.dicts, left.nulls, left.lazy)
+                          left.dicts, left.nulls, left.lazy, left.spans)
 
         left_outer = node.kind in ("left", "full")
         total = jnp.sum(jnp.where(left.valid, jnp.maximum(counts, 1), 0)) \
@@ -913,7 +951,7 @@ class Executor:
             mask = hits > 0 if node.kind == "semi" else \
                 (left.valid & (hits == 0))
             return DBatch(left.cols, left.valid & mask, left.types,
-                          left.dicts, left.nulls, left.lazy)
+                          left.dicts, left.nulls, left.lazy, left.spans)
         if left_outer:
             null_ext = null_right
             if hash_recheck or node.residual:

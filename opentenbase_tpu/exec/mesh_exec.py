@@ -663,8 +663,19 @@ class MeshRunner:
         new_valid = jax.lax.all_to_all(
             mask.reshape(ndn, bucket), self.axis, 0, 0).reshape(-1)
         return (DBatch(cols, new_valid, dict(b.types), dict(b.dicts),
-                       nulls),
+                       nulls, spans=dict(b.spans)),
                 jax.lax.psum(overflow, self.axis))
+
+    def _a2a_sent_bytes(self, rb) -> int:
+        """Bytes ONE chip sends over ICI in the all_to_all that produced
+        `rb`, known when the program is traced: ndn - 1 of the ndn equal
+        buckets of every column, null mask and the validity flags (the
+        chip's own bucket does not leave it)."""
+        ndn = self.cluster.ndn
+        whole = sum(int(a.size) * a.dtype.itemsize
+                    for a in (*rb.cols.values(), *rb.nulls.values(),
+                              rb.valid))
+        return whole // ndn * (ndn - 1)
 
     def _broadcast_batch(self, b):
         from .executor import DBatch
@@ -677,7 +688,8 @@ class MeshRunner:
 
         return DBatch({n: ag(a) for n, a in b.cols.items()},
                       ag(b.valid), dict(b.types), dict(b.dicts),
-                      {n: ag(a) for n, a in b.nulls.items()})
+                      {n: ag(a) for n, a in b.nulls.items()},
+                      spans=dict(b.spans))
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -1054,6 +1066,7 @@ class MeshRunner:
             ex_batches: dict = {}
             overflows = []
             meta["ex_order"] = []
+            meta["exchanges"] = meta["exchange_bytes"] = 0
             join_reqs = []
             gather_out: dict = {}
             gather_over: list = []
@@ -1075,6 +1088,9 @@ class MeshRunner:
                                 b, ex.keys, mults.get(ex.index, 1))
                         ex_batches[ex.index] = rb
                         meta["ex_order"].append(ex.index)
+                        if rb is not b:
+                            meta["exchanges"] += 1
+                            meta["exchange_bytes"] += self._a2a_sent_bytes(rb)
                         overflows.append(over)
                     elif ex.kind == "broadcast":
                         with jax.named_scope("otb.exchange"):
@@ -1153,11 +1169,16 @@ class MeshRunner:
         # the execute span covers the program call and the overflow
         # device_gets — the mesh tier's one legal sync point per call,
         # so the span's wall time includes the device work
-        with obs_trace.span("execute", tier="mesh"):
+        with obs_trace.span("execute", tier="mesh") as sp:
             with stats_tier("mesh"):
                 # executor counters inside the trace attribute to the
                 # mesh tier (first call of a fresh program traces here)
                 outs, a2a_over_vec, join_over, g_over_vec = fn(*flat_args)
+            # the all_to_all exchanges this program holds and the bytes
+            # one chip sends in them: fixed when the program was traced
+            # (meta is filled by the trace the first call made)
+            sp.set(exchanges=meta.get("exchanges", 0),
+                   exchange_bytes=meta.get("exchange_bytes", 0))
             plancache.MESH.record_call(fn, t0)
             if EXPORT_HOOK is not None:
                 EXPORT_HOOK("mesh", fn, tuple(flat_args))

@@ -357,6 +357,7 @@ class QueryTrace:
         ms = dict.fromkeys(_SUMMED, 0.0)
         staged = materialized = overlapped = 0.0
         fetches = fetch_bytes = 0
+        exchanges = exchange_bytes = 0
         hits = misses = 0
         work = [(self.root, ())]
         while work:
@@ -377,6 +378,9 @@ class QueryTrace:
                 elif name == "finalize.fetch":
                     fetches += a.get("fetches", 0) or 0
                     fetch_bytes += a.get("bytes", 0) or 0
+                elif name == "execute":
+                    exchanges += a.get("exchanges", 0) or 0
+                    exchange_bytes += a.get("exchange_bytes", 0) or 0
                 elif name == "pool":
                     if a.get("hit") is True:
                         hits += 1
@@ -413,6 +417,8 @@ class QueryTrace:
         d["finalize_decode_ms"] = ms["finalize.decode"]
         d["finalize_fetches"] = int(fetches)
         d["finalize_fetch_bytes"] = int(fetch_bytes)
+        d["exchanges"] = int(exchanges)
+        d["exchange_bytes"] = int(exchange_bytes)
         d["unattributed_ms"] = self.root.self_ms()
         return d
 

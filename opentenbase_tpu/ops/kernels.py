@@ -30,6 +30,7 @@ import numpy as np
 from ..utils.dtypes import device_float
 
 INT64_MAX = np.int64(2**63 - 1)
+INT64_MIN = np.int64(-2**63)
 
 
 def _scoped(scope: str):
@@ -348,63 +349,73 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
 # join: sort build side once, probe with binary search, expand pairs
 # ---------------------------------------------------------------------------
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("key_span",))
 @_scoped("otb.join_build")
-def join_build(build_keys, build_valid):
+def join_build(build_keys, build_valid, key_span: int | None = None):
     """Sort the build side; invalid rows get key INT64_MAX so they sort
     last and can never match a (clamped) probe key.
 
-    Fast path (same single-word trick as grouped_agg_sort): when the
-    key range times n fits 62 bits, (key, position) pack into one int64
-    and a single-array `jnp.sort` replaces the 2-operand comparator
-    argsort (~4x on XLA CPU); hashed/full-range keys take the exact
-    argsort branch."""
+    ONE algorithm per program, chosen when the program is built:
+    `key_span` is what the host knows of the keys (an upper bound on
+    max - min over the valid rows: storage/codec.span_bound of the key
+    column's class), None when it knows nothing (hashed multi-column
+    keys, computed keys).  When the bound times n fits 62 bits, (key -
+    min, position) pack into one int64 and a single-array `jnp.sort`
+    does it (the single-word trick of grouped_agg_sort; ~4x a
+    2-operand comparator sort on XLA CPU); otherwise the exact argsort.
+    The choice used to be a `lax.cond` on the shard's own span: both
+    sorts compiled into every program, and which one ran was the
+    data's."""
     n = build_keys.shape[0]
-    keys = jnp.where(build_valid, build_keys, INT64_MAX)
-    i64 = jnp.iinfo(jnp.int64)
-    mn = jnp.min(jnp.where(build_valid, build_keys, i64.max))
-    mx = jnp.max(jnp.where(build_valid, build_keys, i64.min))
-    # uint64 span: int64 subtraction wraps for ranges past 2^63
-    # (hashed multi-column keys) and would fake a tiny range
-    span = jnp.where(mx >= mn,
-                     mx.astype(jnp.uint64) - mn.astype(jnp.uint64),
-                     jnp.uint64(0))
-    bits = jnp.log2(span.astype(jnp.float32) + 2) + \
-        jnp.log2(jnp.float32(n + 2))
-    pack_ok = (bits < jnp.float32(62.0)) & jnp.any(build_valid)
-
-    def fast(_):
+    if key_span is not None and (key_span + 2) * (n + 1) < 1 << 62:
+        # a NULL key arrives as INT64_MAX (executor._join_key): outside
+        # the host's bound, and unmatchable like an invalid row
+        ok = build_valid & (build_keys != INT64_MAX)
+        mn = jnp.min(jnp.where(ok, build_keys, INT64_MAX))
         iota = jnp.arange(n, dtype=jnp.int64)
-        rng = span.astype(jnp.int64) + 1   # gated: span < 2^62
-        acc = jnp.where(build_valid,
-                        jnp.clip(build_keys - mn, 0, rng - 1), rng)
-        word = acc * n + iota
-        sw = jnp.sort(word)
+        rng = key_span + 1
+        acc = jnp.where(ok, jnp.clip(build_keys - mn, 0, rng - 1), rng)
+        sw = jnp.sort(acc * n + iota)
         perm = sw % n
         acc_s = sw // n
-        sk = jnp.where(acc_s >= rng, INT64_MAX, acc_s + mn)
-        return sk, perm
-
-    def exact(_):
-        perm = jnp.argsort(keys)
-        return keys[perm], perm
-
-    return jax.lax.cond(pack_ok, fast, exact, None)
+        return jnp.where(acc_s >= rng, INT64_MAX, acc_s + mn), perm
+    keys = jnp.where(build_valid, build_keys, INT64_MAX)
+    perm = jnp.argsort(keys)
+    return keys[perm], perm
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("key_span",))
 @_scoped("otb.join_probe")
-def join_probe_counts(sorted_keys, probe_keys, probe_valid):
+def join_probe_counts(sorted_keys, probe_keys, probe_valid,
+                      key_span: int | None = None):
     """Per-probe-row match range in the sorted build side.
 
-    Two runtime strategies under one `lax.cond`:
-    - direct-address (dense keys — TPC-H order/cust/supp keys are
-      near-contiguous): scatter the build rows into a [key-min,
-      key-max] table, probe = ONE gather (measured 2.1M probes into
-      131k build: ~35ms vs 340ms for two binary searches on XLA CPU);
+    Two strategies, ONE per program, chosen when the program is built:
+    direct-address where the host-known `key_span` (join_build's) fits
+    a table of T = max(2 * nb, np_) cells (enough for dense SQL keys:
+    TPC-H order/cust/supp keys are near-contiguous, without exceeding
+    the probe-side footprint class), else the binary search.  A
+    function of shapes and of program-key material alone.
+    - direct-address (dense keys): scatter the build rows into a
+      [key-min, key-min + T) table, probe = ONE gather (measured on a
+      v5e, 1,572,864 probes into 131,072 build rows: 99 ms against 874
+      ms for the binary search, PERF.md section 6 PR 27);
     - binary search with ONE `searchsorted` (the right edge comes from
       a run-end table built by a suffix-min scan on the small build
-      side: 205ms) for sparse/hashed key spaces.
+      side) for sparse, hashed or unknown key spaces; over int32
+      offsets where the host-known span fits 32 bits.
+    The choice used to be a `lax.cond` on the shard's own span.
+
+    A device gather's time depends on WHICH addresses its lanes ask
+    for, so what an invalid probe row looks up matters: every one of
+    them searches INT64_MIN and walks the same leftmost path (mids
+    nb/2, nb/4, ... 0) whatever the build side holds.  They used to
+    search INT64_MAX - 1 and home in on the boundary between the live
+    keys and the invalid-build sentinels, a path set by the live COUNT:
+    on a v5e half of Q3's 1.5 M lineitem probes (the filtered rows)
+    then read one address per step, and the step cost 26.0 or 29.9 ms
+    by whether that count was under or over 38,912 (PERF.md section 6,
+    PR 27).
 
     INT64_MAX is a reserved key value (the invalid-build sentinel): a
     valid probe row carrying it is treated as unmatchable rather than
@@ -412,54 +423,55 @@ def join_probe_counts(sorted_keys, probe_keys, probe_valid):
     """
     nb = sorted_keys.shape[0]
     np_ = probe_keys.shape[0]
-    pk = jnp.where(probe_valid, probe_keys, INT64_MAX - 1)
     usable = probe_valid & (probe_keys != INT64_MAX)
     if not nb:
         return (jnp.zeros(np_, dtype=jnp.int64),
                 jnp.zeros(np_, dtype=jnp.int64))
 
-    live = sorted_keys != INT64_MAX
-    mn = sorted_keys[0]
-    mx = jnp.max(jnp.where(live, sorted_keys, jnp.iinfo(jnp.int64).min))
-    # direct-address table size: enough for dense SQL keys (TPC-H
-    # orderkey/custkey/suppkey are near-contiguous) without exceeding
-    # the probe-side footprint class.  Range measured in uint64 — the
-    # int64 difference wraps for full-range key spaces and would
-    # wrongly pick the direct table.
     T = max(2 * nb, np_)
-    span = jnp.where(mx >= mn,
-                     mx.astype(jnp.uint64) - mn.astype(jnp.uint64),
-                     jnp.uint64(1) << 63)
-    direct_ok = live[0] & (mx >= mn) & (span < jnp.uint64(T))
-
-    def direct(_):
+    if key_span is not None and key_span < T:
+        live = sorted_keys != INT64_MAX
+        mn = sorted_keys[0]     # INT64_MAX when no build row is live
         idx = jnp.arange(nb, dtype=jnp.int64)
         cell = jnp.where(live, jnp.clip(sorted_keys - mn, 0, T - 1), T)
         lo_tab = jnp.full(T + 1, nb, dtype=jnp.int64).at[cell].min(
             idx, mode="drop")
         cnt_tab = jnp.zeros(T + 1, dtype=jnp.int64).at[cell].add(
             1, mode="drop")
-        off = pk - mn
-        inb = usable & (off >= 0) & (off < T)
-        loc = jnp.clip(off, 0, T - 1)
+        # the offset in uint64: an int64 difference wraps for probe keys
+        # far below the build side's (a full-range key space)
+        off = probe_keys.astype(jnp.uint64) - mn.astype(jnp.uint64)
+        inb = usable & (probe_keys >= mn) & (off < jnp.uint64(T))
+        loc = jnp.where(inb, off, jnp.uint64(0)).astype(jnp.int64)
         cnt = jnp.where(inb, cnt_tab[loc], 0)
         lo = jnp.where(cnt > 0, lo_tab[loc], 0)
         return lo, cnt
 
-    def searched(_):
-        lo = jnp.searchsorted(sorted_keys, pk,
-                              side="left").astype(jnp.int64)
-        idx = jnp.arange(nb, dtype=jnp.int64)
-        chg = jnp.concatenate([sorted_keys[1:] != sorted_keys[:-1],
-                               jnp.ones(1, bool)])
-        nxt = jnp.where(chg, idx + 1, nb)
-        end = jax.lax.associative_scan(jnp.minimum, nxt[::-1])[::-1]
-        loc = jnp.clip(lo, 0, nb - 1)
-        hit = sorted_keys[loc] == pk
-        counts = jnp.where(usable & hit, end[loc] - lo, 0)
-        return lo, counts
-
-    return jax.lax.cond(direct_ok, direct, searched, None)
+    if key_span is not None and key_span < (1 << 31) - 1:
+        # the host-known span fits 32 bits: search int32 offsets from the
+        # build side's smallest key, ONE gather a step where an int64
+        # key costs the chip two (it has no 64-bit lanes); the invalid
+        # build rows' sentinel is the first offset past the span, a
+        # probe outside the span searches -1 like an invalid one
+        live = sorted_keys != INT64_MAX
+        mn = sorted_keys[0]     # INT64_MAX when no build row is live
+        sk = jnp.where(live, sorted_keys - mn,
+                       key_span + 1).astype(jnp.int32)
+        off = probe_keys.astype(jnp.uint64) - mn.astype(jnp.uint64)
+        ok = usable & (probe_keys >= mn) & (off <= jnp.uint64(key_span))
+        pk = jnp.where(ok, off.astype(jnp.int32), jnp.int32(-1))
+    else:
+        sk, ok = sorted_keys, usable
+        pk = jnp.where(probe_valid, probe_keys, INT64_MIN)
+    lo = jnp.searchsorted(sk, pk, side="left").astype(jnp.int32)
+    idx = jnp.arange(nb, dtype=jnp.int32)
+    chg = jnp.concatenate([sk[1:] != sk[:-1], jnp.ones(1, bool)])
+    nxt = jnp.where(chg, idx + 1, nb)
+    end = jax.lax.associative_scan(jnp.minimum, nxt[::-1])[::-1]
+    loc = jnp.clip(lo, 0, nb - 1)
+    hit = sk[loc] == pk
+    counts = jnp.where(ok & hit, end[loc] - lo, 0)
+    return lo.astype(jnp.int64), counts.astype(jnp.int64)
 
 
 @functools.partial(jax.jit, static_argnames=("out_size", "left_outer"))

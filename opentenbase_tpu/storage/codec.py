@@ -33,9 +33,12 @@ values, persisted like the join ladder (exec/fused.py _JOIN_LADDER):
 Program-key discipline (analysis/cardinality.py codec-key rule): the
 only encoding-derived value that may reach program-key material is the
 quantized class token from codec_class() — family + width (+ pow2 LUT
-capacity), e.g. "pack8", "for16", "dict8/256".  Widths are an enum,
-capacities quantize through batch.lut_capacity, so the key domain
-stays bounded and otbcard's cardinality proof holds.  Aux CONTENTS
+capacity, or the proven code limit of a 32-bit class), e.g. "pack8",
+"for16", "dict8/256", "pack32/6291456".  Widths are an enum, capacities
+quantize through batch.lut_capacity and code limits through
+batch.size_class, so the key domain stays bounded and otbcard's cardinality proof holds.  The
+token is also what the host KNOWS about a key column's range when it
+builds a program (span_bound).  Aux CONTENTS
 (references, LUT values) are traced data, never key material.
 
 The per-(table, column) descriptor ladder is process-global so every
@@ -54,11 +57,12 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import re
 
 import numpy as np
 
 from ..utils import locks
-from .batch import lut_capacity
+from .batch import lut_capacity, size_class
 
 #: staged-namespace prefix for codec aux arrays: FOR references and
 #: dictionary LUTs ride the staged dict as traced program inputs — the
@@ -81,6 +85,13 @@ class Enc:
     orig: str     # original staged dtype str ("int64", "int32", ...)
     lo: int = 0   # for: reference (code = v - lo + 1; 0 = padding)
     cap: int = 0  # dict: pow2 LUT capacity incl the sentinel slot
+    limit: int = 0  # pack32/for32: proven code limit (a size class);
+    #                 0 = the width's 2**width
+
+    @property
+    def code_limit(self) -> int:
+        """Every code of the column is below this."""
+        return self.limit or 1 << self.width
 
     @property
     def code_dtype(self):
@@ -129,7 +140,28 @@ def codec_class(enc) -> str:
         return "raw"
     if enc.family == "dict":
         return f"dict{enc.width}/{enc.cap}"
+    if enc.limit:
+        return f"{enc.family}{enc.width}/{enc.limit}"
     return f"{enc.family}{enc.width}"
+
+
+_RANGED = re.compile(r"(?:pack|for)(\d+)(?:/(\d+))?")
+
+
+def span_bound(cls) -> "int | None":
+    """What a class token proves about the column's staged values: an
+    upper bound on max - min (pack: 0 <= v < limit; for: codes
+    1 .. limit - 1 above one reference), or None where the class
+    proves no range (raw, dictionaries).  Host knowledge the executor
+    may choose an algorithm by when it BUILDS a program (the join
+    kernels' direct-address table and packed sort, ops/kernels.py): the
+    token is program-key material, so a column that outgrows the bound
+    re-chooses its descriptor and the program is built anew."""
+    m = _RANGED.fullmatch(cls) if isinstance(cls, str) else None
+    if m is None:
+        return None
+    width, limit = m.groups()
+    return (int(limit) if limit else 1 << int(width)) - 1
 
 
 def codec_classes(store) -> tuple:
@@ -171,6 +203,21 @@ def _range_width(span: int):
     return None
 
 
+def _proven_limit(max_code: int, width: int) -> int:
+    """The proven code limit of a 32-bit class, or 0 where the width
+    says it all: uint32 codes are the class of every key column past
+    65,534 values (TPC-H custkey 150,000, orderkey 6,000,000), and
+    2**32 bounds no table a join could address directly.  Quantized to
+    batch.size_class's quarter steps (the ladder the staged tables'
+    own padding rides: orderkey's 6,000,000 proves 6,291,456, the
+    width lineitem pads to), so the key domain stays an enum and a
+    growing column re-chooses at most four times per doubling."""
+    if width != 32:
+        return 0
+    limit = size_class(max_code + 1, floor=1 << 16)
+    return limit if limit < 1 << 32 else 0
+
+
 def _fits_locked(st: _ColState, h) -> bool:
     """Do these values fit the persisted descriptor without widening?
     (Dictionaries may still extend append-only within capacity.)"""
@@ -181,19 +228,22 @@ def _fits_locked(st: _ColState, h) -> bool:
         return True
     vmin, vmax = int(h.min()), int(h.max())
     if enc.family == "pack":
-        return vmin >= 0 and vmax <= (1 << enc.width) - 1
+        return vmin >= 0 and vmax <= enc.code_limit - 1
     if enc.family == "for":
-        return vmin >= enc.lo and vmax - enc.lo <= (1 << enc.width) - 2
+        return vmin >= enc.lo and vmax - enc.lo <= enc.code_limit - 2
     u = np.unique(h)
     new = sum(1 for v in u if int(v) not in st.index)
     return len(st.values) + new + 1 <= enc.cap
 
 
-def _choose_locked(h, prev=None) -> _ColState:
+def _choose_locked(h, prev=None, refine=True) -> _ColState:
     """Choose a descriptor from the actual values.  `prev` is the
     outgrown state, if any — an outgrown DICTIONARY extends its
     append-only value list into a larger capacity (codes already
-    resident elsewhere stay valid) instead of rebuilding."""
+    resident elsewhere stay valid) instead of rebuilding.  `refine`
+    asks for a 32-bit class's proven code limit (_proven_limit): not for
+    the MVCC system columns, which no join keys on and whose ids and
+    timestamps only ever grow."""
     orig = str(h.dtype)
     if h.size == 0:
         # nothing provable yet: stage raw WITHOUT pinning, so the
@@ -219,14 +269,16 @@ def _choose_locked(h, prev=None) -> _ColState:
 
     pack_w = _range_width(vmax) if vmin >= 0 else None
     for_w = None
+    drifts = False
     if vmin > np.iinfo(h.dtype).min:  # lo - 1 must be representable
         for_w = _range_width(vmax - vmin)
         if for_w is not None and vmin >= (1 << 40):
             # wall-clock-scale reference (MVCC timestamps): appends
             # drift forward forever, so a width proven on today's span
             # would promote on every batch — start at 32 bits (still
-            # 2x narrower than the int64 original)
+            # 2x narrower than the int64 original), all of them
             for_w = max(for_w, 32)
+            drifts = True
     best = None
     for fam, w in (("pack", pack_w), ("for", for_w)):
         if w is not None and w // 8 < itemsize \
@@ -242,7 +294,9 @@ def _choose_locked(h, prev=None) -> _ColState:
         return _ColState(None)
     fam, w = best
     lo = vmin if fam == "for" else 0
-    return _ColState(Enc(fam, w, orig, lo=lo))
+    limit = _proven_limit(vmax - lo + (fam == "for"), w) \
+        if refine and not (drifts and fam == "for") else 0
+    return _ColState(Enc(fam, w, orig, lo=lo, limit=limit))
 
 
 def _dict_geometry(nvals: int):
@@ -284,11 +338,11 @@ def _encode_locked(st: _ColState, h):
         return np.zeros(0, enc.code_dtype)
     vmin, vmax = int(h.min()), int(h.max())
     if enc.family == "pack":
-        if vmin < 0 or vmax > (1 << enc.width) - 1:
+        if vmin < 0 or vmax > enc.code_limit - 1:
             return None
         return h.astype(enc.code_dtype)
     if enc.family == "for":
-        if vmin < enc.lo or vmax - enc.lo > (1 << enc.width) - 2:
+        if vmin < enc.lo or vmax - enc.lo > enc.code_limit - 2:
             return None
         return (h.astype(np.int64)
                 - np.int64(enc.lo - 1)).astype(enc.code_dtype)
@@ -324,7 +378,8 @@ def encode_staged(table: str, name: str, h):
             return None               # proven-raw pin: stays raw
         codes = _encode_locked(st, h) if st is not None else None
         if codes is None:
-            st = _choose_locked(h, prev=st)
+            st = _choose_locked(h, prev=st,
+                                refine=not name.startswith("__"))
             _LADDER[key] = st
             _save_locked()
             if st.enc is None:
@@ -386,7 +441,8 @@ def ensure_classes(store, host_cols: dict) -> dict:
                 st = _LADDER.get(key)
                 if st is None or (st.enc is not None
                                   and not _fits_locked(st, h)):
-                    st = _choose_locked(h, prev=st)
+                    st = _choose_locked(h, prev=st,
+                                        refine=not name.startswith("__"))
                     _LADDER[key] = st
                     _save_locked()
                 if st.enc is not None:
@@ -493,7 +549,8 @@ def _load_locked():  # holds: _STATE_LOCK
             _LADDER[key] = _ColState(None)
         else:
             enc = Enc(d["family"], int(d["width"]), d["orig"],
-                      lo=int(d.get("lo", 0)), cap=int(d.get("cap", 0)))
+                      lo=int(d.get("lo", 0)), cap=int(d.get("cap", 0)),
+                      limit=int(d.get("limit", 0)))
             _LADDER[key] = _ColState(enc, d.get("values"))
 
 
@@ -508,7 +565,8 @@ def _save_locked():
             d["family"] = "raw"
         else:
             d.update(family=st.enc.family, width=st.enc.width,
-                     orig=st.enc.orig, lo=st.enc.lo, cap=st.enc.cap)
+                     orig=st.enc.orig, lo=st.enc.lo, cap=st.enc.cap,
+                     limit=st.enc.limit)
             if st.enc.family == "dict":
                 d["values"] = list(st.values)
         out.append(d)

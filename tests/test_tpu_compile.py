@@ -162,3 +162,55 @@ def test_redistribute_is_one_all_to_all_program(topo, tpu_mode):
     text = _compile(M.redistribute_program(mesh, ["k", "v"], "k", SMALL),
                     s(BOOL), s(I64), s(I64))
     assert "all-to-all" in text
+
+
+def test_q3_mesh_program_on_four_chips(topo, tpu_mode, tmp_path):
+    """The cell tpch_sf1_mesh4's Q3 (every parameter pinned), whole: the
+    program the mesh tier builds over four DataNodes, exported from a CPU
+    run in the chip's dtype mode and compiled for the described 2x2 (at
+    SF0.01's size classes: SF1's compile in minutes, on the chip, PERF.md
+    section 6).  The compiled text carries the exchange, and no
+    `conditional` under an `otb.join_*` scope: the join kernels' algorithm
+    is chosen when the program is built, not by the shard's data."""
+    from benchmarks.lib import datagen, files, mesh_check
+    from benchmarks.lib import stack as stack_mod
+    from benchmarks.lib.traffic import Mix, Request
+    from jax import export
+    from opentenbase_tpu.exec import mesh_exec
+
+    data = datagen.generate(sf=0.01, seed=27)
+    mesh_check.PROGRAMS.clear()
+    mesh_check.arm()
+    stack = stack_mod.Stack(4, str(tmp_path / "cluster"))
+    try:
+        client, session = stack.connect()
+        stack_mod.load_tpch(stack, client, data, (), str(tmp_path))
+        mix = Mix(files.workload("tpch_sf1_mesh4")["traffic"], 27, data)
+        mix.build_pools()
+        st = next(s for s in mix.statements if s.name == "q3_pinned")
+        req = mix.run_request(Request(st, *mix.pools[st.name][0]), client,
+                              session)
+        assert req.steps[0][4] is None and session.fallbacks == []
+    finally:
+        mesh_exec.EXPORT_HOOK = None
+        stack.stop()
+    (fn, shapes), = mesh_check.PROGRAMS.values()
+    mesh_check.PROGRAMS.clear()
+
+    mesh = Mesh(topo.devices, ("dn",))
+
+    def described(a):
+        if not isinstance(a, jax.ShapeDtypeStruct):
+            return a
+        sharded = a.sharding is not None
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=NamedSharding(mesh, P("dn") if sharded else P()))
+
+    exp = export.export(fn, platforms=("tpu",))(*shapes)
+    text = _compile(jax.jit(exp.call), *[described(a) for a in shapes])
+    assert "all-to-all" in text
+    conditionals = [ln for ln in text.splitlines() if " conditional(" in ln]
+    # the names survive the export: the sorted aggregate's pack test shows
+    assert any("otb.agg" in ln for ln in conditionals)
+    assert not [ln for ln in conditionals if "otb.join_" in ln]
