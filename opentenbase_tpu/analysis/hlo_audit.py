@@ -14,7 +14,8 @@ for
   itself free of both (``export_check(..., no_scatter_sort=True)``:
   finalize's live-row selection runs in front of every wide read, and a
   scatter with one update per input row costs more than the copy it
-  saves);
+  saves; the dense aggregate is a compare-and-reduce because such a
+  scatter serialises into its few cells);
 - ``hlo-conditional``    — a ``case``/``if`` in a kernel that declares
   itself free of them (``export_check(..., no_conditional=True)``: the
   join kernels choose their algorithm when the program is built, so a
@@ -126,11 +127,16 @@ def check_kernels(report: dict):
         v = jnp.zeros(n, bool)
         export_check(lambda m: K.live_positions(m, out_size=256), (v,),
                      f"live_positions/{n}", report, no_scatter_sort=True)
-        export_check(
-            lambda g, m, a: K.grouped_agg_dense(
-                g, m, a, num_groups=64,
-                agg_kinds=("sum", "count", "min", "max", "sumf")),
-            (i, v, (i, i, i, f, f)), f"grouped_agg_dense/{n}", report)
+        # the dense aggregate is a compare-and-reduce at every domain
+        # (Q1's 6 groups, Q5's 25): no scatter and no sort
+        for groups in (6, 25, 64):
+            export_check(
+                lambda g, m, a, groups=groups: K.grouped_agg_dense(
+                    g, m, a, num_groups=groups,
+                    agg_kinds=("sum", "count", "min", "max", "sumf")),
+                (i, v, (i, i, i, f, f)),
+                f"grouped_agg_dense/{n}/{groups}", report,
+                no_scatter_sort=True)
         export_check(
             lambda k, m, a: K.grouped_agg_sort(
                 k, m, a, max_groups=n,

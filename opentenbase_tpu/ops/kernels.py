@@ -9,9 +9,10 @@ here every operator is a static-shape array program:
 - dynamic result sizes are handled by (padded arrays + count) pairs with
   power-of-two size classes (storage/batch.py:next_pow2), so XLA compiles
   one program per size class, not per query;
-- group-by is either *dense* (scatter-add over a precomputed bounded group
-  id — the path TPC-H Q1 takes, no sort, pure VPU/MXU work) or *sort-based*
-  (lexicographic sort + segment reduce) for unbounded keys;
+- group-by is either *dense* (a compare-and-reduce over the rows against
+  a precomputed bounded group id — the path TPC-H Q1 takes, no sort, no
+  scatter) or *sort-based* (lexicographic sort + segment reduce) for
+  unbounded keys;
 - join is sort+binary-search (build side sorted once; probe via two
   searchsorted passes, then a static-size pair expansion) — the TPU-friendly
   replacement for a chained hash table; multi-key joins combine via a 64-bit
@@ -164,14 +165,18 @@ def live_positions(valid, out_size: int):
 _AGG_KINDS = ("sum", "count", "min", "max", "sumf")
 
 
+def _extreme(dtype, kind: str):
+    """What an empty group's min or max reads: the dtype's far end."""
+    if jnp.issubdtype(dtype, jnp.integer):
+        info = jnp.iinfo(dtype)
+        return info.max if kind == "min" else info.min
+    return np.inf if kind == "min" else -np.inf
+
+
 def _masked_for(kind: str, vals, valid):
     if kind in ("min", "max"):
-        if jnp.issubdtype(vals.dtype, jnp.integer):
-            info = jnp.iinfo(vals.dtype)
-            fill = info.max if kind == "min" else info.min
-        else:
-            fill = np.inf if kind == "min" else -np.inf
-        return jnp.where(valid, vals, jnp.asarray(fill, vals.dtype))
+        return jnp.where(valid, vals,
+                         jnp.asarray(_extreme(vals.dtype, kind), vals.dtype))
     if kind == "sum" and jnp.issubdtype(vals.dtype, jnp.integer):
         vals = vals.astype(jnp.int64)  # SQL widens sum(int4) -> bigint
     return jnp.where(valid, vals, jnp.zeros((), vals.dtype))
@@ -183,29 +188,57 @@ def grouped_agg_dense(group_id, valid, agg_inputs: tuple,
                       num_groups: int, agg_kinds: tuple):
     """Aggregate with a precomputed dense group id in [0, num_groups).
 
-    The planner uses this when the grouping keys have a statically bounded
-    combined domain (dictionary codes, small ints): pure scatter-reduce,
-    no sort — the TPC-H Q1 path.
+    The planner uses this when the grouping keys have a statically
+    bounded combined domain (dictionary codes, BOOLs: up to 4,096 cells,
+    executor._exec_agg) and for an aggregate without keys (`num_groups`
+    1).  Compare-and-reduce: ONE variadic reduction over the rows of
+    `[num_groups, n]` operands XLA never materialises (the compare with
+    the group's index and each aggregate's select fuse into the
+    reduction's input), so the rows are read once for all aggregates.
+    No scatter, no sort: a scatter with one update per row serialises
+    into its few cells on the chip (65-110 ns a row and aggregate, 2.9 s
+    of TPC-H Q1's reply at SF1), while this costs ~0.1 ms a group at
+    6.3 M rows and stays ahead of the scatter up to ~65,536 groups
+    (PERF.md section 6, PR 28).  A caller with a larger domain wants a
+    scatter arm back.
+
+    Outputs are `[num_groups]` each: integer sums and counts int64 (they
+    wrap as a serial sum does: addition modulo 2**64 is associative;
+    only the row count is summed in int32, which n < 2**31 rows cannot
+    overflow), a min's or max's far end for an empty group, and nothing
+    from an invalid row or a group id out of range.
     """
-    gid = jnp.where(valid, group_id, num_groups)  # invalid -> overflow slot
-    outs = []
+    n = valid.shape[0]
+    in_range = valid & (group_id >= 0) & (group_id < num_groups)
+    gid = jnp.where(in_range, group_id, num_groups).astype(jnp.int32)
+    hot = gid[None, :] == jnp.arange(num_groups, dtype=jnp.int32)[:, None]
+    operands = [hot.astype(jnp.int32 if n < 2**31 else jnp.int64)]
+    inits, combine = [0], [jnp.add]
     for kind, vals in zip(agg_kinds, agg_inputs):
         if kind == "count":
-            vals = valid.astype(jnp.int64)
-        elif kind == "sumf":
-            vals = _masked_for("sum", vals.astype(device_float()), valid)
+            continue                    # the row count, reduced once
+        if kind == "sumf":
+            vals = vals.astype(device_float())
+        elif kind == "sum" and jnp.issubdtype(vals.dtype, jnp.integer):
+            vals = vals.astype(jnp.int64)  # SQL widens sum(int4) -> bigint
+        if kind in ("min", "max"):
+            inits.append(_extreme(vals.dtype, kind))
+            combine.append(jnp.minimum if kind == "min" else jnp.maximum)
         else:
-            vals = _masked_for(kind, vals, valid)
-        if kind == "min":
-            o = jax.ops.segment_min(vals, gid, num_segments=num_groups + 1)
-        elif kind == "max":
-            o = jax.ops.segment_max(vals, gid, num_segments=num_groups + 1)
-        else:
-            o = jax.ops.segment_sum(vals, gid, num_segments=num_groups + 1)
-        outs.append(o[:num_groups])
-    present = jax.ops.segment_sum(valid.astype(jnp.int64), gid,
-                                  num_segments=num_groups + 1)[:num_groups]
-    return tuple(outs), present
+            inits.append(0)
+            combine.append(jnp.add)
+        operands.append(jnp.where(hot, vals[None, :],
+                                  jnp.asarray(inits[-1], vals.dtype)))
+    reduced = jax.lax.reduce(
+        tuple(operands),
+        tuple(jnp.asarray(i, o.dtype) for i, o in zip(inits, operands)),
+        lambda a, b: tuple(f(x, y) for f, x, y in zip(combine, a, b)),
+        (1,))
+    present = reduced[0].astype(jnp.int64)
+    rest = iter(reduced[1:])
+    outs = tuple(present if kind == "count" else next(rest)
+                 for kind in agg_kinds)
+    return outs, present
 
 
 def _sortable_int(k, valid):

@@ -1,6 +1,7 @@
 """Device kernels vs numpy oracles (runs on CPU backend; same code path
 runs on TPU)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -59,6 +60,104 @@ class TestGroupedAggDense:
             jnp.asarray(gid), jnp.ones(10, bool), (jnp.asarray(vals),),
             1, ("sumf",))
         assert float(s[0]) == pytest.approx(15.0)
+
+
+_DENSE_KINDS = ("sum", "count", "min", "max", "sumf")
+_DENSE_INPUTS = ("wrapping_int64", "int32_widens", "decimal_scaled",
+                 "all_invalid", "one_empty_group", "stray_ids_on_invalid")
+#: an aggregate without keys, Q1's and Q5's domains, the largest domain
+#: executor._exec_agg sends here, and one past it
+_DENSE_GROUPS = (1, 6, 25, 4096, 4097)
+
+
+def _dense_case(inputs: str, g: int, n: int = 389):
+    """(group ids, valid, integer column, float column) of one case."""
+    r = np.random.default_rng(len(inputs) * 7919 + g)
+    gid = r.integers(0, g, n).astype(np.int64)
+    valid = r.random(n) > 0.25
+    ints = r.integers(-10**6, 10**6, n).astype(np.int64)
+    if inputs == "wrapping_int64":      # a group's sum passes 2**63
+        ints = r.choice(np.asarray([2**62, 2**62 + 5, -2**62, -2**62 - 9]),
+                        n).astype(np.int64)
+    elif inputs == "int32_widens":      # three rows overflow int32
+        ints = r.integers(2**30, 2**31 - 1, n).astype(np.int32)
+    elif inputs == "decimal_scaled":    # DECIMAL(15,2) x (1-d) x (1+t): 1e-6
+        ints = r.integers(90_000, 10_499_550, n) * \
+            r.integers(90, 101, n) * r.integers(100, 109, n)
+    elif inputs == "all_invalid":
+        valid = np.zeros(n, bool)
+    elif inputs == "one_empty_group":
+        valid &= gid != g - 1
+    elif inputs == "stray_ids_on_invalid":
+        gid = np.where(valid, gid, r.choice(
+            np.asarray([-1, -7, g, g + 3, 2**31 + 2, 2**40, -2**40]), n))
+    return gid, valid, ints, r.normal(0, 1e3, n)
+
+
+def _by_scatter(gid, valid, ints, g):
+    """The formulation the kernel had before: `segment_*`, one scatter
+    update per row into g + 1 cells (invalid rows into the last)."""
+    cell = jnp.where(valid, gid, g)
+    wide = jnp.where(valid, ints.astype(jnp.int64), 0)
+    return (jax.ops.segment_sum(wide, cell, g + 1)[:g],
+            jax.ops.segment_sum(valid.astype(jnp.int64), cell, g + 1)[:g],
+            jax.ops.segment_min(ints, cell, g + 1)[:g],
+            jax.ops.segment_max(ints, cell, g + 1)[:g])
+
+
+class TestGroupedAggDenseExact:
+    """grouped_agg_dense against numpy and against the scatter it
+    replaced: integer kinds to the bit (sums wrap modulo 2**64 in every
+    order), `sumf` within float64's rounding of a reordered sum."""
+
+    @pytest.mark.parametrize("inputs", _DENSE_INPUTS)
+    @pytest.mark.parametrize("g", _DENSE_GROUPS)
+    def test_against_numpy_and_the_scatter(self, g, inputs):
+        gid, valid, ints, flts = _dense_case(inputs, g)
+        live = valid & (gid >= 0) & (gid < g)
+        wide = ints.astype(np.int64)
+        want_sum = np.zeros(g, np.int64)
+        np.add.at(want_sum, gid[live], wide[live])          # wraps
+        if inputs == "wrapping_int64" and g == 1:
+            assert int(want_sum[0]) != sum(int(v) for v in wide[live])
+        want_cnt = np.bincount(gid[live], minlength=g).astype(np.int64)
+        info = np.iinfo(ints.dtype)
+        want_min = np.full(g, info.max, ints.dtype)
+        np.minimum.at(want_min, gid[live], ints[live])
+        want_max = np.full(g, info.min, ints.dtype)
+        np.maximum.at(want_max, gid[live], ints[live])
+        want_f = np.zeros(g)
+        np.add.at(want_f, gid[live], flts[live])
+
+        gid, valid, ints = map(jnp.asarray, (gid, valid, ints))
+        (s, c, mn, mx, sf), present = K.grouped_agg_dense(
+            gid, valid, (ints,) * 4 + (jnp.asarray(flts),), g, _DENSE_KINDS)
+        for o, w in ((s, want_sum), (c, want_cnt), (mn, want_min),
+                     (mx, want_max), (present, want_cnt)):
+            assert o.dtype == w.dtype and o.shape == (g,)
+            np.testing.assert_array_equal(np.asarray(o), w)
+        np.testing.assert_allclose(np.asarray(sf), want_f,
+                                   rtol=1e-12, atol=1e-9)
+        for o, w in zip((s, c, mn, mx), _by_scatter(gid, valid, ints, g)):
+            assert o.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(o), np.asarray(w))
+
+    @pytest.mark.parametrize("g", [6, 25, 4096])
+    def test_program_has_no_scatter_and_no_sort(self, g):
+        """What the audit's kernel battery declares of this kernel
+        (analysis/hlo_audit.check_kernels), at Q1's and Q5's domains and
+        at the largest the executor sends."""
+        from opentenbase_tpu.analysis import hlo_audit
+        i, v = jnp.zeros(4096, jnp.int64), jnp.zeros(4096, bool)
+        report: dict = {}
+        hlo_audit.export_check(
+            lambda gid, m, a: K.grouped_agg_dense(
+                gid, m, a, num_groups=g, agg_kinds=_DENSE_KINDS),
+            (i, v, (i, i, i, i, i.astype(float))), f"dense/{g}", report,
+            no_scatter_sort=True)
+        assert report["programs"] == 1 and not report.get("export_errors")
+        rules = [f.rule for f in report.get("findings", [])]
+        assert "hlo-scatter-sort" not in rules
 
 
 class TestGroupedAggSort:
