@@ -305,7 +305,7 @@ def phase_load(sf, seed, cluster, client, copy_tables):
     data = datagen.generate(sf=sf, seed=seed)
     datagen_s = time.perf_counter() - t0
     client.execute(SCHEMA)                       # DDL over the wire
-    bulk = ClusterSession(cluster)               # bench.py's column path
+    bulk = ClusterSession(cluster)               # the column path
     rows, served0 = {}, dict(loader.SERVED)
     t0 = time.perf_counter()
     for tname in LOAD_ORDER:

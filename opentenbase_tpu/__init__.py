@@ -19,7 +19,7 @@ Layout (≈ reference layer map, SURVEY.md §1):
 - sql/       lexer/parser/analyzer (ref src/backend/parser)
 - plan/      logical+physical planner, FQS, distribution (ref optimizer, pgxc/plan)
 - exec/      host-side fragment executor over device kernels (ref executor)
-- ops/       JAX/Pallas kernel library (ref execExprInterp/nodeHash/nodeAgg hot loops)
+- ops/       JAX kernel library (ref execExprInterp/nodeHash/nodeAgg hot loops)
 - parallel/  shard map, locator, cluster 2PC, mesh collectives (ref
              pgxc/locator, forward, execRemote.c remote-2PC)
 - gtm/       timestamp-oracle service (ref src/gtm); distributed MVCC

@@ -1,7 +1,7 @@
 """History-based snapshot-isolation checker (Adya G1 / G-SI).
 
 Input: the bounded read/write history `utils/snapcheck.py` records
-under ``$OTB_SNAP_HISTORY`` during the chaos/zipf bench shards —
+under ``$OTB_SNAP_HISTORY`` while a workload runs —
 commits as ``{"t": "w", "sess", "gts", "writes": [[table, version],
 ...]}`` (post-commit store versions tagged with the commit GTS) and
 reads as ``{"t": "r", "sess", "gts", "src", "obs": [[table, version],
@@ -39,7 +39,7 @@ and reject:
   flagged.)
 
 Because wr/ww edges strictly increase commit GTS, reachability is
-pruned by GTS, keeping the check near-linear on bench histories.
+pruned by GTS, keeping the check near-linear in the history's length.
 
 CLI::
 

@@ -165,7 +165,7 @@ def dump_sql(session, batch_rows: int = 500) -> str:
     # the rows (the __gidx_* mapping tables themselves are excluded
     # from _topo_tables — CREATE GLOBAL INDEX rebuilds them, re-routed
     # for the restored cluster's topology); dropping these silently
-    # lost cluster-wide UNIQUE + point routing (ADVICE r5 #1)
+    # lost cluster-wide UNIQUE + point routing
     for t, cols in sorted(catalog.global_indexes.items()):
         for col, cinfo in sorted(cols.items()):
             uq = "unique " if cinfo.get("unique") else ""

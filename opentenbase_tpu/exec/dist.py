@@ -167,10 +167,6 @@ class DistExecutor:
         # when the mesh tier declined, fallback_reason says why
         self.tier: str = ""
         self.fallback_reason: str = ""
-        # staging wall time of the mesh run (ms): host->device upload
-        # cost, ~0 on a buffer-pool warm repeat (bench splits engine_ms
-        # into stage_ms vs compute_ms with it)
-        self.stage_ms: float = 0.0
 
     # ------------------------------------------------------------------
     def run(self, dp: DistPlan) -> DBatch:
@@ -258,7 +254,6 @@ class DistExecutor:
                         dp, self.snapshot_ts, self.txid, self.params)
                     mesh_ms = (_time.perf_counter() - t_run) * 1e3
                     top = dp.fragments[dp.top_fragment]
-                    self.stage_ms = runner.last_stage_ms
                     if self.instrument:
                         # mesh fragments execute as ONE shard_map
                         # program — each gathered fragment reports its

@@ -91,18 +91,11 @@ class ClusterSession:
         self.cluster = cluster
         self.txn: Optional[ClusterTxn] = None
         self.txn_aborted = False
-        # data plane of the last SELECT (surfaced in EXPLAIN ANALYZE and
-        # asserted by the mesh CI suite): 'mesh' | 'fqs' | 'host'.
-        # last_tier/last_fallback/last_stage_ms are DEPRECATED aliases —
-        # last_query_stats() is the trace-backed replacement
-        self.last_tier = ""
-        self.last_fallback = ""
-        # mesh staging wall time of the last SELECT (ms): ~0 when the
-        # device buffer pool served every table warm
-        self.last_stage_ms = 0.0
-        # cumulative tier usage + fallback reasons: the CI proof that the
-        # device data plane carries the benchmark suites with no silent
-        # host fallbacks
+        # how SELECTs ran: last_query_stats() answers for the last
+        # statement (tier, fallback, per-phase ms); these two count
+        # every SELECT's data plane ('mesh' | 'fqs' | 'gidx' | 'host')
+        # and keep the host tier's fallback reasons, tracing on or off
+        # (the proof that no suite falls back to the host in silence)
         self.tier_counts: dict[str, int] = {}
         self.fallbacks: list[str] = []
         self._last_trace = None     # see last_query_stats
@@ -183,9 +176,9 @@ class ClusterSession:
     def last_query_stats(self) -> dict:
         """Trace-backed per-phase breakdown of the most recent
         statement on this session (plan/stage/execute/exchange/
-        finalize ms, tier, rows, bytes, pool hit counts) — the unified
-        replacement for the last_tier/last_stage_ms attribute pairs.
-        Empty when OTB_TRACE=0.  The trace of a statement that came
+        finalize ms, tier, fallback reason, rows, bytes, pool hit
+        counts).  Empty when OTB_TRACE=0: `tier_counts` and `fallbacks`
+        count on regardless.  The trace of a statement that came
         over the wire is the CN server's and is still open while the
         reply is on its way: read then, every span counts as of now and
         `wire_ms` lacks part of the send; the finished trace in
@@ -1016,7 +1009,7 @@ class ClusterSession:
                 # slots carry this coordinator's identity + a lease so
                 # a crashed CN can't permanently shrink the group's
                 # cluster-wide concurrency (the GTM reaps on lease
-                # expiry and on connection close; ADVICE r5 #3)
+                # expiry and on connection close)
                 owner = self._resq_owner()
                 try:
                     lease = float(c.gucs.get("resgroup_lease_s", "30"))
@@ -1084,11 +1077,6 @@ class ClusterSession:
             if queue is not None:
                 queue.release()
         names, rows = materialize(batch, dp.output_names)
-        # deprecated aliases (trace-backed last_query_stats() is the
-        # replacement surface; bench's mesh arm still reads these)
-        self.last_tier = ex.tier
-        self.last_stage_ms = ex.stage_ms
-        self.last_fallback = ex.fallback_reason
         self.tier_counts[ex.tier] = self.tier_counts.get(ex.tier, 0) + 1
         if ex.tier == "host" and ex.fallback_reason:
             self.fallbacks.append(ex.fallback_reason)

@@ -108,7 +108,7 @@ def exec_stats_rows() -> list:
 
 
 def exec_stats_snapshot() -> dict:
-    """Flat totals across tiers (bench delta accounting)."""
+    """Flat totals across tiers (delta accounting in tests)."""
     with STATS_LOCK:
         return {f: sum(EXEC_STATS[t][f] for t in EXEC_STATS)
                 for f in STAT_FIELDS}

@@ -39,7 +39,6 @@ and any other mutation drops the stale arrays lazily.
 from __future__ import annotations
 
 import dataclasses
-import threading
 import time
 from typing import Optional
 
@@ -119,13 +118,6 @@ class MeshRunner:
         # queries under one byte budget, with an incremental tail path
         # for append-only growth — this runner only assembles entries
         self._snapshots: dict = {}   # (dn_index, table) -> snapshot
-        # staging wall time of the last run on THIS thread: the runner
-        # is shared by every concurrent CN session, so a plain instance
-        # attribute would let query A's staging time leak into query
-        # B's stage_ms/compute_ms split (the serving tier runs many
-        # dispatches over one runner) — thread-local scopes the value
-        # per dispatch, and a thread that never staged reads 0.0
-        self._stage_tls = threading.local()
         # compiled shard_map programs live in the SHARED program cache
         # (exec/plancache.py MESH tier: bounded LRU, global
         # live-executable budget, hit/miss telemetry), keyed per
@@ -133,13 +125,6 @@ class MeshRunner:
         # observability surface (did THIS query compile or reuse?)
         self._programs: dict = {}
         self._ladder: dict = {}
-
-    @property
-    def last_stage_ms(self) -> float:
-        """Staging wall time of the last run ON THE CALLING THREAD
-        (0.0 if this thread never staged) — per-dispatch scoping for
-        concurrent sessions sharing the runner."""
-        return getattr(self._stage_tls, "ms", 0.0)
 
     # ------------------------------------------------------------------
     # plan screening
@@ -734,13 +719,11 @@ class MeshRunner:
             if not isinstance(v, (int, float, str, bool, type(None))):
                 raise MeshUnsupported("non-scalar init-plan param")
 
-        t_stage = time.perf_counter()
         staged = {}
         for t in sorted(tables):
             with obs_trace.span("stage", table=t, tier="mesh") as sp:
                 staged[t] = self._stage_table(t)
                 sp.set(padded=staged[t].padded)
-        self._stage_tls.ms = (time.perf_counter() - t_stage) * 1e3
         if not staged:
             raise MeshUnsupported("no mesh-stageable scans")
         base_pad = max((s.padded for s in staged.values()), default=64)

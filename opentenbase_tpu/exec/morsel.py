@@ -242,7 +242,7 @@ class MorselDriver:
         # is accounted to this token, so a shared stream's other
         # consumers can never be released by this one erroring
         self.token = workshare.new_token()
-        # per-stream instrumentation (bench --oob reads these)
+        # per-stream counts, folded into the module stats at the end
         self.chunks = 0
         self.downshifts = 0
         self.bytes_streamed = 0

@@ -19,7 +19,7 @@ segfaulted XLA:CPU at a few hundred programs.  Four pieces:
    batch — reuses the compiled executable.
 
 2. Persistent compilation cache — enable_persistent_cache(), called
-   by the entry points (ctl, bench.py, chip_smoke.py), keeps compiled
+   by the entry points (ctl, chip_smoke.py, benchmarks/), keeps compiled
    programs where $JAX_COMPILATION_CACHE_DIR says, else at one fixed
    path inside the checkout, so process restarts, `ctl start`, and
    repeated runs skip the XLA compile entirely.
@@ -501,7 +501,7 @@ _REPO_CACHE_DIR = os.path.join(
 def enable_persistent_cache() -> str:
     """Arm jax's persistent compilation cache so XLA compiles survive
     process restarts; returns the directory in force.  Called by the
-    entry points (ctl, bench.py, chip_smoke.py), never as a side effect
+    entry points (ctl, chip_smoke.py, benchmarks/), never as a side effect
     of opening a session or a cluster.
 
     Where $JAX_COMPILATION_CACHE_DIR is set, jax reads it itself and no
@@ -560,7 +560,7 @@ def warm_async(job) -> None:
 
 
 def warm_drain(timeout: float = 60.0) -> bool:
-    """Block until queued warmup jobs finish (tests/bench)."""
+    """Block until queued warmup jobs finish (tests)."""
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
         if _warm_q.unfinished_tasks == 0:

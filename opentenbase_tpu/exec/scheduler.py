@@ -94,8 +94,8 @@ _STATS: dict = {          # guarded_by: _STATS_LOCK
     "canceled": 0,        # cancel event consumed (queued or in-flight)
     # two-stage pipeline (otbpipe): dispatches whose finish-phase host
     # sync ran on the drainer thread, and how much staging wall time
-    # overlapped an in-flight device dispatch (the overlap ratio the
-    # bench reports — staging wait ≪ staging work once warm)
+    # overlapped an in-flight device dispatch (staging wait ≪ staging
+    # work once warm)
     "pipelined_dispatches": 0,
     "drained": 0,         # flights the drainer completed
     "stage_work_ms": 0.0,     # total staging wall time
@@ -250,8 +250,8 @@ class _Gone(Exception):
 
 class CancelEvent(threading.Event):
     """A cancel signal that can WAKE parked waiters.  A plain Event
-    forces `Scheduler.wait` to poll (the idle-spin the --qps bench saw
-    as wasted CPU at low load); this variant notifies every registered
+    forces `Scheduler.wait` to poll (an idle-spin that wastes CPU at
+    low load); this variant notifies every registered
     per-item condition when it fires, so waiters park on their
     completion CV and still observe an out-of-band cancel promptly.
     The CN server hands one of these to every connection session."""
@@ -628,10 +628,14 @@ class Scheduler:
              tuple(v for _n, v, _t in item.info.lits), item.vkey),
             item.snap, names, rows, rowcount=len(rows),
             budget=workshare.cache_budget(gucs))
-        if snapcheck.history_on():
+        if snapcheck.history_on() \
+                and item.info.version_key() == item.vkey:
             # the producing execution is itself a primary read at
             # item.snap over the captured version tuple — the SI
-            # checker cross-checks cache hits against it
+            # checker cross-checks cache hits against it.  Not when a
+            # DML raced it: versions taken before and a tag drawn
+            # after name no snapshot the statement read at (the entry
+            # is unservable; the record would be a false stale read)
             snapcheck.note_read(id(item.session), item.snap,
                                 "primary", obs=item.vkey)
 

@@ -505,17 +505,6 @@ class Session:
         qt = getattr(self, "_last_trace", None)
         return qt.summary() if qt is not None else {}
 
-    @property
-    def last_stage_ms(self) -> float:
-        # deprecated alias: staging time now comes from the trace
-        # (kept for callers that predate last_query_stats()).  Reports
-        # the overlap-ADJUSTED wait (stage_wait_ms) so pipelined
-        # staging hidden behind device compute doesn't count as time
-        # this statement stalled; falls back to raw stage_ms for
-        # traces without overlap attribution.
-        st = self.last_query_stats()
-        return float(st.get("stage_wait_ms", st.get("stage_ms", 0.0)))
-
     # ------------------------------------------------------------------
     def _begin_implicit(self) -> tuple[TxnState, bool]:
         if self.txn is not None:

@@ -122,7 +122,7 @@ class GtmCore:
     # connection) between acquire and release can no longer leak the
     # slot forever — expired leases are reaped at the next acquire, and
     # the TCP server reaps a connection's owners on disconnect,
-    # mirroring gtm_resqueue.c's per-connection cleanup (ADVICE r5 #3).
+    # mirroring gtm_resqueue.c's per-connection cleanup.
     def _resq_slots(self, group: str) -> list:
         # caller holds self._lock; slots: [owner, lease_deadline]
         rq = getattr(self, "_resq", None)

@@ -502,7 +502,7 @@ class PgWireServer:
         """Emit a portal's rows honoring the Execute row limit: a
         truncating limit sends PortalSuspended ('s') and KEEPS the
         portal's position so the next Execute resumes — previously the
-        rows past the limit were silently lost (ADVICE r5 #4)."""
+        rows past the limit were silently lost."""
         res = ent["res"]
         rows = res.rows or []
         if res.names:

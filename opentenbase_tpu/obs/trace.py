@@ -393,6 +393,7 @@ class QueryTrace:
             "trace_id": self.trace_id,
             "signature": self.signature,
             "tier": self.tier or "single",
+            "fallback": self.root.attrs.get("fallback", ""),
             "total_ms": self.total_ms,
             "rows": self.rows,
             "bytes_staged": int(staged),

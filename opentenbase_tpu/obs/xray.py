@@ -265,7 +265,7 @@ def _prune(d: dict, width: int, depth: int) -> None:
 
 
 # ---------------------------------------------------------------------------
-# per-DN remote phase rollup (EXPLAIN ANALYZE / bench --trace)
+# per-DN remote phase rollup (EXPLAIN ANALYZE)
 # ---------------------------------------------------------------------------
 
 def remote_rows(qt=None) -> list:

@@ -66,7 +66,7 @@ def ensure_scheduler(cluster) -> "JobScheduler":
 
 
 def resume_jobs(cluster) -> None:
-    """Restart survival (ADVICE r5 #2): a cluster initializing with
+    """Restart survival: a cluster initializing with
     non-empty persisted catalog.jobs starts the launcher immediately —
     previously only the CREATE JOB DDL path did, so scheduled jobs
     silently stopped after every ctl start / Cluster(datadir=...)."""

@@ -116,7 +116,7 @@ _STATE_LOADED = False  # guarded_by: _STATE_LOCK
 
 def enabled() -> bool:
     """Codec escape hatch: OTB_CODEC=0 stages every column raw (the
-    bit-identity A/B arm in bench.py and tests/test_codec.py)."""
+    bit-identity A/B arm of tests/test_codec.py)."""
     return os.environ.get("OTB_CODEC", "1") != "0"
 
 
@@ -587,7 +587,7 @@ def ladder_snapshot() -> list:
 
 
 def reset_state():
-    """Drop the descriptor ladder (tests / bench arm isolation)."""
+    """Drop the descriptor ladder (isolation between tests)."""
     global _STATE_LOADED
     with _STATE_LOCK:
         _LADDER.clear()

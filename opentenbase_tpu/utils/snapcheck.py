@@ -36,9 +36,9 @@ the runtime half of the otbsnap trilogy (static half:
   (with source = primary/cache/replica/shared/pool/standby) and
   commits (write sets with commit GTS) append to a bounded in-memory
   history; :func:`save_history` writes it for the post-hoc Adya-style
-  G1/G-SI checker (``analysis/sicheck.py``), which the chaos/zipf
-  bench shards run to certify the three serving tiers against each
-  other.
+  G1/G-SI checker (``analysis/sicheck.py``), which certifies the
+  serving tiers against the commits they raced
+  (tests/test_visibility.py runs it over a concurrent workload).
 
 Fast path: the flag is ONE env read per serve (``enabled()``), and
 every hook site guards with ``if snapcheck.enabled():`` so argument

@@ -28,5 +28,5 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def pytest_configure(config):
     config.addinivalue_line(
-        "markers", "slow: long-running bench / end-to-end arms "
+        "markers", "slow: long-running end-to-end arms "
         "(deselected by the tier-1 run)")

@@ -47,17 +47,17 @@ def host_oracle(cs, sql):
 class TestMeshResidency:
     def test_warm_repeat_stages_nothing(self, cs):
         r1 = cs.query(Q_JOIN)
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         t0 = POOL.totals()
         r2 = cs.query(Q_JOIN)
         t1 = POOL.totals()
         assert r2 == r1
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         # both tables resident: zero host->device upload, 100% hit rate
         assert t1["uploaded_bytes"] - t0["uploaded_bytes"] == 0
         assert t1["misses"] - t0["misses"] == 0
         assert t1["hits"] - t0["hits"] >= 2
-        assert cs.last_stage_ms < 50.0
+        assert cs.last_query_stats()["stage_ms"] < 50.0
 
     def test_warm_repeat_zero_table_staging(self, cs, monkeypatch):
         """Zero device_put of TABLE columns on a warm repeat: every
@@ -66,7 +66,7 @@ class TestMeshResidency:
         reassembly still makes small device transfers)."""
         from opentenbase_tpu.storage.store import TableStore
         cs.query(Q_AGG)
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         calls = []
         real = TableStore.host_live_columns
 
@@ -76,18 +76,18 @@ class TestMeshResidency:
 
         monkeypatch.setattr(TableStore, "host_live_columns", counting)
         cs.query(Q_AGG)
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         assert not calls, "warm repeat re-staged table columns"
 
     def test_insert_takes_tail_path(self, cs):
         r1 = cs.query(Q_AGG)
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         cs.execute("insert into t values (100, 1, 7.00, 'g1'), "
                    "(101, 2, 8.00, 'gX')")
         t0 = POOL.totals()
         r2 = cs.query(Q_AGG)
         t1 = POOL.totals()
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         # only the appended tail crossed host->device (the new 'gX'
         # dictionary value extends the union in place)
         assert t1["tail_rows"] - t0["tail_rows"] >= 2
@@ -97,7 +97,7 @@ class TestMeshResidency:
         cs.cluster._mesh_runner = None
         POOL.clear()
         r3 = cs.query(Q_AGG)
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         assert r3 == r2
 
     def test_update_delete_invalidate(self, cs):
@@ -108,7 +108,7 @@ class TestMeshResidency:
             cs.execute(dml)
             got = cs.query(Q_AGG)
             t1 = POOL.totals()
-            assert cs.last_tier == "mesh"
+            assert cs.last_query_stats()["tier"] == "mesh"
             assert t1["invalidations"] > t0["invalidations"], dml
             assert got == host_oracle(cs, Q_AGG), dml
 
@@ -131,13 +131,13 @@ class TestMeshResidency:
     def test_vacuum_invalidates(self, cs):
         cs.execute("delete from t where k < 10")
         before = cs.query(Q_AGG)
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         t0 = POOL.totals()
         from opentenbase_tpu.parallel.maintenance import vacuum_cluster
         assert vacuum_cluster(cs.cluster, "t") == 10
         got = cs.query(Q_AGG)
         t1 = POOL.totals()
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         assert got == before
         assert t1["invalidations"] > t0["invalidations"]
 

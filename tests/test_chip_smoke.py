@@ -148,7 +148,7 @@ s.execute("insert into u values " + ", ".join(
     f"({100 + i}, {i % 64})" for i in range(96)))
 s.execute("set enable_mesh_exchange = on")
 s.query("select sum(price * (1 - disc) * (1 + tax)) from t, u where k = tk")
-assert s.last_tier == "mesh" and texts
+assert s.last_query_stats()["tier"] == "mesh" and texts
 print(hashlib.md5("".join(texts).encode()).hexdigest())
 """
 

@@ -239,7 +239,7 @@ class TestBitIdentity:
             f"({i}, {10 ** 12 + i % 6})" for i in range(80)))
         q = "select sum(v) from cdk where k <= {}"
         got = cs.query(q.format(40))
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         assert any(t == "cdk" and cls != "raw"
                    for t, _c, cls in codec.ladder_snapshot())
         c0, h0 = plancache.MESH.compiles, plancache.MESH.hits

@@ -374,7 +374,7 @@ class TestNodeGroupRecovery:
 
 
 class TestSelfReferencingFk:
-    """ADVICE r4: the delete-side orphan scan must include the table's
+    """The delete-side orphan scan must include the table's
     own self-FKs (reference: ri_triggers.c enforces them identically)."""
 
     @pytest.fixture(autouse=True)
@@ -404,7 +404,7 @@ class TestSelfReferencingFk:
 
 
 class TestPartitionConstraintInheritance:
-    """ADVICE r4: CHECK/FK declared on a partitioned parent must be
+    """CHECK/FK declared on a partitioned parent must be
     enforced for rows routed to partition children (reference:
     ExecConstraints runs after ExecFindPartition)."""
 

@@ -95,7 +95,7 @@ class TestRoundTrip:
 
 class TestGlobalIndexDump:
     def test_global_index_round_trip(self):
-        """ADVICE r5 #1: dump emits CREATE [UNIQUE] GLOBAL INDEX so a
+        """Dump emits CREATE [UNIQUE] GLOBAL INDEX so a
         restored cluster keeps cluster-wide uniqueness and gidx point
         routing (the __gidx_* mapping tables are rebuilt, re-routed
         for the restored topology)."""

@@ -46,7 +46,7 @@ class TestRouting:
         calls = _count_touches(s)
         assert s.query("select id, name from emp where badge = 1042") \
             == [(42, "e42")]
-        assert s.last_tier == "gidx"
+        assert s.last_query_stats()["tier"] == "gidx"
         # mapping lookup + (in-process fast path) base exec: <= 2 nodes
         assert calls["n"] <= 2
 
@@ -60,14 +60,14 @@ class TestRouting:
         s.execute("create unique global index gi_badge on emp (badge)")
         calls = _count_touches(s)
         assert s.query("select id from emp where badge = 99999") == []
-        assert s.last_tier == "gidx"
+        assert s.last_query_stats()["tier"] == "gidx"
         assert calls["n"] <= 1
 
     def test_guc_disables_route(self, s):
         s.execute("create unique global index gi_badge on emp (badge)")
         s.execute("set enable_global_indexscan = off")
         assert s.query("select id from emp where badge = 1042") == [(42,)]
-        assert s.last_tier != "gidx"
+        assert s.last_query_stats()["tier"] != "gidx"
 
     def test_non_selective_key_falls_through_correctly(self, s):
         # dozens of rows share cat=3 across nodes: no single-node pin,
@@ -86,7 +86,7 @@ class TestMaintenance:
         s.execute("create unique global index gi_badge on emp (badge)")
         s.execute("insert into emp values (500, 9500, 'new')")
         assert s.query("select id from emp where badge = 9500") == [(500,)]
-        assert s.last_tier == "gidx"
+        assert s.last_query_stats()["tier"] == "gidx"
         s.execute("update emp set badge = 9501 where id = 500")
         assert s.query("select id from emp where badge = 9501") == [(500,)]
         assert s.query("select id from emp where badge = 9500") == []
@@ -180,7 +180,7 @@ class TestDdl:
                   "distribute by shard(id)")
         s.execute("insert into emp values (7, 1042, 'fresh')")
         assert s.query("select id from emp where badge = 1042") == [(7,)]
-        assert s.last_tier != "gidx"
+        assert s.last_query_stats()["tier"] != "gidx"
 
     def test_drop_index(self, s):
         s.execute("create unique global index gi_badge on emp (badge)")
@@ -260,6 +260,6 @@ class TestPersistence:
         s.cluster.checkpoint()
         s2 = ClusterSession(Cluster(datadir=str(tmp_path / "cl")))
         assert s2.query("select id from emp where badge = 200") == [(2,)]
-        assert s2.last_tier == "gidx"
+        assert s2.last_query_stats()["tier"] == "gidx"
         s2.execute("insert into emp values (3, 300)")
         assert s2.query("select id from emp where badge = 300") == [(3,)]

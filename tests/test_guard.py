@@ -605,6 +605,62 @@ class TestChaosFailover:
         rows = dict((r[0], r[1]) for r in guard.health_rows())
         assert rows[key] == "up"
 
+    def test_flapping_dn_point_reads_never_lie(self, tcp_cluster,
+                                               monkeypatch):
+        """Point reads over SQL while dn0's wire flaps.  Inside the
+        retry budget every tear is absorbed: no error, no wrong row.
+        Past it a read may fail but never lies, the breaker's trip
+        leaves a flight bundle that survives JSON, and after the
+        cooldown every read answers again."""
+        import json
+        from opentenbase_tpu.obs import xray
+        s, servers, gtm, d = tcp_cluster
+        s.execute("create table fk (k bigint primary key, v bigint) "
+                  "distribute by shard(k)")
+        s.execute("insert into fk values " + ", ".join(
+            f"({i}, {i * 3})" for i in range(64)))
+
+        def read(k):
+            return s.query(f"select v from fk where k = {k}")
+
+        # a breaker takes its knobs when it is built: rebuild the guards
+        monkeypatch.setenv("OTB_RPC_RETRIES", "3")
+        monkeypatch.setenv("OTB_BREAKER_THRESHOLD", "16")
+        guard.reset()
+        retries0 = _counter_value("otb_guard_retries_total")
+        for i in range(48):
+            if i % 8 == 0:
+                FI.arm_wire("dn0.recv", "close", times=2)
+            assert read(i % 64) == [((i % 64) * 3,)]
+        FI.disarm_wire()
+        assert _counter_value("otb_guard_retries_total") > retries0
+
+        monkeypatch.setenv("OTB_RPC_RETRIES", "0")
+        monkeypatch.setenv("OTB_BREAKER_THRESHOLD", "3")
+        monkeypatch.setenv("OTB_BREAKER_COOLDOWN", "0.1")
+        guard.reset()
+        key = s.cluster.datanodes[0].guard_key
+        FI.arm_wire("dn0.recv", "close", times=6)
+        failed = 0
+        for k in range(24):
+            try:
+                rows = read(k)
+            except Exception:   # noqa: BLE001 — a torn read may fail
+                failed += 1
+                continue
+            assert rows == [(k * 3,)]
+        FI.disarm_wire()
+        assert failed >= 3
+        assert _counter_value("otb_guard_breaker_trips_total") >= 1
+        trips = [b for b in xray.flights()
+                 if b["kind"] == "breaker_trip" and b["signature"] == key]
+        assert trips, [b["kind"] for b in xray.flights()]
+        for b in trips:
+            assert json.loads(json.dumps(b))["kind"] == "breaker_trip"
+        time.sleep(0.15)
+        for k in range(24):
+            assert read(k) == [(k * 3,)]
+
     def test_dead_dn_reads_fail_over_to_standby(self, tcp_cluster):
         """The tentpole acceptance: kill one DN mid-workload; read-only
         fragments re-dispatch to its promoted standby with ZERO wrong

@@ -77,7 +77,7 @@ class TestJobs:
         assert cl2.catalog.jobs["pj"]["interval_s"] == 60.0
 
     def test_jobs_resume_after_restart(self, tmp_path):
-        """Restart survival (ADVICE r5 #2): a cluster initializing with
+        """Restart survival: a cluster initializing with
         persisted catalog.jobs runs them WITHOUT any new CREATE JOB —
         previously the scheduler only started from the DDL path, so
         every ctl start silently stopped all scheduled work."""

@@ -149,6 +149,6 @@ class TestGatherTrim:
         s.execute("set enable_mesh_exchange = on")
         got = s.query("select grp, count(*), sum(v) from g "
                       "group by grp order by grp")
-        assert s.last_tier == "mesh"
+        assert s.last_query_stats()["tier"] == "mesh"
         assert [r[:2] for r in got] == [(0, 1667), (1, 1667), (2, 1666)]
         assert seen and max(seen) == 256, seen   # 6 live rows, not 2x2560

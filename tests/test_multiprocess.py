@@ -248,7 +248,8 @@ class TestTcpMeshTier:
             "select nm, sum(v) from f, dm where f.g = dm.g "
             "group by nm"))
         assert got == [(100, 1350), (200, 1450), (300, 1550)]
-        assert s.last_tier == "mesh", s.last_fallback
+        st = s.last_query_stats()
+        assert st["tier"] == "mesh", st["fallback"]
 
     def test_snapshot_cache_invalidates_on_write(self, tcp_cluster):
         s, *_ = tcp_cluster
@@ -256,12 +257,12 @@ class TestTcpMeshTier:
                   "distribute by shard(k)")
         s.execute("insert into w values (1, 10), (2, 20), (3, 30)")
         assert s.query("select count(*), sum(v) from w") == [(3, 60)]
-        t1 = s.last_tier
+        st = s.last_query_stats()
         s.execute("update w set v = v + 1 where k = 2")
         assert s.query("select count(*), sum(v) from w") == [(3, 61)]
         s.execute("delete from w where k = 1")
         assert s.query("select count(*), sum(v) from w") == [(2, 51)]
-        assert t1 == "mesh", s.last_fallback
+        assert st["tier"] == "mesh", st["fallback"]
 
 
 class TestConnectionPool:

@@ -52,7 +52,8 @@ class TestMultiColumnShardKeys:
                             "w": [i * 2 for i in range(100)]})
         m = df1.merge(df2, on=["a", "b"])
         assert cs.query(q) == [(len(m), int((m.v + m.w).sum()))]
-        assert cs.last_tier == "mesh", cs.last_fallback
+        st = cs.last_query_stats()
+        assert st["tier"] == "mesh", st["fallback"]
 
     def test_partial_key_join_redistributes(self, cs):
         cs.execute("create table p1 (a bigint, b bigint) "

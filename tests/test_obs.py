@@ -403,11 +403,13 @@ class TestSingleTier:
         top = re.search(r"actual rows=(\d+)", r.text.splitlines()[0])
         assert top and int(top.group(1)) == want
 
-    def test_deprecated_stage_alias(self, single_env):
+    def test_stage_wait_is_stage_without_overlap(self, single_env):
+        # a serial statement hides no staging behind device compute:
+        # the overlap-adjusted wait is the whole of the staging time
         s = single_env
         s.query(Q[1])
-        assert s.last_stage_ms == pytest.approx(
-            s.last_query_stats().get("stage_ms", 0.0))
+        st = s.last_query_stats()
+        assert st["stage_wait_ms"] == pytest.approx(st["stage_ms"])
 
 
 # ---------------------------------------------------------------------------
@@ -437,6 +439,44 @@ class TestClusterTier:
         assert st["rows"] == len(rows)
         assert st["tier"] in ("mesh", "host", "local", "fqs", "gidx")
         assert st["total_ms"] > 0 and st["execute_ms"] > 0
+
+    @pytest.mark.parametrize("traced", [True, False],
+                             ids=["traced", "untraced"])
+    @pytest.mark.parametrize("setup, sql, tier, reason", [
+        ("", "select count(*), sum(o_totalprice) from orders",
+         "mesh", ""),
+        ("", "select o_totalprice from orders where o_orderkey = 1",
+         "fqs", ""),
+        ("set work_mem_rows = 16",
+         "select count(*), sum(o_totalprice) from orders",
+         "host", "work_mem_rows budget"),
+    ], ids=["mesh", "fqs", "host"])
+    def test_how_a_select_ran(self, cluster_env, monkeypatch, setup, sql,
+                              tier, reason, traced):
+        """The one answer to "how did that statement run":
+        last_query_stats() names the tier and the fallback reason of
+        the last statement, and tier_counts / fallbacks move by exactly
+        one SELECT whether or not the statement was traced."""
+        s = ClusterSession(cluster_env.cluster)   # counters start empty
+        if setup:
+            cluster_env.execute(setup)            # GUCs are the cluster's
+        monkeypatch.setattr(obs_trace, "ENABLED", traced)
+        try:
+            s.query(sql)
+            st = s.last_query_stats()
+        finally:
+            monkeypatch.setattr(obs_trace, "ENABLED", True)
+            if setup:
+                cluster_env.execute("set work_mem_rows = 0")
+        assert s.tier_counts == {tier: 1}
+        assert len(s.fallbacks) == (1 if reason else 0)
+        assert all(reason in r for r in s.fallbacks)
+        if traced:
+            assert st["tier"] == tier
+            assert reason in st["fallback"]
+            assert bool(st["fallback"]) == bool(reason)
+        else:
+            assert st == {}
 
     def test_warm_q1_stage_is_zero_with_full_pool_hits(self, cluster_env):
         s = cluster_env

@@ -31,7 +31,7 @@ class TestPrepared:
                   "select v, note from kv where k = $1")
         assert s.query("execute getv (7)") == [(70, "n7")]
         # light-coordinator path: whole statement shipped to ONE datanode
-        assert s.last_tier == "fqs"
+        assert s.last_query_stats()["tier"] == "fqs"
         assert s.query("execute getv (33)") == [(330, "n33")]
         assert s.prepared["getv"].mode == "plan"
         assert s.prepared["getv"].router is not None
@@ -49,7 +49,7 @@ class TestPrepared:
         assert s.query("execute agg1 (25)") == [(24, 9000)]
         assert s.query("execute agg1 (40)") == [(9, 4050)]
         # no single-node pin -> the distributed plan (mesh tier)
-        assert s.last_tier == "mesh"
+        assert s.last_query_stats()["tier"] == "mesh"
 
     def test_text_param_substitution_mode(self, s):
         s.execute("prepare byname (varchar(16)) as "

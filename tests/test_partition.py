@@ -58,7 +58,8 @@ class TestRangePartitions:
         device data plane still carries the query."""
         assert cs.query("select sum(v) from m "
                         "where d < '1999-04-01'") == [(40,)]
-        assert cs.last_tier == "mesh", cs.last_fallback
+        st = cs.last_query_stats()
+        assert st["tier"] == "mesh", st["fallback"]
 
     def test_update_delete_through_parent(self, cs):
         cs.execute("update m set v = v + 1 where d >= '1999-04-01'")

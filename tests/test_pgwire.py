@@ -297,7 +297,7 @@ class TestDescribeAndFetchSize:
     """Describe-driven drivers (JDBC, async fetch-size clients): a
     SELECT portal Describe answers a REAL RowDescription, and a
     row-limited Execute sends PortalSuspended and keeps the portal's
-    position for the next Execute (ADVICE r5 #4)."""
+    position for the next Execute."""
 
     def _drive(self, c, msgs):
         """Send raw extended-protocol messages + Sync; return the

@@ -59,11 +59,11 @@ class TestCanonicalSignatures:
                    + ", ".join(f"({i}, {i * 3})" for i in range(40)))
         assert cs.query("select sum(v) from lit_m where k <= 9")[0][0] \
             == sum(i * 3 for i in range(10))
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         c0, h0 = _mesh().compiles, _mesh().hits
         assert cs.query("select sum(v) from lit_m where k <= 29")[0][0] \
             == sum(i * 3 for i in range(30))
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         assert _mesh().compiles == c0, \
             "an autoprep'd literal change must reuse the mesh program"
         assert _mesh().hits > h0
@@ -126,7 +126,7 @@ class TestAotWarmup:
         c0, h0 = _mesh().compiles, _mesh().hits
         r = cs.query("execute wq (9)")
         assert r[0][0] == sum(range(10))
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         assert _mesh().hits > h0
         assert _mesh().compiles == c0, \
             "EXECUTE after PREPARE warmup must find the program compiled"
@@ -147,7 +147,7 @@ class TestAotWarmup:
         # a DIFFERENT literal: the traced-param program still serves it
         assert cs.query("select sum(v) from ws_t where k <= 9")[0][0] \
             == sum(range(10))
-        assert cs.last_tier == "mesh"
+        assert cs.last_query_stats()["tier"] == "mesh"
         assert _mesh().compiles == c0, \
             "warm_statement must precompile the ad-hoc mesh program"
 

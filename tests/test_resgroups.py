@@ -122,8 +122,9 @@ class TestStagingBudget:
         # rg has 5000 rows > 1000 budget: the mesh (whole-table HBM
         # staging) tier must be bypassed for the spill tier
         assert s.query("select count(*) from rg") == [(5000,)]
-        assert s.last_tier != "mesh"
-        assert "budget" in (s.last_fallback or "")
+        st = s.last_query_stats()
+        assert st["tier"] != "mesh"
+        assert "budget" in st["fallback"]
         s.execute("set resource_group = none")
         s.query("select count(*) from rg")
 
@@ -188,7 +189,7 @@ class TestStatView:
 
 
 class TestSlotLeases:
-    """Per-slot acquirer identity + lease reaping (ADVICE r5 #3): a
+    """Per-slot acquirer identity + lease reaping: a
     crashed coordinator can no longer permanently shrink a group's
     cluster-wide concurrency."""
 

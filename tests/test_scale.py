@@ -72,7 +72,8 @@ class TestMeshAtScale:
     @pytest.mark.parametrize("qn", [1, 3, 5])
     def test_mesh_matches_single(self, qn, cs, single):
         got = cs.query(Q[qn])
-        assert cs.last_tier == "mesh", cs.last_fallback
+        st = cs.last_query_stats()
+        assert st["tier"] == "mesh", st["fallback"]
         rows_close(got, single.query(Q[qn]))
 
 

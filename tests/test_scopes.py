@@ -133,7 +133,7 @@ def test_mesh_program_on_four_devices_names_its_exchange(programs,
     # u is sharded on uk and joins on tk: its rows are redistributed
     rows = s.query("select g, sum(price) from t, u where k = tk "
                    "group by g order by g")
-    assert len(rows) == 5 and s.last_tier == "mesh"
+    assert len(rows) == 5 and s.last_query_stats()["tier"] == "mesh"
     mesh = [t for tag, t in programs if tag == "mesh"]
     assert mesh
     text = mesh[0]

@@ -70,7 +70,8 @@ class TestViews:
                    "where sal > 70")
         assert sorted(cs.query("select * from rich")) == \
             [(1, 100), (2, 80)]
-        assert cs.last_tier == "mesh", cs.last_fallback
+        st = cs.last_query_stats()
+        assert st["tier"] == "mesh", st["fallback"]
 
     def test_view_name_collision_with_table(self, sess):
         with pytest.raises(ExecError):

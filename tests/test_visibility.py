@@ -345,6 +345,83 @@ class TestSiChecker:
 
 
 # ---------------------------------------------------------------------------
+# the certificate over a real concurrent run
+# ---------------------------------------------------------------------------
+
+class TestSiCertificate:
+    """Readers through the coalescing scheduler (result cache, buffer
+    pool, primary) race a DML writer with the sanitizer live; the
+    history they leave holds no G1/G-SI anomaly and the sanitizer saw
+    no violation."""
+
+    def test_concurrent_serving_history_certifies(self, monkeypatch,
+                                                  tmp_path):
+        import threading
+        from opentenbase_tpu.exec import scheduler as sm
+        from opentenbase_tpu.exec import share
+        from opentenbase_tpu.exec.session import LocalNode, Session
+        monkeypatch.setenv("OTB_SNAPCHECK", "1")
+        monkeypatch.setenv("OTB_SNAP_HISTORY", str(tmp_path / "h.json"))
+        snapcheck.reset()
+        sm.reset_stats()
+        share.RESULT_CACHE.clear()
+        node = LocalNode()
+        Session(node).execute(
+            "create table kv (k bigint, v bigint); "
+            "insert into kv values " + ", ".join(
+                f"({i}, {i * 7})" for i in range(64)))
+        # the writer only touches keys >= 1000
+        want = {"select v from kv where k = 3": [(21,)],
+                "select count(*) from kv where k < 64": [(64,)]}
+        reads = list(want)
+        wrong, errs = [], []
+
+        def reader(i):
+            sess = Session(node)
+            try:
+                for j in range(24):
+                    sql = reads[(i + j) % 2]
+                    rows = sched.run(sess, sql)[-1].rows
+                    if rows != want[sql]:
+                        wrong.append((sql, rows))
+            except Exception as e:   # noqa: BLE001 — asserted below
+                errs.append(e)
+
+        def writer():
+            sess = Session(node)
+            try:
+                for j in range(16):
+                    k = 1000 + j // 2
+                    sched.run(sess, f"insert into kv values ({k}, {j})"
+                              if j % 2 == 0 else
+                              f"delete from kv where k = {k}")
+            except Exception as e:   # noqa: BLE001 — asserted below
+                errs.append(e)
+
+        with sm.Scheduler(node=node, window_ms=5.0) as sched:
+            threads = [threading.Thread(target=reader, args=(i,))
+                       for i in range(4)]
+            threads.append(threading.Thread(target=writer))
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=300)
+        try:
+            assert errs == [] and wrong == []
+            res = check_history(snapcheck.history_events())
+            assert res["anomalies"] == []
+            assert snapcheck.violations() == []
+            assert res["writes"] >= 16 and res["reads"] > 0
+            assert res["by_source"].get("cache", 0) > 0
+            assert res["by_source"].get("primary", 0) > 0
+            sm.assert_slot_balance()
+        finally:
+            snapcheck.reset()
+            share.RESULT_CACHE.clear()
+            sm.reset_stats()
+
+
+# ---------------------------------------------------------------------------
 # witnessed ⊆ statically-gated, on a real OTB_SNAPCHECK=1 workload
 # ---------------------------------------------------------------------------
 
