@@ -20,7 +20,11 @@ for
   itself free of them (``export_check(..., no_conditional=True)``: the
   join kernels choose their algorithm when the program is built, so a
   program's device time is a function of its shapes and not of which
-  arm the data took, and only one arm is compiled).
+  arm the data took, and only one arm is compiled);
+- ``hlo-wide-gather``    — a gather whose table or indices are i64 in a
+  kernel that declares itself free of them (``export_check(...,
+  no_wide_gather=True)``: the chip has no 64-bit lanes, so such a gather
+  is two word-gathers, and join_expand's positions all fit a word).
 
 ``python -m opentenbase_tpu.analysis.hlo_audit`` exports the kernel
 battery (add ``--full`` for the live query battery with fused/mesh
@@ -54,10 +58,15 @@ _DYNSHAPE = re.compile(
     r"|tensor<(\?|\d+x\?|[0-9x]*\?x)")
 _SCATTER_SORT = re.compile(r"stablehlo\.(scatter|sort)\b")
 _CONDITIONAL = re.compile(r"stablehlo\.(case|if)\b")
+# the op's type signature, `: (table, indices) -> result`, not its
+# attributes (`slice_sizes = array<i64: 1>` is no tensor)
+_WIDE_GATHER = re.compile(
+    r"stablehlo\.gather.*:\s*\([^)]*i64>[^)]*\)\s*->")
 
 
 def scan_hlo_text(label: str, txt: str, no_scatter_sort: bool = False,
-                  no_conditional: bool = False) -> list:
+                  no_conditional: bool = False,
+                  no_wide_gather: bool = False) -> list:
     """Scan one exported program's MLIR text; one finding per rule per
     program, at the first offending line."""
     findings = []
@@ -73,6 +82,9 @@ def scan_hlo_text(label: str, txt: str, no_scatter_sort: bool = False,
     if no_conditional:
         rules.append(("hlo-conditional", _CONDITIONAL,
                       "conditional in a kernel declared free of them"))
+    if no_wide_gather:
+        rules.append(("hlo-wide-gather", _WIDE_GATHER,
+                      "64-bit gather in a kernel declared free of them"))
     for rule, rx, msg in rules:
         m = rx.search(txt)
         if m:
@@ -93,7 +105,8 @@ def _sds_of(tree):
 
 def export_check(fn, args, label: str, report: dict,
                  no_scatter_sort: bool = False,
-                 no_conditional: bool = False):
+                 no_conditional: bool = False,
+                 no_wide_gather: bool = False):
     """Export `fn(*args)` for platform 'tpu'; scan the StableHLO and
     record findings (f64 hits also land in the legacy report keys)."""
     import jax
@@ -108,7 +121,8 @@ def export_check(fn, args, label: str, report: dict,
             f"{label}: {type(e).__name__}: {e}")
         return
     report["programs"] = report.get("programs", 0) + 1
-    for f in scan_hlo_text(label, txt, no_scatter_sort, no_conditional):
+    for f in scan_hlo_text(label, txt, no_scatter_sort, no_conditional,
+                           no_wide_gather):
         report.setdefault("findings", []).append(f)
         if f.rule == "hlo-f64":
             report.setdefault("f64", []).append(label)
@@ -160,7 +174,7 @@ def check_kernels(report: dict):
             lambda lo, c, p: K.join_expand(lo, c, p, out_size=2 * n,
                                            left_outer=True,
                                            probe_valid=None),
-            (i, i, i), f"join_expand/{n}", report)
+            (i, i, i), f"join_expand/{n}", report, no_wide_gather=True)
         export_check(K.semi_mask, (i,), f"semi_mask/{n}", report)
         export_check(lambda c, pv: K.anti_mask(c, pv), (i, v),
                      f"anti_mask/{n}", report)

@@ -926,7 +926,8 @@ class Executor:
         bi_safe = jnp.where(bi < 0, 0, bi) if left_outer else bi
 
         # late materialization: the join output carries both inputs'
-        # columns behind the fresh pair indices (pi / bi) — prior
+        # columns behind the fresh pair indices (pi / bi, int32: every
+        # index composed from them is a one-word table) — prior
         # indirections compose, payloads stay untouched until a
         # width-consuming operator materializes (SURVEY: move indices,
         # not payloads)
@@ -1078,11 +1079,8 @@ class Executor:
         copies = jnp.where(gvalid, copies, 0)
         total = int(jnp.sum(copies))
         out_size = next_pow2(max(total, 1))
-        csum = jnp.cumsum(copies)
-        j = jnp.arange(out_size, dtype=jnp.int64)
-        gi = jnp.searchsorted(csum, j, side="right")
-        gi = jnp.clip(gi, 0, max_groups - 1)
-        out_valid = j < total
+        gi = K.lane_rows(jnp.cumsum(copies), out_size)
+        out_valid = jnp.arange(out_size) < total
         cols, types, nulls = {}, {}, {}
         ki = 0
         for n in node.names:

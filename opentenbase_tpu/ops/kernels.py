@@ -13,10 +13,11 @@ here every operator is a static-shape array program:
   a precomputed bounded group id — the path TPC-H Q1 takes, no sort, no
   scatter) or *sort-based* (lexicographic sort + segment reduce) for
   unbounded keys;
-- join is sort+binary-search (build side sorted once; probe via two
-  searchsorted passes, then a static-size pair expansion) — the TPU-friendly
-  replacement for a chained hash table; multi-key joins combine via a 64-bit
-  hash with a residual equality filter added by the planner;
+- join is sort+search (build side sorted once; probe via a direct-address
+  table or one searchsorted pass, then a static-size pair expansion that
+  finds each output lane's rows by row gathers, in 32-bit words) — the
+  TPU-friendly replacement for a chained hash table; multi-key joins combine
+  via a 64-bit hash with a residual equality filter added by the planner;
 - all kernels take/return whole batches; invalid rows ride along masked.
 """
 
@@ -32,6 +33,7 @@ from ..utils.dtypes import device_float
 
 INT64_MAX = np.int64(2**63 - 1)
 INT64_MIN = np.int64(-2**63)
+_INT32_MIN = np.int32(-2**31)
 
 
 def _scoped(scope: str):
@@ -386,7 +388,8 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
 @_scoped("otb.join_build")
 def join_build(build_keys, build_valid, key_span: int | None = None):
     """Sort the build side; invalid rows get key INT64_MAX so they sort
-    last and can never match a (clamped) probe key.
+    last and can never match a (clamped) probe key.  Returns (sorted
+    keys, perm): perm int32, a position in a class a chip can hold.
 
     ONE algorithm per program, chosen when the program is built:
     `key_span` is what the host knows of the keys (an upper bound on
@@ -409,11 +412,11 @@ def join_build(build_keys, build_valid, key_span: int | None = None):
         rng = key_span + 1
         acc = jnp.where(ok, jnp.clip(build_keys - mn, 0, rng - 1), rng)
         sw = jnp.sort(acc * n + iota)
-        perm = sw % n
+        perm = (sw % n).astype(jnp.int32)
         acc_s = sw // n
         return jnp.where(acc_s >= rng, INT64_MAX, acc_s + mn), perm
     keys = jnp.where(build_valid, build_keys, INT64_MAX)
-    perm = jnp.argsort(keys)
+    perm = jnp.argsort(keys).astype(jnp.int32)
     return keys[perm], perm
 
 
@@ -507,6 +510,101 @@ def join_probe_counts(sorted_keys, probe_keys, probe_valid,
     return lo.astype(jnp.int64), counts.astype(jnp.int64)
 
 
+#: words per row of join_expand's tables: a row of a [n / 128, 128] int32
+#: array is one sublane of the chip's (8, 128) tile, and ONE row gather
+#: plus a compare over the row costs a third of one scalar gather there
+#: (4.9 against 14.3 ms at 1,572,864 lanes; 64 and 32 cost the same and
+#: are padded to 128 in HBM, 16 costs 10.7, 256 7.3: PERF.md section 6,
+#: PR 30)
+_ROW = 128
+#: the search's root: at most this many pivots are compared against
+#: every lane with no gather (a level less: 26.0 against 28.9 ms)
+_ROOT = 1024
+#: lanes per pass: a row gather leaves [lanes, _ROW] int32 in HBM, 1 GiB
+#: at 2**21 lanes; a larger class runs in passes of this many (6,291,456
+#: lanes: 83.5 ms and 1.3 GB in three passes, 78.1 ms and 3.3 GB in one)
+_MAX_LANES = 1 << 21
+
+
+def _rows_of(table, fill):
+    """`table` as rows of _ROW words, the last one padded with `fill`
+    (an empty table is one row of it)."""
+    n = table.shape[0]
+    return jnp.pad(table, (0, -n % _ROW if n else _ROW),
+                   constant_values=fill).reshape(-1, _ROW)
+
+
+def _take(rows, idx):
+    """`table[idx]` for `rows = _rows_of(table, ...)` and idx in range:
+    ONE row gather and a one-hot select over the row, where a scalar
+    gather costs the chip three times as much."""
+    hot = jnp.arange(_ROW, dtype=jnp.int32) == (idx % _ROW)[:, None]
+    return jnp.sum(jnp.where(hot, rows[idx // _ROW], 0), axis=1,
+                   dtype=rows.dtype)
+
+
+def _by_passes(fn, out_size: int):
+    """`fn(j)` over the lanes j = 0 .. out_size - 1, at most _MAX_LANES
+    of them at a time (fn returns per-lane arrays; a lane past out_size
+    in the last pass is computed and cut)."""
+    if out_size <= _MAX_LANES:
+        return fn(jnp.arange(out_size, dtype=jnp.int32))
+    passes = -(-out_size // _MAX_LANES)
+    j = jnp.arange(passes * _MAX_LANES, dtype=jnp.int32)
+    outs = jax.lax.map(fn, j.reshape(passes, _MAX_LANES))
+    return jax.tree.map(lambda o: o.reshape(-1)[:out_size], outs)
+
+
+def _lane_search(csum, out_size: int):
+    """j -> the first row whose running count `csum` passes lane j
+    (`searchsorted(csum, j, side="right")`), clipped to the last row.
+
+    In 32-bit words: a count past `out_size` decides nothing about a
+    lane below it, so the searched table is `min(csum, out_size)`.  By
+    ROWS of pivots, not step by step: the table is cut into rows of
+    _ROW entries, each row's last entry is a pivot of the level above,
+    and a lane descends by ONE row gather a level and a compare-and-
+    count over the row; the root's pivots are compared against every
+    lane with no gather.  6,291,456 rows are two gathers deep."""
+    n = csum.shape[0]
+    t = jnp.minimum(csum, out_size).astype(jnp.int32)
+    levels, width = [], n
+    while width > _ROOT:
+        # a pad of out_size is past every lane: never counted
+        rows = _rows_of(t, out_size)
+        levels.append(rows)
+        t, width = rows[:, -1], rows.shape[0]
+
+    def search(j):
+        pos = jnp.sum(t <= j[:, None], axis=1, dtype=jnp.int32)
+        for rows in reversed(levels):
+            blk = jnp.minimum(pos, rows.shape[0] - 1)
+            pos = blk * _ROW + jnp.sum(rows[blk] <= j[:, None], axis=1,
+                                       dtype=jnp.int32)
+        return jnp.minimum(pos, n - 1)
+    return search
+
+
+def _check_word(what: str, *sizes):
+    if sum(sizes) >= 1 << 31:
+        raise ValueError(f"{what}: positions in classes of {sizes} rows "
+                         "do not fit below 2**31")
+
+
+@functools.partial(jax.jit, static_argnames=("out_size",))
+@_scoped("otb.join_expand")
+def lane_rows(csum, out_size: int):
+    """For each output lane j < out_size, the row whose run of lanes
+    holds it, given the rows' running count of lanes `csum` (int64,
+    non-decreasing); the last row for the lanes no row reaches.  int32.
+    The search of join_expand, for an operator that repeats rows by a
+    count and joins nothing (INTERSECT/EXCEPT ALL)."""
+    _check_word("lane_rows", out_size, csum.shape[0])
+    if not csum.shape[0]:
+        return jnp.zeros(out_size, jnp.int32)
+    return _by_passes(_lane_search(csum, out_size), out_size)
+
+
 @functools.partial(jax.jit, static_argnames=("out_size", "left_outer"))
 @_scoped("otb.join_expand")
 def join_expand(lo, counts, perm, out_size: int, left_outer: bool = False,
@@ -515,8 +613,34 @@ def join_expand(lo, counts, perm, out_size: int, left_outer: bool = False,
 
     With left_outer, *valid* probe rows with zero matches emit one pair with
     build_idx == -1 (the null row); pass probe_valid so padding rows don't
-    null-extend.  Returns (probe_idx, build_idx, total).
+    null-extend.  Returns (probe_idx, build_idx, total): the indices
+    int32, `total` the exact int64 number of pairs (it may pass out_size
+    and a word: the size ladder compares it with out_size).
+
+    Every position is below a static class, so only `total` and the
+    running count behind it need 64 bits.  A lane finds its probe row p
+    by `_lane_search`; its build row is `perm[j + d[p]]` with `d = lo -
+    (csum - eff)` computed once per PROBE row and clamped into a word
+    (a row that starts past out_size is read by no live lane); under
+    left_outer a row with no match carries INT32_MIN there, which is
+    its null flag.  `d[p]` and `perm[...]` are row gathers too (_take).
+
+    ONE formulation, kept from a sweep on a v5e at the cells' shapes
+    (PERF.md section 6, PR 30; 1,572,864 lanes over 6,291,456 probe
+    rows, kernel alone): this one 26.0 ms; the same with two scalar
+    gathers behind the search 39.9; a 32-bit coarse binary search and
+    one row gather 216; the plain 32-bit binary search 305; what it
+    replaced (a 64-bit `searchsorted` of the running count, then
+    `csum[p]`, `eff[p]`, `lo[p]`, `perm[...]`, all int64) 1,120.  What
+    the lanes at or past `total` look up moved nothing (26.0 or 26.6
+    ms): they search their own j and `valid` cuts them.
     """
+    np_, nb = counts.shape[0], perm.shape[0]
+    _check_word("join_expand", out_size, max(np_, nb))
+    if not np_:
+        none = jnp.zeros(out_size, jnp.int32)
+        return none, none, jnp.int64(0)
+    counts = counts.astype(jnp.int64)
     if left_outer:
         eff = jnp.maximum(counts, 1)
         if probe_valid is not None:
@@ -524,22 +648,28 @@ def join_expand(lo, counts, perm, out_size: int, left_outer: bool = False,
     else:
         eff = counts
     csum = jnp.cumsum(eff)
-    total = csum[-1] if eff.shape[0] else jnp.int64(0)
-    j = jnp.arange(out_size, dtype=jnp.int64)
-    p = jnp.searchsorted(csum, j, side="right")
-    p = jnp.clip(p, 0, max(eff.shape[0] - 1, 0))
-    base = csum[p] - eff[p]
-    r = j - base
-    bpos = lo[p] + r
-    bpos = jnp.clip(bpos, 0, max(perm.shape[0] - 1, 0))
-    build_idx = perm[bpos]
+    total = csum[-1]
+    d = jnp.clip(lo.astype(jnp.int64) - (csum - eff), -out_size,
+                 nb).astype(jnp.int32)
     if left_outer:
-        build_idx = jnp.where(counts[p] == 0, -1, build_idx)
-    valid = j < total
-    probe_idx = jnp.where(valid, p, 0)
-    if not left_outer:
-        build_idx = jnp.where(valid, build_idx, 0)
-    return probe_idx, build_idx, total
+        d = jnp.where(counts == 0, _INT32_MIN, d)
+    search = _lane_search(csum, out_size)
+    d_rows = _rows_of(d, 0)
+    perm_rows = _rows_of(perm.astype(jnp.int32), 0)
+
+    def pairs(j):
+        p = search(j)
+        dp = _take(d_rows, p)
+        # j + INT32_MIN stays in the word: j >= 0
+        build_idx = _take(perm_rows, jnp.clip(j + dp, 0, max(nb - 1, 0)))
+        valid = j < total
+        if left_outer:
+            build_idx = jnp.where(dp == _INT32_MIN, -1, build_idx)
+        else:
+            build_idx = jnp.where(valid, build_idx, 0)
+        return jnp.where(valid, p, 0), build_idx
+
+    return (*_by_passes(pairs, out_size), total)
 
 
 @jax.jit

@@ -242,6 +242,8 @@ class TestJoin:
         out_size = max(256, total)
         pi, bi, tot = K.join_expand(lo, counts, perm, out_size)
         assert int(tot) == total
+        assert perm.dtype == pi.dtype == bi.dtype == jnp.int32
+        assert tot.dtype == jnp.int64
         got = {(int(pi[i]), int(bi[i])) for i in range(total)}
         assert got == self._oracle_pairs(probe, build, pvalid, bvalid)
 

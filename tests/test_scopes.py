@@ -54,6 +54,8 @@ KERNELS = [
     ("join_build", "otb.join_build", lambda: _lowered(K.join_build, I, B)),
     ("join_probe_counts", "otb.join_probe",
      lambda: _lowered(K.join_probe_counts, I, I, B)),
+    ("lane_rows", "otb.join_expand",
+     lambda: _lowered(K.lane_rows, I, out_size=N)),
     ("join_expand", "otb.join_expand",
      lambda: _lowered(K.join_expand, I, I % 2, I, out_size=N)),
     ("compose_index", "otb.join_expand",

@@ -128,6 +128,29 @@ def test_join_expand(one_chip):
         s(4 * SMALL, I32), s(4 * SMALL, I32), s(SMALL, I32))
 
 
+@pytest.mark.parametrize("out_size, left_outer", [(1572864, False),
+                                                  (6291456, True)])
+def test_join_expand_at_sf1(one_chip, out_size, left_outer):
+    """The cells' largest join (lineitem's 6,291,456 probe rows into
+    1,572,864 lanes) and the class the size ladder reaches at factor 4:
+    sort-free, so it may go to the real class.  No `while` walks a
+    binary search, no gather reads a 64-bit table, and the row gathers'
+    temporaries ([lanes, 128] int32) stay under 2 GiB because a class
+    past 2**21 lanes runs in passes."""
+    s = one_chip
+    compiled = jax.jit(lambda lo, counts, perm, pv: K.join_expand(
+        lo, counts, perm, out_size=out_size, left_outer=left_outer,
+        probe_valid=pv)).lower(
+        s(6291456, I64), s(6291456, I64), s(393216, I32),
+        s(6291456, BOOL)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    text = compiled.as_text()
+    gathers = [ln for ln in text.splitlines() if " gather(" in ln]
+    assert gathers and not [g for g in gathers if "s64[" in g]
+    # one pass: no loop at all; three passes: the loop over them
+    assert (" while(" in text) is (out_size > K._MAX_LANES)
+
+
 def test_grouped_agg_sort(one_chip):
     s = one_chip
     _compile(jax.jit(lambda k, v, a: K.grouped_agg_sort(
