@@ -141,6 +141,27 @@ def days_to_date(days: int) -> str:
     return str(_EPOCH + np.timedelta64(int(days), "D"))
 
 
+def add_interval(days: int, qty: int, unit: str) -> int:
+    """Day number of `days` plus `qty` days, months or years (`qty` may
+    be negative).  Month and year arithmetic keeps the day of the month
+    and clamps it to the target month's last day, as PostgreSQL's
+    date + interval does: 1996-02-29 + 1 year = 1997-02-28,
+    01-31 + 1 month = 02-28 (02-29 in a leap year)."""
+    if unit == "day":
+        return int(days) + int(qty)
+    if unit not in ("month", "year"):
+        raise ValueError(f"interval unit {unit!r} unsupported")
+    base = _EPOCH + np.timedelta64(int(days), "D")
+    month0 = base.astype("datetime64[M]")
+    month = month0 + np.timedelta64(
+        int(qty) * (12 if unit == "year" else 1), "M")
+    last = (month + np.timedelta64(1, "M")).astype("datetime64[D]") \
+        - np.timedelta64(1, "D")
+    out = min(month.astype("datetime64[D]")
+              + (base - month0.astype("datetime64[D]")), last)
+    return int((out - _EPOCH).astype(np.int64))
+
+
 def decimal_to_int(value, scale: int) -> int:
     """Parse a decimal literal into its scaled-int64 representation."""
     s = str(value)

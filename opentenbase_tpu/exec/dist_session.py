@@ -46,13 +46,15 @@ class Prepared:
     (the light-coordinator analog, execLight.c:34) ships dist-key-pinned
     statements whole to one datanode.
 
-    mode 'ast': binding with abstract params failed (e.g. TEXT params in
-    dictionary predicates); EXECUTE substitutes argument literals into
-    the stored parse tree and replans — still skipping the parse.
+    mode 'ast': binding with abstract params failed (a TEXT param
+    anywhere but `column = $n` / `column <> $n` over a dictionary-coded
+    base column); EXECUTE substitutes argument literals into the stored
+    parse tree and replans — still skipping the parse.
     """
     stmt: A.Node
     param_types: dict
     mode: str = "ast"
+    baked: int = 0    # an autoprep template's WHERE literals left baked
     planned: object = None        # pristine PlannedStmt (FQS fragment)
     dp: object = None             # generic distributed DistPlan
     router: object = None         # params -> datanode index | None
@@ -725,22 +727,24 @@ class ClusterSession:
         """AOT warmup at PREPARE time (ISSUE 1): trace+compile the
         statement's mesh program on the background warmup thread, so
         the first EXECUTE lands warm instead of paying the multi-second
-        XLA compile on the query path.  Numeric/date params ride as
+        XLA compile on the query path.  Numeric/date params, and the
+        dictionary code of a TEXT param compared with a column, ride as
         traced inputs, so the warmed program serves EVERY later binding
-        (zero-valued dummies stand in when no binding is known);
-        TEXT/BOOL params bake into program structure and can't be
-        abstracted — those preps warm on first execution instead.
-        Router (FQS) preps run single-node eager plans: nothing to
-        compile ahead of time."""
+        (zero-valued dummies and the empty string stand in when no
+        binding is known); BOOL params bake into program structure and
+        can't be abstracted — those preps warm on first execution
+        instead.  Router (FQS) preps run single-node eager plans:
+        nothing to compile ahead of time."""
         if prep.mode != "plan" or prep.router is not None \
                 or prep.dp is None:
             return
         if params is None:
             params = {}
             for i, t in prep.param_types.items():
-                if t.kind in (TypeKind.TEXT, TypeKind.BOOL):
+                if t.kind == TypeKind.BOOL:
                     return
-                params[f"__bindparam{i}"] = (0, t)
+                params[f"__bindparam{i}"] = (
+                    "" if t.kind == TypeKind.TEXT else 0, t)
         self._schedule_warm_dp(prep.dp, params)
 
     def _schedule_warm_dp(self, dp: DistPlan, params: dict) -> None:
@@ -776,9 +780,10 @@ class ClusterSession:
                     or c.gucs.get("enable_autoprepare", "on") == "off"
                     or c.gucs.get("enable_spm", "off") == "on"
                     or c.gucs.get("spm_capture", "off") == "on"):
-                prep, params = self._autoprep_template(stmt)
-            if prep is not None and prep.mode == "plan" \
-                    and prep.router is None and prep.dp is not None:
+                prep, arg_nodes = self._autoprep_template(stmt)
+                params = self._bind_lifted(prep, arg_nodes)
+            if params is not None and prep.router is None \
+                    and prep.dp is not None:
                 self._schedule_warm(prep, params)
                 n += 1
                 continue
@@ -800,6 +805,12 @@ class ClusterSession:
     def _build_prepared(self, inner: A.Node, ptypes: dict) -> Prepared:
         from ..sql.analyze import BindError
         prep = Prepared(inner, ptypes, ddl_gen=self._prep_gen())
+        if self.cluster.catalog.global_indexes and any(
+                t.kind == TypeKind.TEXT for t in ptypes.values()):
+            # global-index routing reads the string at plan time
+            # (gindex.route): substitute and replan, as the ad-hoc path
+            # does wherever a global index exists (_try_autoprep)
+            return prep
         if isinstance(inner, A.SelectStmt):
             try:
                 masks = self.cluster.gucs.get(
@@ -820,9 +831,10 @@ class ClusterSession:
                 if "substitution path" not in str(e):
                     # invalid statement: error at PREPARE time (PG does)
                     raise ExecError(str(e)) from None
-                # TEXT params inside dictionary predicates: fall back to
-                # literal substitution + replan per EXECUTE
-                # (PostgreSQL's custom-plan path)
+                # a TEXT param that is no `column = $n` / `<> $n` (LIKE,
+                # IN, a range, a projection): fall back to literal
+                # substitution + replan per EXECUTE (PostgreSQL's
+                # custom-plan path)
                 prep.mode = "ast"
             except ValueError:
                 # binds fine but this shape can't pre-plan with abstract
@@ -841,6 +853,18 @@ class ClusterSession:
             raise ExecError("cannot negate a non-numeric argument")
         if isinstance(node, A.TypedConst) and node.type_name == "date":
             return T.date_to_days(node.value)
+        if isinstance(node, A.BinOp) and node.op in ("+", "-") \
+                and isinstance(node.right, A.TypedConst) \
+                and node.right.type_name == "interval":
+            # a date-valued constant expression, evaluated here so that
+            # it rides as ONE DATE parameter (the binder folds a baked
+            # one with the same function)
+            qty = node.right.qty if node.op == "+" else -node.right.qty
+            try:
+                return T.add_interval(self._bind_arg(node.left, t), qty,
+                                      node.right.unit)
+            except ValueError as e:
+                raise ExecError(str(e)) from None
         if not isinstance(node, A.Const):
             raise ExecError("EXECUTE arguments must be literals")
         v = node.value
@@ -1098,6 +1122,12 @@ class ClusterSession:
         res = None
         if not instrument:
             res = self._try_autoprep(stmt, t)
+        elif stmt.where is not None:
+            # EXPLAIN ANALYZE plans the statement as written, so that
+            # every node reports actuals: no literal is lifted
+            from .autoprep import count_literals
+            with obs_trace.span("bind") as sp:
+                sp.set(traced=0, baked=count_literals(stmt.where))
         if res is None:
             dp = self._plan_distributed(stmt, txn=t)
             res, ex = self._run_select_dp(dp, t, instrument=instrument)
@@ -1135,8 +1165,19 @@ class ClusterSession:
                 or c.gucs.get("spm_capture", "off") == "on":
             return None
         with obs_trace.span("autoprep"):
-            prep, params = self._autoprep_template(stmt)
-        if prep is None or prep.mode != "plan" or params is None:
+            prep, arg_nodes = self._autoprep_template(stmt)
+        # bind: the lifted literals' values, each in its parameter's
+        # storage representation (a date expression evaluated, a string
+        # as it is: the tier that runs the statement binds it to a
+        # dictionary code, in a `bind` span of its own)
+        with obs_trace.span("bind") as sp:
+            params = self._bind_lifted(prep, arg_nodes)
+            if params is None:
+                from .autoprep import count_literals
+                sp.set(traced=0, baked=count_literals(stmt.where))
+            else:
+                sp.set(traced=len(params), baked=prep.baked)
+        if params is None:
             return None     # normal plan path (original stmt)
         self.plan_cache_hits += 1
         node = prep.router(params) if prep.router is not None else None
@@ -1150,21 +1191,37 @@ class ClusterSession:
         return res
 
     def _autoprep_template(self, stmt: A.SelectStmt):
-        """(Prepared, bound params) for the statement's autoprep
+        """(Prepared, lifted literal nodes) for the statement's autoprep
         template, or (None, None).  The SHARED core of the ad-hoc fast
         path and warm_statement — both must build the same template
         under the same cache key so warmup compiles exactly the program
-        the first execution looks up."""
-        c = self.cluster
-        from .autoprep import cached_template, parameterize
-        try:
-            hit = parameterize(stmt)
-        except Exception:
+        the first execution looks up.  Strings are lifted first; where
+        that template does not pre-plan (the binder found a lifted
+        string no dictionary-coded base column takes), the template
+        with the strings baked stands in, so one string in a view or a
+        CTE does not cost the statement its other parameters."""
+        from .autoprep import parameterize
+        for text in (True, False):
+            try:
+                hit = parameterize(stmt, text)
+            except Exception:
+                return None, None
+            if hit is None:
+                return None, None
+            template, arg_nodes, ptypes = hit
+            strings = any(t.kind == TypeKind.TEXT for t in ptypes.values())
+            prep = self._template_prepared(template, ptypes)
+            if not strings or (prep is not None and prep.mode == "plan"):
+                break
+        if prep is None:
             return None, None
-        if hit is None:
-            return None, None
-        template, arg_nodes, ptypes = hit
+        return prep, arg_nodes
+
+    def _template_prepared(self, template, ptypes) -> "Prepared | None":
+        """The cluster-wide Prepared of one autoprep template, or None
+        for a template that cannot bind (remembered, like any other)."""
         from ..sql.fingerprint import fingerprint
+        from .autoprep import cached_template, count_literals
         try:
             # the type signature is part of the key: A.Param carries
             # only an index, so `k = 10` (INT64) and `k = 10.5`
@@ -1174,25 +1231,33 @@ class ClusterSession:
                    tuple(str(ptypes[i])
                          for i in range(1, len(ptypes) + 1)))
         except Exception:
-            return None, None
+            return None
 
         def build():
             try:
-                return self._build_prepared(template, ptypes)
+                prep = self._build_prepared(template, ptypes)
             except Exception:
                 return None     # remember: this template can't bind
+            prep.baked = count_literals(template.where)
+            return prep
 
-        prep = cached_template(c, key, self._plan_gen(), build)
-        if prep is None:
-            return None, None
+        return cached_template(self.cluster, key, self._plan_gen(), build)
+
+    def _bind_lifted(self, prep, arg_nodes) -> "dict | None":
+        """The template's parameters bound to this statement's lifted
+        literals, or None when the statement takes the normal plan path
+        (no template, one that does not pre-plan, or a literal its
+        parameter's type cannot hold)."""
+        if prep is None or prep.mode != "plan":
+            return None
         params = {}
         try:
             for i, arg in enumerate(arg_nodes, start=1):
-                params[f"__bindparam{i}"] = (
-                    self._bind_arg(arg, ptypes[i]), ptypes[i])
+                t = prep.param_types[i]
+                params[f"__bindparam{i}"] = (self._bind_arg(arg, t), t)
         except Exception:
-            return prep, None
-        return prep, params
+            return None
+        return params
 
     def _exec_select_for_update(self, stmt: A.SelectStmt) -> Result:
         """Cluster SELECT ... FOR UPDATE [NOWAIT]: lock matching rows

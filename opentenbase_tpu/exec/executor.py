@@ -296,6 +296,50 @@ class DeviceTableCache:
         POOL.invalidate(store)
 
 
+def text_param_key(param: tuple) -> str:
+    """The name under which a text parameter's dictionary code rides in
+    `params`: one per (parameter, table, column), because one `$n` set
+    against two columns is two codes of two dictionaries."""
+    name, table, column = param
+    return f"{name}@{table}.{column}"
+
+
+def bind_text_params(exprs, params: dict, stores: dict, tier: str) -> dict:
+    """`params` for a COMPILED tier: every text parameter that `exprs`
+    compare with a column (StrPred.param) leaves as a string and comes
+    back as that column's dictionary code in `stores` (the dictionaries
+    this tier's scans put into their batches: a DataNode's own, or the
+    mesh's union), under `text_param_key`; -1, a code no row holds,
+    where the dictionary holds no such string.  An int rides as a traced
+    scalar like any numeric parameter, so no string reaches a program
+    key.  `Executor._prep` is the one place that reads the binding.
+    Timed as a `bind` span whose `dict_miss` counts the strings no
+    dictionary held.  `params` itself comes back when it holds no
+    string."""
+    if not any(isinstance(v, str) for v, _t in params.values()):
+        return params
+    out, misses = dict(params), 0
+    with obs_trace.span("bind", tier=tier) as sp:
+        for x in exprs:
+            if not (isinstance(x, E.StrPred) and x.param is not None):
+                continue
+            name, table, column = x.param
+            key = text_param_key(x.param)
+            v = params[name][0]
+            if key in out or not isinstance(v, str):
+                continue
+            store = stores.get(table)
+            d = store.dicts.get(column) if store is not None else None
+            if d is None:
+                raise ExecError(f"no dictionary for {table}.{column}")
+            code = d.code_of(v)
+            misses += code < 0
+            out[key] = (code, T.INT32)
+            out.pop(name, None)
+        sp.set(dict_miss=misses)
+    return out
+
+
 @dataclasses.dataclass
 class ExecContext:
     stores: dict[str, TableStore]
@@ -356,13 +400,32 @@ class Executor:
 
     # ------------------------------------------------------------------
     def _prep(self, e: E.Expr) -> E.Expr:
-        """Substitute init-plan results before compiling."""
+        """Substitute init-plan results and bound parameters before
+        compiling.  A text parameter compared with a column
+        (StrPred.param) has ONE reading, here: where a compiled tier
+        bound it to the column's dictionary code (bind_text_params), a
+        compare of the column's codes with that traced scalar; where the
+        string itself arrives (the host tier, a DataNode's or the
+        coordinator's fragment), the literal's own StrPred, which
+        compiles against the dictionaries of the batch it filters
+        (re-encoded after an exchange, so no stored code would do).
+        Both keep SQL's three-valued result for NULL rows."""
         params = self.ctx.params
 
         def sub(x: E.Expr):
             if isinstance(x, E.Col) and x.name in params:
                 v, t = params[x.name]
                 return E.Lit(v, t)
+            if isinstance(x, E.StrPred) and x.param is not None:
+                code = params.get(text_param_key(x.param))
+                if code is not None:
+                    return E.Cmp("=" if x.kind == "eq" else "<>", x.col,
+                                 E.Lit(code[0], T.INT32))
+                v = params.get(x.param[0], (None,))[0]
+                if not isinstance(v, str):
+                    raise ExecError(
+                        f"text parameter {x.param[0]} is not bound")
+                return E.StrPred(x.col, x.kind, (v,))
             return None
         return rewrite(e, sub)
 

@@ -101,6 +101,11 @@ def _strpred_colname(pred: E.StrPred) -> str:
 
 
 def _codes_for_strpred(pred: E.StrPred, dicts: dict) -> np.ndarray:
+    if pred.param is not None:
+        # Executor._prep gives a run-time string its value before
+        # compiling: reaching here would compile an empty pattern set,
+        # a wrong answer
+        raise E.ExprError(f"text parameter {pred.param[0]} is not bound")
     name = _strpred_colname(pred)
     d = dicts.get(name)
     if d is None:

@@ -28,10 +28,11 @@ sync per join per query).
 Compiled programs live in the shared program cache (exec/plancache.py
 FUSED tier) under a CANONICAL FRAGMENT SIGNATURE: numeric/date literals
 in scan filters and quals are masked out of the plan and ride as traced
-program inputs instead, so `WHERE l_shipdate <= X` with a different
-constant reuses the compiled executable (the reference's generic-plan
-arm, taken further: the plan cache there saves planning, this saves the
-XLA compile).  Multi-table fragments key per-table components (store
+program inputs instead, as does the dictionary code of a text parameter
+compared with a column (`_bound_ctx`), so `WHERE l_shipdate <= X` with a
+different constant reuses the compiled executable (the reference's
+generic-plan arm, taken further: the plan cache there saves planning,
+this saves the XLA compile).  Multi-table fragments key per-table components (store
 identity + TEXT dictionary lengths — dictionaries are trace constants).
 jax re-traces per array shape automatically — the pow2/quarter-step
 size classes bound that — and the cache's global live-executable budget
@@ -207,7 +208,7 @@ def _has_transformed_dup_dict(node, store) -> bool:
     maps several codes to one string — key canonicalization builds a
     host LUT per batch (executor._eval_group_keys), which is fine eager
     but not worth special-casing under the trace: fall back."""
-    for x in _walk_plan_exprs(node):
+    for x in P.walk_exprs(node):
         if isinstance(x, E.TextExpr):
             base = store.dicts.get(x.col.name.split(".", 1)[-1])
             if base is not None:
@@ -217,33 +218,9 @@ def _has_transformed_dup_dict(node, store) -> bool:
     return False
 
 
-def _walk_plan_exprs(node):
-    for attr in ("filters", "quals"):
-        for q in getattr(node, attr, None) or []:
-            yield from E.walk(q)
-    for name, e in getattr(node, "outputs", None) or []:
-        yield from E.walk(e)
-    if isinstance(node, P.Agg):
-        for _, ke in node.group_keys:
-            yield from E.walk(ke)
-        for _, ac in node.aggs:
-            yield from E.walk(ac)
-    if isinstance(node, P.Sort):
-        for ke, _ in node.keys:
-            yield from E.walk(ke)
-    if isinstance(node, P.HashJoin):
-        for e in (list(node.left_keys) + list(node.right_keys)
-                  + list(node.residual or [])):
-            yield from E.walk(e)
-    for attr in ("child", "left", "right"):
-        c = getattr(node, attr, None)
-        if isinstance(c, P.PhysNode):
-            yield from _walk_plan_exprs(c)
-
-
 def _needed_columns(node, alias: str) -> set[str]:
     need = set()
-    for x in _walk_plan_exprs(node):
+    for x in P.walk_exprs(node):
         if isinstance(x, E.Col) and x.name.startswith(alias + "."):
             need.add(x.name.split(".", 1)[1])
     return need
@@ -294,6 +271,19 @@ def _mask_node(node, lits: list):
         return dataclasses.replace(node,
                                    child=_mask_node(node.child, lits))
     return node
+
+
+def _bound_ctx(ctx, node):
+    """`ctx` with the text parameters `node` compares with a column
+    bound to that column's code in `ctx.stores`: an int rides as a
+    traced input like any numeric parameter, so the program key holds
+    no string."""
+    from .executor import bind_text_params
+    params = bind_text_params(P.walk_exprs(node), ctx.params,
+                              ctx.stores, "fused")
+    if params is ctx.params:
+        return ctx
+    return dataclasses.replace(ctx, params=params)
 
 
 def _screen_fragment(ctx, node):
@@ -381,6 +371,7 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
         staged_ns[t] = jnp.int64(n)
 
     table_sig = _table_sig(stores)
+    ctx = _bound_ctx(ctx, exec_node_plan)
     traced_names = tuple(sorted(
         k for k, (v, _t) in ctx.params.items()
         if isinstance(v, (int, float)) and not isinstance(v, bool)))
@@ -434,8 +425,8 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
         # the execute span covers the program call AND the join-overflow
         # device_get below — that device read is the tier's ONE legal
         # sync boundary, so the span's wall time includes device work
-        with obs_trace.span("execute", tier="fused") \
-                if obs_trace.ENABLED else obs_trace.NULL_SPAN:
+        with (obs_trace.span("execute", tier="fused")
+              if obs_trace.ENABLED else obs_trace.NULL_SPAN) as sp:
             try:
                 with stats_tier("fused"):
                     # trace-time executor counters attribute to the
@@ -486,6 +477,10 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
                     grew = True
                 if grew:
                     _ladder_remember(lkey, factors)
+                    # this call's output overflowed a join class: the
+                    # statement replays one class up (`retraces` of
+                    # summary() sums these)
+                    sp.set(retraces=1)
                     obs_trace.event("retrace", tier="fused",
                                     factors=dict(factors))
                     continue
@@ -672,7 +667,7 @@ class FragmentProgram:
 
     def __init__(self, ctx, plan, chunk_rows: int):
         from ..storage.batch import chunk_class
-        self.ctx = ctx
+        self.ctx = _bound_ctx(ctx, plan)
         self.plan = plan
         self.chunk_rows = int(chunk_rows)
         self._chunk_key = ("__morsel", chunk_class(int(chunk_rows)))

@@ -72,17 +72,27 @@ class MeshUnsupported(Exception):
 
 
 class _DictView:
-    def __init__(self, values):
+    """One column's UNION dictionary: `values` by code and `index`, the
+    string -> code map staging keeps beside it (both grow in place when
+    a table's tail is staged)."""
+
+    def __init__(self, values, index):
         self.values = values
+        self.index = index
+
+    def code_of(self, s: str) -> int:
+        return self.index.get(s, -1)
 
 
 class _MeshStoreView:
     """TableStore facade used by the traced scan: schema + UNION
     dictionaries (codes comparable across every shard)."""
 
-    def __init__(self, td, union_dicts: dict, null_columns: set):
+    def __init__(self, td, union_dicts: dict, dict_state: dict,
+                 null_columns: set):
         self.td = td
-        self.dicts = {c: _DictView(v) for c, v in union_dicts.items()}
+        self.dicts = {c: _DictView(v, dict_state[c]["index"])
+                      for c, v in union_dicts.items()}
         self.null_columns = set(null_columns)
 
 
@@ -406,7 +416,7 @@ class MeshRunner:
                 rep.reshape(ndn * aux.shape[0]), sh)
             nbytes += rep.nbytes
         nrows = jax.device_put(np.asarray(counts, np.int64), sh)
-        view = _MeshStoreView(td, union_dicts, null_columns)
+        view = _MeshStoreView(td, union_dicts, dict_state, null_columns)
         codec.note_staged(view, encs)
         staged = _StagedTable(arrs, nrows, padded, view, vkey)
         POOL.note_upload(nbytes)
@@ -829,8 +839,11 @@ class MeshRunner:
         warms everything the first real execution needs: table staging,
         the traced+compiled shard_map programs (written to the
         persistent XLA cache and to the jit dispatch caches), AND the
-        learned size-class ladder — numeric params are traced inputs,
-        so any later binding reuses all of it."""
+        learned size-class ladder — every lifted param (a number, a
+        date, a text param's dictionary code) is a traced input and in
+        no key, so any later binding reuses all of it; one that selects
+        more rows may overflow a class once, and the ladder only
+        grows."""
         try:
             self.run(dp, snapshot_ts, 0, params)
             return True
@@ -959,7 +972,7 @@ class MeshRunner:
 
     def _execute(self, dp, staged, snapshot_ts, txid, params, factors,
                  mults, gathers, included):
-        from .executor import ExecContext, Executor
+        from .executor import ExecContext, Executor, bind_text_params
 
         table_names = sorted(staged)
         gather_ex = [ex for ex in dp.exchanges
@@ -970,9 +983,15 @@ class MeshRunner:
         gather_idx = [ex.index for ex in gather_ex]
 
         # canonical program signature: numeric params (lifted literals,
-        # bound $n params, scalar-subquery results) are MASKED out of
-        # the key and ride as TRACED inputs, so same-shape statements
-        # with different literals reuse the compiled shard_map program
+        # bound $n params, scalar-subquery results, and the union-
+        # dictionary code of a text param compared with a column) are
+        # MASKED out of the key and ride as TRACED inputs, so same-shape
+        # statements with different literals reuse the compiled
+        # shard_map program
+        params = bind_text_params(
+            (x for f in dp.fragments if f.index in included
+             for x in P.walk_exprs(f.plan)),
+            params, {t: staged[t].view for t in table_names}, "mesh")
         traced_names = tuple(sorted(
             k for k, (v, _t) in params.items()
             if isinstance(v, (int, float)) and not isinstance(v, bool)))
@@ -1176,6 +1195,9 @@ class MeshRunner:
             gv = np.asarray(jax.device_get(g_over_vec))
             g_over = sorted({gi for gi, ov in
                              zip(meta.get("gi_order", ()), gv) if ov > 0})
+            # a class overflowed: run() replays the statement one class
+            # up (`retraces` of summary() sums these)
+            sp.set(retraces=int(bool(over_jids or a2a_over or g_over)))
         return (dict(zip(gather_idx, outs)), meta, over_jids,
                 a2a_over, g_over)
 

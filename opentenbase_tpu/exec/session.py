@@ -381,6 +381,16 @@ def _trace_explain_lines() -> str:
         f"Buffer Pool: hits={qt.count_events('pool', hit=True)} "
         f"misses={qt.count_events('pool', hit=False)}",
     ]
+    if qt.count_events("bind"):
+        # the statement's WHERE literals: lifted ones ride as program
+        # inputs, the rest stay in the plan and program keys (an
+        # instrumented run lifts none: its plan keeps every literal)
+        lines.append(
+            f"Bind: {qt.phase_ms('bind'):.2f} ms "
+            f"traced={int(qt.sum_attr('bind', 'traced'))} "
+            f"baked={int(qt.sum_attr('bind', 'baked'))} "
+            f"dict_miss={int(qt.sum_attr('bind', 'dict_miss'))} "
+            f"retraces={int(qt.sum_attr('execute', 'retraces'))}")
     rounds = int(qt.sum_attr("exchange", "rounds"))
     if rounds:
         lines.append(

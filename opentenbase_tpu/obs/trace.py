@@ -62,8 +62,9 @@ _SEED = os.urandom(4).hex()
 # canonical phase names summarized per query (otb_stat_query columns)
 PHASES = ("plan", "stage", "execute", "exchange", "finalize")
 # every span name `summary()` sums into a `*_ms` key
-_SUMMED = PHASES + ("wire.recv", "wire.send", "parse", "autoprep", "wait",
-                    "finalize.gather", "finalize.fetch", "finalize.decode")
+_SUMMED = PHASES + ("wire.recv", "wire.send", "parse", "autoprep", "bind",
+                    "wait", "finalize.gather", "finalize.fetch",
+                    "finalize.decode")
 _BY_START = operator.attrgetter("t0_ms")
 
 
@@ -358,6 +359,7 @@ class QueryTrace:
         staged = materialized = overlapped = 0.0
         fetches = fetch_bytes = 0
         exchanges = exchange_bytes = 0
+        traced = baked = retraces = 0
         hits = misses = 0
         work = [(self.root, ())]
         while work:
@@ -381,6 +383,10 @@ class QueryTrace:
                 elif name == "execute":
                     exchanges += a.get("exchanges", 0) or 0
                     exchange_bytes += a.get("exchange_bytes", 0) or 0
+                    retraces += a.get("retraces", 0) or 0
+                elif name == "bind":
+                    traced += a.get("traced", 0) or 0
+                    baked += a.get("baked", 0) or 0
                 elif name == "pool":
                     if a.get("hit") is True:
                         hits += 1
@@ -412,6 +418,14 @@ class QueryTrace:
         d["wire_ms"] = ms["wire.recv"] + ms["wire.send"]
         d["parse_ms"] = ms["parse"]
         d["autoprep_ms"] = ms["autoprep"]
+        # the lifted literals: host time to bind them (values, then
+        # dictionary codes in the tier that ran), how many rode as
+        # program inputs, how many WHERE literals stayed in the keys,
+        # and how often a size class overflowed and the program replayed
+        d["bind_ms"] = ms["bind"]
+        d["params_traced"] = int(traced)
+        d["params_baked"] = int(baked)
+        d["retraces"] = int(retraces)
         d["wait_ms"] = ms["wait"]
         d["finalize_gather_ms"] = ms["finalize.gather"]
         d["finalize_fetch_ms"] = ms["finalize.fetch"]

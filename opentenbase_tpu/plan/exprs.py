@@ -223,10 +223,17 @@ class StrPred(Expr):
     a device code-set mask.
     kind: 'eq' | 'ne' | 'like' | 'not_like' | 'in' | 'not_in' | 'lt' | 'le' |
     'gt' | 'ge'
+
+    With `param` set (kinds 'eq' and 'ne' over a plain Col, no patterns)
+    the string arrives at run time: `param` is (parameter name, table,
+    column), and the executor compares the column's codes with the code
+    that table's dictionary holds for the bound string — a traced scalar
+    in a compiled program, so every value runs one program.
     """
     col: Expr                 # Col or TextExpr over a TEXT column
     kind: str
     patterns: tuple[str, ...]
+    param: Optional[tuple] = None
 
     def __post_init__(self):
         object.__setattr__(self, "type", BOOL)

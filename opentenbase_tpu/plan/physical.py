@@ -254,6 +254,35 @@ class Result(PhysNode):
     outputs: list[tuple[str, E.Expr]] = dataclasses.field(default_factory=list)
 
 
+def walk_exprs(node: PhysNode):
+    """Every expression node a plan holds: scan filters and outputs,
+    quals, group keys and aggregates, sort keys, join keys and
+    residuals, window calls, of `node` and of all below it.  What a
+    tier asks when it must know which columns or parameters a plan
+    reads (exec/fused.py's needed columns, executor.bind_text_params)."""
+    for attr in ("filters", "quals"):
+        for q in getattr(node, attr, None) or []:
+            yield from E.walk(q)
+    for _name, e in getattr(node, "outputs", None) or []:
+        yield from E.walk(e)
+    if isinstance(node, Agg):
+        for _, e in list(node.group_keys) + list(node.aggs):
+            yield from E.walk(e)
+    elif isinstance(node, Sort):
+        for e, _ in node.keys:
+            yield from E.walk(e)
+    elif isinstance(node, HashJoin):
+        for e in (list(node.left_keys) + list(node.right_keys)
+                  + list(node.residual or [])):
+            yield from E.walk(e)
+    elif isinstance(node, Window):
+        for _, e in node.calls:
+            yield from E.walk(e)
+    for c in node.children():
+        if isinstance(c, PhysNode):
+            yield from walk_exprs(c)
+
+
 def explain(node: PhysNode, indent: int = 0, out: Optional[list] = None,
             annotate=None) -> str:
     """Render a plan tree.  ``annotate(node) -> str`` (optional)

@@ -476,7 +476,8 @@ class Planner:
             elif isinstance(q, E.StrPred):
                 cst = st["cols"].get(_strpred_plain(q))
                 if q.kind in ("eq", "in"):
-                    k = len(q.patterns)
+                    # a run-time string (StrPred.param) is one value
+                    k = len(q.patterns) or 1
                     sel = k / max(cst["ndv"], 1) if cst else 0.1
                 elif q.kind in ("like",):
                     sel = 0.1

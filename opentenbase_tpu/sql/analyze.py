@@ -61,6 +61,9 @@ class Binder:
         # runtime parameter column (reference: ParamRef -> Param with
         # paramtype from the prepared statement, parse_param.c)
         self.param_types = param_types or {}
+        # $n -> the kinds of the columns a TEXT $n is compared with
+        # (_bind_text_param: one parameter, one reading)
+        self._text_param_reads: dict = {}
         # column masking (exec/security.py): user-facing SELECT paths
         # opt in; internal DML/constraint/trigger reads must see (and
         # write back) REAL values, so the default is off
@@ -505,7 +508,7 @@ class Binder:
             raise BindError("interval literal outside date arithmetic")
 
         if isinstance(node, A.BinOp):
-            return self._bind_binop(node, b)
+            return self._bind_binop(node, b, scopes)
 
         if isinstance(node, A.UnaryOp):
             if node.op == "-":
@@ -712,12 +715,21 @@ class Binder:
             flip = {"in": "not_in", "not_in": "in", "like": "not_like",
                     "not_like": "like", "eq": "ne", "ne": "eq"}
             if e.kind in flip:
-                return E.StrPred(e.col, flip[e.kind], e.patterns)
+                return dataclasses.replace(e, kind=flip[e.kind])
         return E.Not(e)
 
-    def _bind_binop(self, node: A.BinOp, b) -> E.Expr:
+    def _bind_binop(self, node: A.BinOp, b, scopes) -> E.Expr:
         if node.op in ("<->", "<=>", "<#>"):
             return self._bind_distance(node, b)
+        if node.op in ("=", "<>"):
+            for p, other in ((node.right, node.left),
+                             (node.left, node.right)):
+                if isinstance(p, A.Param) and (
+                        p.index in self._text_param_reads
+                        or self.param_types.get(
+                            p.index, T.INT64).kind == TypeKind.TEXT):
+                    return self._bind_text_param(node.op, p, b(other),
+                                                 scopes)
         # date +/- interval constant folding (TPC-H uses literal arithmetic)
         if node.op in ("+", "-"):
             folded = self._try_fold_date(node, b)
@@ -734,6 +746,36 @@ class Binder:
             raise BindError("string concatenation unsupported on device "
                             "columns")
         raise BindError(f"operator {node.op!r} unsupported")
+
+    def _bind_text_param(self, op: str, p: A.Param, col: E.Expr,
+                         scopes) -> E.Expr:
+        """`col = $n` / `col <> $n` with a TEXT parameter: a StrPred
+        whose string arrives at run time and binds to a code of THIS
+        column's dictionary, so the binding names the column's base
+        table.  Against a DATE the parameter takes the column's type, as
+        a string literal would (the session binds the string to a day
+        number).  Anything else a TEXT value could be compared with (a
+        transformed column, a subquery's output) takes the substitution
+        path."""
+        reads = self._text_param_reads.setdefault(p.index, set())
+        reads.add(col.type.kind)
+        if len(reads) > 1:
+            # one string read as a day number here and as a dictionary
+            # code there: the parameter has one type, so substitute
+            raise BindError("TEXT parameters require the substitution path")
+        if col.type.kind == TypeKind.DATE:
+            self.param_types[p.index] = T.DATE
+            return E.Cmp(op, col, E.Col(f"__bindparam{p.index}", T.DATE))
+        if isinstance(col, E.Col) and col.type.kind == TypeKind.TEXT \
+                and "." in col.name and scopes:
+            alias, plain = col.name.split(".", 1)
+            for rte in scopes[0].rtable:
+                if rte.alias == alias and rte.kind == "table" \
+                        and plain in rte.columns:
+                    return E.StrPred(
+                        col, "eq" if op == "=" else "ne", (),
+                        (f"__bindparam{p.index}", rte.table.name, plain))
+        raise BindError("TEXT parameters require the substitution path")
 
     def _bind_distance(self, node: A.BinOp, b) -> E.Expr:
         metric = {"<->": "l2", "<=>": "cosine", "<#>": "ip"}[node.op]
@@ -767,22 +809,11 @@ class Binder:
         left = b(node.left)
         if not (isinstance(left, E.Lit) and left.type.kind == TypeKind.DATE):
             raise BindError("interval arithmetic only on date literals")
-        import numpy as np
-        base = np.datetime64(T.days_to_date(left.value), "D")
         qty = rl.qty if node.op == "+" else -rl.qty
-        if rl.unit == "day":
-            out = base + np.timedelta64(qty, "D")
-        elif rl.unit == "month":
-            m = (base.astype("datetime64[M]") + np.timedelta64(qty, "M"))
-            out = m.astype("datetime64[D]") + (base
-                                               - base.astype("datetime64[M]"))
-        elif rl.unit == "year":
-            m = (base.astype("datetime64[M]") + np.timedelta64(12 * qty, "M"))
-            out = m.astype("datetime64[D]") + (base
-                                               - base.astype("datetime64[M]"))
-        else:
-            raise BindError(f"interval unit {rl.unit!r} unsupported")
-        return E.Lit(T.date_to_days(str(out)), T.DATE)
+        try:
+            return E.Lit(T.add_interval(left.value, qty, rl.unit), T.DATE)
+        except ValueError as e:
+            raise BindError(str(e)) from None
 
     def _bind_cmp(self, op: str, left: E.Expr, right: E.Expr) -> E.Expr:
         lt, rt = left.type, right.type

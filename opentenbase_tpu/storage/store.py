@@ -87,6 +87,11 @@ class StringDict:
             self._index[s] = code
         return code
 
+    def code_of(self, s: str) -> int:
+        """The code of `s`, or -1 (a code no row holds) when the
+        dictionary has never seen it.  Never registers anything."""
+        return self._index.get(s, -1)
+
     def encode(self, strings) -> np.ndarray:
         return np.fromiter((self.encode_one(s) for s in strings),
                            dtype=np.int32, count=len(strings))

@@ -63,6 +63,19 @@ class TestRouting:
         assert s.last_query_stats()["tier"] == "gidx"
         assert calls["n"] <= 1
 
+    def test_prepared_text_key_still_routes(self, s):
+        # a TEXT parameter's string is what the mapping probe reads at
+        # plan time: with a global index about, such a PREPARE
+        # substitutes and replans, and routes like the ad-hoc statement
+        s.execute("create unique global index gi_name on emp (name)")
+        s.execute("prepare byname (varchar(12)) as "
+                  "select id from emp where name = $1")
+        assert s.prepared["byname"].mode == "ast"
+        assert s.query("execute byname ('e42')") == [(42,)]
+        assert s.last_query_stats()["tier"] == "gidx"
+        assert s.query("select id from emp where name = 'e7'") == [(7,)]
+        assert s.last_query_stats()["tier"] == "gidx"
+
     def test_guc_disables_route(self, s):
         s.execute("create unique global index gi_badge on emp (badge)")
         s.execute("set enable_global_indexscan = off")

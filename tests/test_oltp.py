@@ -52,11 +52,19 @@ class TestPrepared:
         assert s.last_query_stats()["tier"] == "mesh"
 
     def test_text_param_substitution_mode(self, s):
+        # a TEXT param compared by = with a dictionary-coded column binds
+        # to the column's code and pre-plans; any other place a TEXT
+        # param stands (here a range) substitutes and replans
         s.execute("prepare byname (varchar(16)) as "
                   "select k from kv where note = $1 order by k")
-        assert s.prepared["byname"].mode == "ast"
+        assert s.prepared["byname"].mode == "plan"
         assert s.query("execute byname ('n5')") == [(5,)]
         assert s.query("execute byname ('n41')") == [(41,)]
+        assert s.query("execute byname ('no such note')") == []
+        s.execute("prepare fromname (varchar(16)) as "
+                  "select k from kv where note >= $1 and k < 3 order by k")
+        assert s.prepared["fromname"].mode == "ast"
+        assert s.query("execute fromname ('n1')") == [(1,), (2,)]
 
     def test_prepared_insert_and_arity_errors(self, s):
         s.execute("prepare pin (bigint, bigint, varchar(16)) as "
