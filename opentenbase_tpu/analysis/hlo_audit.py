@@ -24,7 +24,12 @@ for
 - ``hlo-wide-gather``    — a gather whose table or indices are i64 in a
   kernel that declares itself free of them (``export_check(...,
   no_wide_gather=True)``: the chip has no 64-bit lanes, so such a gather
-  is two word-gathers, and join_expand's positions all fit a word).
+  is two word-gathers, and the join kernels' positions and compared
+  words all fit one);
+- ``hlo-loop``           — a ``while`` in a kernel that declares itself
+  free of them (``export_check(..., no_loop=True)``: join_probe_counts
+  searches by rows of pivots, one row gather a level; a binary search
+  step inside a ``while`` cost the chip nine of those).
 
 ``python -m opentenbase_tpu.analysis.hlo_audit`` exports the kernel
 battery (add ``--full`` for the live query battery with fused/mesh
@@ -62,11 +67,13 @@ _CONDITIONAL = re.compile(r"stablehlo\.(case|if)\b")
 # attributes (`slice_sizes = array<i64: 1>` is no tensor)
 _WIDE_GATHER = re.compile(
     r"stablehlo\.gather.*:\s*\([^)]*i64>[^)]*\)\s*->")
+_LOOP = re.compile(r"stablehlo\.while\b")
 
 
 def scan_hlo_text(label: str, txt: str, no_scatter_sort: bool = False,
                   no_conditional: bool = False,
-                  no_wide_gather: bool = False) -> list:
+                  no_wide_gather: bool = False,
+                  no_loop: bool = False) -> list:
     """Scan one exported program's MLIR text; one finding per rule per
     program, at the first offending line."""
     findings = []
@@ -85,6 +92,9 @@ def scan_hlo_text(label: str, txt: str, no_scatter_sort: bool = False,
     if no_wide_gather:
         rules.append(("hlo-wide-gather", _WIDE_GATHER,
                       "64-bit gather in a kernel declared free of them"))
+    if no_loop:
+        rules.append(("hlo-loop", _LOOP,
+                      "while in a kernel declared free of them"))
     for rule, rx, msg in rules:
         m = rx.search(txt)
         if m:
@@ -106,7 +116,7 @@ def _sds_of(tree):
 def export_check(fn, args, label: str, report: dict,
                  no_scatter_sort: bool = False,
                  no_conditional: bool = False,
-                 no_wide_gather: bool = False):
+                 no_wide_gather: bool = False, no_loop: bool = False):
     """Export `fn(*args)` for platform 'tpu'; scan the StableHLO and
     record findings (f64 hits also land in the legacy report keys)."""
     import jax
@@ -122,7 +132,7 @@ def export_check(fn, args, label: str, report: dict,
         return
     report["programs"] = report.get("programs", 0) + 1
     for f in scan_hlo_text(label, txt, no_scatter_sort, no_conditional,
-                           no_wide_gather):
+                           no_wide_gather, no_loop):
         report.setdefault("findings", []).append(f)
         if f.rule == "hlo-f64":
             report.setdefault("f64", []).append(label)
@@ -157,19 +167,23 @@ def check_kernels(report: dict):
                 agg_kinds=("sum", "count", "min", "max", "sumf")),
             ((i, i), v, (i, i, i, f, f)),
             f"grouped_agg_sort/{n}", report)
-        # both arms of each join kernel: nothing known of the key's
-        # range (exact sort, binary search) and a host-known span
-        # (packed sort, direct-address table)
+        # both arms of join_build: nothing known of the key's range
+        # (exact sort) and a host-known span (packed sort); both word
+        # widths of join_probe_counts' search (the int64's halves; int32
+        # offsets, over a narrow span and a wide one): row gathers of
+        # 32-bit words, no loop, no scatter
         for span in (None, n // 2):
             export_check(
                 lambda k, m, span=span: K.join_build(k, m, key_span=span),
                 (i, v), f"join_build/{n}/{span}", report,
                 no_conditional=True)
+        for span in (None, n // 2, 4 * n):
             export_check(
                 lambda s_, k, m, span=span: K.join_probe_counts(
                     s_, k, m, key_span=span),
                 (i, i, v), f"join_probe_counts/{n}/{span}", report,
-                no_conditional=True)
+                no_scatter_sort=True, no_conditional=True,
+                no_wide_gather=True, no_loop=True)
         export_check(
             lambda lo, c, p: K.join_expand(lo, c, p, out_size=2 * n,
                                            left_outer=True,

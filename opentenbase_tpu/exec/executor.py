@@ -959,8 +959,9 @@ class Executor:
                           left.dicts, left.nulls, left.lazy, left.spans)
 
         left_outer = node.kind in ("left", "full")
-        total = jnp.sum(jnp.where(left.valid, jnp.maximum(counts, 1), 0)) \
-            if left_outer else jnp.sum(counts)
+        # the counts are int32 words; their sum may pass one
+        total = jnp.sum(jnp.where(left.valid, jnp.maximum(counts, 1), 0)
+                        if left_outer else counts, dtype=jnp.int64)
         if self._traced:
             # no host sync inside a compiled (shard_map) program: static
             # output class laddered per join id.  join_expand packs live

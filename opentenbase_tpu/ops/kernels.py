@@ -13,9 +13,9 @@ here every operator is a static-shape array program:
   a precomputed bounded group id — the path TPC-H Q1 takes, no sort, no
   scatter) or *sort-based* (lexicographic sort + segment reduce) for
   unbounded keys;
-- join is sort+search (build side sorted once; probe via a direct-address
-  table or one searchsorted pass, then a static-size pair expansion that
-  finds each output lane's rows by row gathers, in 32-bit words) — the
+- join is sort+search (build side sorted once; probe via a search by rows
+  of pivots, then a static-size pair expansion; both find their rows by
+  row gathers, in 32-bit words) — the
   TPU-friendly replacement for a chained hash table; multi-key joins combine
   via a 64-bit hash with a residual equality filter added by the planner;
 - all kernels take/return whole batches; invalid rows ride along masked.
@@ -34,6 +34,7 @@ from ..utils.dtypes import device_float
 INT64_MAX = np.int64(2**63 - 1)
 INT64_MIN = np.int64(-2**63)
 _INT32_MIN = np.int32(-2**31)
+_INT32_MAX = np.int32(2**31 - 1)
 
 
 def _scoped(scope: str):
@@ -381,7 +382,7 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
 
 
 # ---------------------------------------------------------------------------
-# join: sort build side once, probe with binary search, expand pairs
+# join: sort build side once, probe by row gathers, expand pairs
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("key_span",))
@@ -397,8 +398,11 @@ def join_build(build_keys, build_valid, key_span: int | None = None):
     column's class), None when it knows nothing (hashed multi-column
     keys, computed keys).  When the bound times n fits 62 bits, (key -
     min, position) pack into one int64 and a single-array `jnp.sort`
-    does it (the single-word trick of grouped_agg_sort; ~4x a
-    2-operand comparator sort on XLA CPU); otherwise the exact argsort.
+    does it (the single-word trick of grouped_agg_sort: ONE sort
+    operand, and the chip's compiler pays for every operand of a sort,
+    CHANGES.md PR 22; not timed against the argsort on the chip);
+    otherwise the exact argsort.  join_probe_counts reads the same
+    bound to choose the word its search compares.
     The choice used to be a `lax.cond` on the shard's own span: both
     sorts compiled into every program, and which one ran was the
     data's."""
@@ -418,96 +422,6 @@ def join_build(build_keys, build_valid, key_span: int | None = None):
     keys = jnp.where(build_valid, build_keys, INT64_MAX)
     perm = jnp.argsort(keys).astype(jnp.int32)
     return keys[perm], perm
-
-
-@functools.partial(jax.jit, static_argnames=("key_span",))
-@_scoped("otb.join_probe")
-def join_probe_counts(sorted_keys, probe_keys, probe_valid,
-                      key_span: int | None = None):
-    """Per-probe-row match range in the sorted build side.
-
-    Two strategies, ONE per program, chosen when the program is built:
-    direct-address where the host-known `key_span` (join_build's) fits
-    a table of T = max(2 * nb, np_) cells (enough for dense SQL keys:
-    TPC-H order/cust/supp keys are near-contiguous, without exceeding
-    the probe-side footprint class), else the binary search.  A
-    function of shapes and of program-key material alone.
-    - direct-address (dense keys): scatter the build rows into a
-      [key-min, key-min + T) table, probe = ONE gather (measured on a
-      v5e, 1,572,864 probes into 131,072 build rows: 99 ms against 874
-      ms for the binary search, PERF.md section 6 PR 27);
-    - binary search with ONE `searchsorted` (the right edge comes from
-      a run-end table built by a suffix-min scan on the small build
-      side) for sparse, hashed or unknown key spaces; over int32
-      offsets where the host-known span fits 32 bits.
-    The choice used to be a `lax.cond` on the shard's own span.
-
-    A device gather's time depends on WHICH addresses its lanes ask
-    for, so what an invalid probe row looks up matters: every one of
-    them searches INT64_MIN and walks the same leftmost path (mids
-    nb/2, nb/4, ... 0) whatever the build side holds.  They used to
-    search INT64_MAX - 1 and home in on the boundary between the live
-    keys and the invalid-build sentinels, a path set by the live COUNT:
-    on a v5e half of Q3's 1.5 M lineitem probes (the filtered rows)
-    then read one address per step, and the step cost 26.0 or 29.9 ms
-    by whether that count was under or over 38,912 (PERF.md section 6,
-    PR 27).
-
-    INT64_MAX is a reserved key value (the invalid-build sentinel): a
-    valid probe row carrying it is treated as unmatchable rather than
-    matching masked-out build rows.
-    """
-    nb = sorted_keys.shape[0]
-    np_ = probe_keys.shape[0]
-    usable = probe_valid & (probe_keys != INT64_MAX)
-    if not nb:
-        return (jnp.zeros(np_, dtype=jnp.int64),
-                jnp.zeros(np_, dtype=jnp.int64))
-
-    T = max(2 * nb, np_)
-    if key_span is not None and key_span < T:
-        live = sorted_keys != INT64_MAX
-        mn = sorted_keys[0]     # INT64_MAX when no build row is live
-        idx = jnp.arange(nb, dtype=jnp.int64)
-        cell = jnp.where(live, jnp.clip(sorted_keys - mn, 0, T - 1), T)
-        lo_tab = jnp.full(T + 1, nb, dtype=jnp.int64).at[cell].min(
-            idx, mode="drop")
-        cnt_tab = jnp.zeros(T + 1, dtype=jnp.int64).at[cell].add(
-            1, mode="drop")
-        # the offset in uint64: an int64 difference wraps for probe keys
-        # far below the build side's (a full-range key space)
-        off = probe_keys.astype(jnp.uint64) - mn.astype(jnp.uint64)
-        inb = usable & (probe_keys >= mn) & (off < jnp.uint64(T))
-        loc = jnp.where(inb, off, jnp.uint64(0)).astype(jnp.int64)
-        cnt = jnp.where(inb, cnt_tab[loc], 0)
-        lo = jnp.where(cnt > 0, lo_tab[loc], 0)
-        return lo, cnt
-
-    if key_span is not None and key_span < (1 << 31) - 1:
-        # the host-known span fits 32 bits: search int32 offsets from the
-        # build side's smallest key, ONE gather a step where an int64
-        # key costs the chip two (it has no 64-bit lanes); the invalid
-        # build rows' sentinel is the first offset past the span, a
-        # probe outside the span searches -1 like an invalid one
-        live = sorted_keys != INT64_MAX
-        mn = sorted_keys[0]     # INT64_MAX when no build row is live
-        sk = jnp.where(live, sorted_keys - mn,
-                       key_span + 1).astype(jnp.int32)
-        off = probe_keys.astype(jnp.uint64) - mn.astype(jnp.uint64)
-        ok = usable & (probe_keys >= mn) & (off <= jnp.uint64(key_span))
-        pk = jnp.where(ok, off.astype(jnp.int32), jnp.int32(-1))
-    else:
-        sk, ok = sorted_keys, usable
-        pk = jnp.where(probe_valid, probe_keys, INT64_MIN)
-    lo = jnp.searchsorted(sk, pk, side="left").astype(jnp.int32)
-    idx = jnp.arange(nb, dtype=jnp.int32)
-    chg = jnp.concatenate([sk[1:] != sk[:-1], jnp.ones(1, bool)])
-    nxt = jnp.where(chg, idx + 1, nb)
-    end = jax.lax.associative_scan(jnp.minimum, nxt[::-1])[::-1]
-    loc = jnp.clip(lo, 0, nb - 1)
-    hit = sk[loc] == pk
-    counts = jnp.where(ok & hit, end[loc] - lo, 0)
-    return lo.astype(jnp.int64), counts.astype(jnp.int64)
 
 
 #: words per row of join_expand's tables: a row of a [n / 128, 128] int32
@@ -555,6 +469,18 @@ def _by_passes(fn, out_size: int):
     return jax.tree.map(lambda o: o.reshape(-1)[:out_size], outs)
 
 
+def _in_passes(fn, lanes: tuple, per_pass: int):
+    """`fn(*lanes)`, per-lane arrays in and out, over at most `per_pass`
+    lanes at a time: each pass a static slice of the inputs, one after
+    another in the program.  Not `_by_passes`: its `lax.map` is a
+    `while`, which join_probe_counts declares itself free of
+    (analysis/hlo_audit, `hlo-loop`), and its lanes are an iota, not
+    inputs."""
+    outs = [fn(*(a[i:i + per_pass] for a in lanes))
+            for i in range(0, lanes[0].shape[0], per_pass)]
+    return jax.tree.map(lambda *o: jnp.concatenate(o), *outs)
+
+
 def _lane_search(csum, out_size: int):
     """j -> the first row whose running count `csum` passes lane j
     (`searchsorted(csum, j, side="right")`), clipped to the last row.
@@ -585,10 +511,130 @@ def _lane_search(csum, out_size: int):
     return search
 
 
+def _key_search(planes: tuple):
+    """q -> (lo, count): where a lane's query starts in a SORTED table
+    and how many entries equal it (`searchsorted` left, and right less
+    left), the table and the queries given as int32 word planes, most
+    significant first, compared lexicographically (one plane: a key that
+    fits a word; two: an int64's halves, `_planes`).
+
+    By rows of pivots as `_lane_search`: each level is the table below
+    it cut into rows of _ROW entries, a row's last entry its pivot; the
+    root's pivots are compared against every lane with no gather, then a
+    lane descends by ONE row gather a level and a compare-and-count over
+    the row.  Twice: once counting the entries below the query, once
+    those at or below it.  No table of run ends is built: a scan over
+    the build side costs the chip's compiler 12-165 s at 393,216 to
+    1,572,864 rows, this compiles in a second (PERF.md section 6,
+    PR 33).  A pad of INT32_MAX in every plane is INT64_MAX's image: at
+    or past every query a live lane asks."""
+    levels, top, width = [], planes, planes[0].shape[0]
+    while width > _ROOT:
+        rows = tuple(_rows_of(p, _INT32_MAX) for p in top)
+        levels.append(rows)
+        top, width = tuple(r[:, -1] for r in rows), rows[0].shape[0]
+
+    def count(last, entries, q):
+        hit = last(entries[-1], q[-1])
+        for e, x in zip(entries[-2::-1], q[-2::-1]):
+            hit = (e < x) | ((e == x) & hit)
+        return jnp.sum(hit, axis=1, dtype=jnp.int32)
+
+    def descend(last, q):
+        pos = count(last, [p[None, :] for p in top], q)
+        for rows in reversed(levels):
+            blk = jnp.minimum(pos, rows[0].shape[0] - 1)
+            pos = blk * _ROW + count(last, [r[blk] for r in rows], q)
+        return pos
+
+    def search(*q):
+        q = [x[:, None] for x in q]
+        lo = descend(jnp.less, q)
+        # one descent's gathered rows in memory at a time
+        lo, q = jax.lax.optimization_barrier((lo, q))
+        return lo, descend(jnp.less_equal, q) - lo
+    return search
+
+
+def _planes(keys):
+    """int64 -> (high, low) int32 words that compare lexicographically,
+    as signed words, the way the int64s compare: the chip has no 64-bit
+    lanes, and a compare over a gathered row of int64 is two of each."""
+    low = (keys & 0xFFFFFFFF).astype(jnp.uint32) ^ jnp.uint32(1 << 31)
+    return (keys >> 32).astype(jnp.int32), low.astype(jnp.int32)
+
+
 def _check_word(what: str, *sizes):
     if sum(sizes) >= 1 << 31:
         raise ValueError(f"{what}: positions in classes of {sizes} rows "
                          "do not fit below 2**31")
+
+
+@functools.partial(jax.jit, static_argnames=("key_span",))
+@_scoped("otb.join_probe")
+def join_probe_counts(sorted_keys, probe_keys, probe_valid,
+                      key_span: int | None = None):
+    """Per-probe-row match range in the sorted build side: (lo, count),
+    int32 (positions in a class a chip can hold; sum them in int64).
+
+    ONE formulation: the sorted build side is searched by rows of
+    pivots (`_key_search`: two descents of one row gather of 32-bit
+    words a level, nothing built but the pivots; no `while`, no scatter,
+    no 64-bit gather).  What the host knows of the keys (`key_span`,
+    join_build's) chooses the WORD it compares when the program is
+    built: int32 offsets from the build side's smallest key where the
+    span fits 32 bits, else (a hashed or unknown key) the two halves of
+    the int64 compared lexicographically, half as many lanes a pass.
+    On a v5e, kernel alone (PERF.md section 6, PR 33): 6,291,456 probe
+    rows into 393,216 build rows over a span of 6,291,455 69.6 ms, where
+    two int64 direct-address tables read by scalar gathers took 278.7;
+    1,572,864 into 131,072 over the same span 12.3, the
+    `jnp.searchsorted` it replaced 248.1; 1,572,864 into 16,384 hashed
+    keys 21.5 for 375.4.  A direct-address table in words (`start[c]`,
+    one sorted scatter-add, ONE row gather a probe row) lost to the
+    search at one chip's classes (72.8 and 83.1 against 69.6 and 70.9),
+    won 0.8-2.9 ms at a shard's, and went (the same section has its
+    curve and the cell's numbers without it).
+
+    A device gather's time depends on WHICH addresses its lanes ask
+    for, so what an invalid probe row looks up matters: every one of
+    them asks for the least value (offset -1, INT64_MIN) and reads the
+    same leftmost row at every level whatever the build side holds.
+    They used to search INT64_MAX - 1 and home in on the boundary
+    between the live keys and the invalid-build sentinels, a path set
+    by the live COUNT (PERF.md section 6, PR 27).
+
+    INT64_MAX is a reserved key value (the invalid-build sentinel): a
+    valid probe row carrying it is treated as unmatchable rather than
+    matching masked-out build rows.
+    """
+    nb = sorted_keys.shape[0]
+    np_ = probe_keys.shape[0]
+    _check_word("join_probe_counts", nb)
+    if not nb or not np_:
+        none = jnp.zeros(np_, jnp.int32)
+        return none, none
+    ok = probe_valid & (probe_keys != INT64_MAX)
+    wide = key_span is None or key_span >= (1 << 31) - 1
+    if wide:
+        table = _planes(sorted_keys)
+        query = _planes(jnp.where(probe_valid, probe_keys, INT64_MIN))
+    else:
+        # offsets from the build side's smallest key (INT64_MAX when no
+        # build row is live); an invalid build row takes the first one
+        # past the span.  The probe's in uint64: an int64 difference
+        # wraps for keys far below the build side's (a full-range space)
+        mn = sorted_keys[0]
+        table = (jnp.where(sorted_keys != INT64_MAX,
+                           jnp.clip(sorted_keys - mn, 0, key_span),
+                           key_span + 1).astype(jnp.int32),)
+        off = probe_keys.astype(jnp.uint64) - mn.astype(jnp.uint64)
+        ok = ok & (probe_keys >= mn) & (off <= jnp.uint64(key_span))
+        query = (jnp.where(ok, off.astype(jnp.int32), -1),)
+    # a level's gathered rows are a temporary a plane
+    lo, counts = _in_passes(_key_search(table), query,
+                            _MAX_LANES // (2 if wide else 1))
+    return lo, jnp.where(ok, counts, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("out_size",))

@@ -115,10 +115,30 @@ def test_join_build(one_chip):
     _compile(K.join_build, s(SMALL, I64), s(SMALL, BOOL))
 
 
-def test_join_probe_counts(one_chip):
+@pytest.mark.parametrize("nb, np_, span", [
+    (SMALL, 4 * SMALL, None), (SMALL, 4 * SMALL, SMALL // 2),
+    # the cells' joins at SF1: lineitem into orders on one chip; a
+    # lineitem shard into an orders shard and an orders shard into a
+    # customer shard on four; Q5's hashed (suppkey, nationkey) key
+    (393216, 6291456, 6291455), (131072, 1572864, 6291455),
+    (40960, 524288, 163839), (16384, 1572864, None)])
+def test_join_probe_counts(one_chip, nb, np_, span):
+    """Sort-free, so it goes to the real classes (a second or two each).
+    No `while` walks a binary search, no gather reads a 64-bit word,
+    nothing scatters, and the row gathers' temporaries ([lanes, 128]
+    int32) stay under 2 GiB because a class past 2**21 lanes runs in
+    passes."""
     s = one_chip
-    _compile(K.join_probe_counts, s(SMALL, I64), s(4 * SMALL, I64),
-             s(4 * SMALL, BOOL))
+    compiled = jax.jit(lambda sk, pk, pv: K.join_probe_counts(
+        sk, pk, pv, key_span=span)).lower(
+        s(nb, I64), s(np_, I64), s(np_, BOOL)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    text = compiled.as_text()
+    assert "f64[" not in text
+    gathers = [ln for ln in text.splitlines() if " gather(" in ln]
+    assert bool(gathers) is (nb > K._ROOT)
+    assert not [g for g in gathers if "s64[" in g or "u64[" in g]
+    assert " scatter(" not in text and " while(" not in text
 
 
 def test_join_expand(one_chip):
