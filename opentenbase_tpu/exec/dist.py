@@ -182,8 +182,10 @@ class DistExecutor:
             d = Distributor(self.cluster.catalog, self.cluster.ndn)
             sub = d.distribute(
                 PlannedStmt(_copy.deepcopy(ip.plan), [], []), None)
-            batch = self._run_distplan(sub)
-            val = self._scalar(batch)
+            # one span an init plan: `initplans` of summary() counts them
+            with obs_trace.span("initplan", plan=ip.name):
+                batch = self._run_distplan(sub)
+                val = self._scalar(batch)
             self.params[ip.name] = (val, ip.type)
         return self._run_distplan(dp)
 

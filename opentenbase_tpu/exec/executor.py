@@ -385,12 +385,18 @@ class Executor:
         self.join_required: list = []
         self.frag_tag = frag_tag
         self._join_seq = 0
+        # what the traversal built, for the compiled tiers to put on
+        # their `execute` span (obs/trace.py `summary()`): joins
+        # answered by a mask, sorted aggregates, the largest one's lanes
+        self.shape = {"semi_joins": 0, "sorted_aggs": 0,
+                      "sorted_agg_lanes": 0}
 
     # ------------------------------------------------------------------
     def run(self, planned: PlannedStmt):
         for ip in planned.init_plans:
-            batch = self.exec_node(ip.plan)
-            val = self._scalar_from_batch(batch, ip.type)
+            with obs_trace.span("initplan", plan=ip.name):
+                batch = self.exec_node(ip.plan)
+                val = self._scalar_from_batch(batch, ip.type)
             self.ctx.params[ip.name] = (val, ip.type)
         out = self.exec_node(planned.plan)
         return out
@@ -778,7 +784,7 @@ class Executor:
 
     def _exec_project(self, node: P.Project) -> DBatch:
         b = self.exec_node(node.child)
-        cols, types, dicts, nulls = {}, {}, {}, {}
+        cols, types, dicts, nulls, spans = {}, {}, {}, {}, {}
         for name, oe in node.outputs:
             arr, nm = self._eval_pair(oe, b)
             if getattr(arr, "ndim", 1) == 0:   # constant: broadcast
@@ -790,7 +796,9 @@ class Executor:
                 dicts[name] = d
             if nm is not None:
                 nulls[name] = nm
-        return DBatch(cols, b.valid, types, dicts, nulls)
+            if isinstance(oe, E.Col) and oe.name in b.spans:
+                spans[name] = b.spans[oe.name]    # the same values
+        return DBatch(cols, b.valid, types, dicts, nulls, spans=spans)
 
     # ---- join ----
     def _join_key(self, keys: list[E.Expr], b: DBatch):
@@ -955,6 +963,7 @@ class Executor:
                 and not hash_recheck:
             mask = K.semi_mask(counts) if node.kind == "semi" \
                 else K.anti_mask(counts, left.valid)
+            self.shape["semi_joins"] += 1
             return DBatch(left.cols, left.valid & mask, left.types,
                           left.dicts, left.nulls, left.lazy, left.spans)
 
@@ -1127,7 +1136,7 @@ class Executor:
         max_groups = next_pow2(max(b.count(), 1))
         c_left = (b.valid & (side == 0)).astype(jnp.int64)
         c_right = (b.valid & (side == 1)).astype(jnp.int64)
-        gkeys, (c1, c2), ng = K.grouped_agg_sort(
+        gkeys, (c1, c2), ng = self._sorted_agg(
             tuple(key_arrs), b.valid, (c_left, c_right), max_groups,
             ("sum", "sum"))
         ng = int(ng)
@@ -1250,10 +1259,38 @@ class Executor:
                  if nm is not None]
         return tuple(key_arrs) + tuple(extra)
 
+    @staticmethod
+    def _group_key_spans(node: P.Agg, b: DBatch, key_dicts, key_nulls):
+        """What the host knows of each grouping array's range (the
+        order of `_grouping_arrays`): a plain column's span as its scan
+        proved it (DBatch.spans), a dictionary's codes (the 0 a NULL is
+        set to is one of them), 1 for a null indicator, None where
+        nothing is known (a computed key; a plain key that may be NULL:
+        its NULLs were set to 0, outside the column's class)."""
+        spans = [max(len(d) - 1, 0) if d is not None
+                 else b.spans.get(ke.name)
+                 if isinstance(ke, E.Col) and nm is None else None
+                 for (_n, ke), d, nm in zip(node.group_keys, key_dicts,
+                                            key_nulls)]
+        return tuple(spans) + (1,) * sum(nm is not None for nm in key_nulls)
+
+    def _sorted_agg(self, keys, valid, inputs, max_groups, kinds,
+                    key_spans=None):
+        self.shape["sorted_aggs"] += 1
+        self.shape["sorted_agg_lanes"] = max(
+            self.shape["sorted_agg_lanes"], int(valid.shape[0]))
+        return K.grouped_agg_sort(keys, valid, inputs, max_groups, kinds,
+                                  key_spans=key_spans)
+
     def _assemble_agg_output(self, node: P.Agg, gkey_out, key_types,
                              key_dicts, outs, out_specs, out_valid,
-                             gkey_nulls=None):
+                             gkey_nulls=None, empty=None):
+        """`empty` (an aggregate without GROUP BY only): whether no row
+        reached it — its SUM, MIN and MAX are then NULL, not 0 (COUNT is
+        0); a grouped aggregate has no group without a row."""
         cols, types, dicts, nulls = {}, {}, {}, {}
+        not_counts = {n for n, ac in node.aggs if ac.func != "count"} \
+            if empty is not None else ()
         for i, ((kname, _), karr, kt, kd) in enumerate(
                 zip(node.group_keys, gkey_out, key_types, key_dicts)):
             cols[kname] = karr.astype(dev_dtype(kt))
@@ -1281,6 +1318,8 @@ class Executor:
             else:
                 cols[name] = outs[oi]
                 oi += 1
+                if name in not_counts:
+                    nulls[name] = empty
             types[name] = t
         return DBatch(cols, out_valid, types, dicts, nulls)
 
@@ -1395,6 +1434,7 @@ class Executor:
         n = b.padded
         any_null_keys = any(nm is not None for nm in key_nulls)
         gkey_nulls = [None] * len(key_arrs)
+        empty, out_spans = None, {}
         if not key_arrs:
             gid = jnp.zeros(n, dtype=jnp.int64)
             (outs, present) = K.grouped_agg_dense(
@@ -1402,6 +1442,7 @@ class Executor:
             out_valid = jnp.ones(1, dtype=bool)
             gkey_out = []
             padded_groups = 1
+            empty = present == 0
         else:
             dense_bound = _dense_bound(key_types, key_dicts) \
                 if not any_null_keys else None
@@ -1430,12 +1471,18 @@ class Executor:
                 # padding is masked out downstream either way
                 max_groups = b.padded if self._traced else \
                     next_pow2(max(b.count(), 1))
-                gkeys, outs, ng = K.grouped_agg_sort(
+                key_spans = self._group_key_spans(node, b, key_dicts,
+                                                  key_nulls)
+                gkeys, outs, ng = self._sorted_agg(
                     self._grouping_arrays(key_arrs, key_nulls), b.valid,
-                    tuple(inputs), max_groups, tuple(kinds))
+                    tuple(inputs), max_groups, tuple(kinds), key_spans)
                 if not self._traced:
                     ng = int(ng)
                 padded_groups = max_groups
+                # a group's key is one of its rows' keys: the bound holds
+                out_spans = {kn: sp for (kn, _), sp, d in
+                             zip(node.group_keys, key_spans, key_dicts)
+                             if sp is not None and d is None}
                 out_valid = jnp.arange(max_groups) < ng
                 gkey_out = list(gkeys[:len(key_arrs)])
                 extra = list(gkeys[len(key_arrs):])
@@ -1445,7 +1492,8 @@ class Executor:
 
         out = self._assemble_agg_output(node, gkey_out, key_types,
                                         key_dicts, outs, out_specs,
-                                        out_valid, gkey_nulls)
+                                        out_valid, gkey_nulls, empty)
+        out.spans.update(out_spans)
         return out
 
     def _exec_agg_final(self, node: P.Agg, b: DBatch) -> DBatch:
@@ -1471,7 +1519,7 @@ class Executor:
         else:
             max_groups = b.padded if self._traced else \
                 next_pow2(max(b.count(), 1))
-            gkeys, outs, ng = K.grouped_agg_sort(
+            gkeys, outs, ng = self._sorted_agg(
                 self._grouping_arrays(key_arrs, key_nulls), b.valid,
                 tuple(inputs), max_groups, tuple(kinds))
             if not self._traced:
@@ -1516,7 +1564,7 @@ class Executor:
             pseudo = dataclasses.replace(node, aggs=plain)
             kinds, inputs, out_specs = self._agg_inputs(pseudo, b,
                                                         final=False)
-            gkeys_p, outs, ng = K.grouped_agg_sort(
+            gkeys_p, outs, ng = self._sorted_agg(
                 gkeys_full or (jnp.zeros(b.padded, jnp.int64),),
                 b.valid, tuple(inputs), max_g, tuple(kinds))
             if not self._traced:
@@ -1554,7 +1602,7 @@ class Executor:
             keys1 = gkeys_full + (enc, nn.astype(jnp.int64))
             g1_pad = b.padded if self._traced else \
                 next_pow2(max(b.count(), 1))
-            gkeys1, _, ng1 = K.grouped_agg_sort(
+            gkeys1, _, ng1 = self._sorted_agg(
                 keys1, b.valid, (b.valid.astype(jnp.int64),), g1_pad,
                 ("count",))
             valid1 = jnp.arange(g1_pad) < ng1
@@ -1589,7 +1637,7 @@ class Executor:
             else:
                 raise ExecError(
                     f"DISTINCT {ac.func} unsupported")
-            gkeys2, outs2, ng2 = K.grouped_agg_sort(
+            gkeys2, outs2, ng2 = self._sorted_agg(
                 tuple(gkeys1[:n_gk]) if n_gk else
                 (jnp.zeros(g1_pad, jnp.int64),),
                 valid1, ins2, max_g, kinds2)

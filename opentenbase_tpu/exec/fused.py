@@ -486,6 +486,8 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
                     continue
             if has_join:
                 _ladder_remember(lkey, factors)
+            # what the program holds, fixed when it was traced
+            sp.set(**meta.get("shape", {}))
             if EXPORT_HOOK is not None:
                 EXPORT_HOOK("fused", fn,
                             (staged_arrs, jnp.int64(ctx.snapshot_ts),
@@ -548,6 +550,7 @@ def _build_program(ctx, frag_plan, baked, traced_names, lits, factors,
         meta["dicts"] = b.dicts
         meta["join_caps"] = tuple(
             (jid, cap) for jid, _req, cap in sub.join_required)
+        meta["shape"] = dict(sub.shape)
         # join_required is a host-side Python list (one entry per join
         # in the fragment, fixed at trace time) — its truthiness is not
         # a device read

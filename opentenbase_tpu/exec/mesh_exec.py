@@ -1069,6 +1069,7 @@ class MeshRunner:
             overflows = []
             meta["ex_order"] = []
             meta["exchanges"] = meta["exchange_bytes"] = 0
+            shape = meta["shape"] = {}
             join_reqs = []
             gather_out: dict = {}
             gather_over: list = []
@@ -1081,6 +1082,9 @@ class MeshRunner:
                 exe._traced = True
                 b = exe.exec_node(plan)
                 join_reqs.extend(exe.join_required)
+                for k, v in exe.shape.items():
+                    shape[k] = max(shape.get(k, 0), v) \
+                        if k == "sorted_agg_lanes" else shape.get(k, 0) + v
                 for ex in dp.exchanges:
                     if ex.source_fragment != frag.index:
                         continue
@@ -1180,7 +1184,8 @@ class MeshRunner:
             # one chip sends in them: fixed when the program was traced
             # (meta is filled by the trace the first call made)
             sp.set(exchanges=meta.get("exchanges", 0),
-                   exchange_bytes=meta.get("exchange_bytes", 0))
+                   exchange_bytes=meta.get("exchange_bytes", 0),
+                   **meta.get("shape", {}))
             plancache.MESH.record_call(fn, t0)
             if EXPORT_HOOK is not None:
                 EXPORT_HOOK("mesh", fn, tuple(flat_args))

@@ -391,6 +391,10 @@ def _trace_explain_lines() -> str:
             f"baked={int(qt.sum_attr('bind', 'baked'))} "
             f"dict_miss={int(qt.sum_attr('bind', 'dict_miss'))} "
             f"retraces={int(qt.sum_attr('execute', 'retraces'))}")
+    summary = qt.summary()
+    lines.append("Shape: " + " ".join(
+        f"{k}={summary[k]}" for k in ("semi_joins", "sorted_aggs",
+                                      "sorted_agg_lanes", "initplans")))
     rounds = int(qt.sum_attr("exchange", "rounds"))
     if rounds:
         lines.append(
@@ -1163,13 +1167,16 @@ class Session:
             ctx = ExecContext(self.node.stores, t.snapshot_ts, t.txid,
                               self.node.cache)
             with obs_trace.span("execute", tier="single") \
-                    if obs_trace.ENABLED else obs_trace.NULL_SPAN:
+                    if obs_trace.ENABLED else obs_trace.NULL_SPAN as sp:
                 if instrument:
                     from .executor import InstrumentedExecutor
                     exe = InstrumentedExecutor(ctx)
-                    batch = exe.run(planned)
                 else:
-                    batch = Executor(ctx).run(planned)
+                    exe = Executor(ctx)
+                batch = exe.run(planned)
+                # the operators this tier ran itself (a fused fragment
+                # under it carries its own on its own span)
+                sp.set(**exe.shape)
         names, rows = materialize(batch, planned.output_names)
         qt = obs_trace.current_trace() if obs_trace.ENABLED else None
         if qt is not None:

@@ -360,6 +360,7 @@ class QueryTrace:
         fetches = fetch_bytes = 0
         exchanges = exchange_bytes = 0
         traced = baked = retraces = 0
+        semi_joins = sorted_aggs = sorted_agg_lanes = initplans = 0
         hits = misses = 0
         work = [(self.root, ())]
         while work:
@@ -384,6 +385,14 @@ class QueryTrace:
                     exchanges += a.get("exchanges", 0) or 0
                     exchange_bytes += a.get("exchange_bytes", 0) or 0
                     retraces += a.get("retraces", 0) or 0
+                    if not a.get("retraces"):
+                        # the program that answered, not one whose
+                        # size class overflowed and was replayed
+                        semi_joins += a.get("semi_joins", 0) or 0
+                        sorted_aggs += a.get("sorted_aggs", 0) or 0
+                        sorted_agg_lanes = max(
+                            sorted_agg_lanes,
+                            a.get("sorted_agg_lanes", 0) or 0)
                 elif name == "bind":
                     traced += a.get("traced", 0) or 0
                     baked += a.get("baked", 0) or 0
@@ -392,6 +401,8 @@ class QueryTrace:
                         hits += 1
                     elif a.get("hit") is False:
                         misses += 1
+            if name == "initplan":
+                initplans += 1
             for c in s.children:
                 work.append((c, inside_c))
         d = {
@@ -434,6 +445,14 @@ class QueryTrace:
         d["finalize_fetch_bytes"] = int(fetch_bytes)
         d["exchanges"] = int(exchanges)
         d["exchange_bytes"] = int(exchange_bytes)
+        # the shape of the compiled programs that answered, fixed when
+        # they were traced: joins answered by a mask (semi, anti: no
+        # expansion), sorted aggregates and the padded rows of the
+        # largest; and the scalar subqueries run before the statement
+        d["semi_joins"] = int(semi_joins)
+        d["sorted_aggs"] = int(sorted_aggs)
+        d["sorted_agg_lanes"] = int(sorted_agg_lanes)
+        d["initplans"] = int(initplans)
         d["unattributed_ms"] = self.root.self_ms()
         return d
 

@@ -24,6 +24,7 @@ here every operator is a static-shape array program:
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -259,10 +260,12 @@ def _sortable_int(k, valid):
     return k, mn, mx
 
 
-@functools.partial(jax.jit, static_argnames=("max_groups", "agg_kinds"))
+@functools.partial(jax.jit, static_argnames=("max_groups", "agg_kinds",
+                                             "key_spans"))
 @_scoped("otb.agg")
 def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
-                     max_groups: int, agg_kinds: tuple):
+                     max_groups: int, agg_kinds: tuple,
+                     key_spans: tuple | None = None):
     """General grouped aggregation: sort on the key columns (invalid
     rows last), boundary detection, segment reduce.
 
@@ -276,8 +279,23 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
     is injective exactly when prod(ranges)*n fits 62 bits, checked at
     runtime; `lax.cond` falls back to the exact multi-operand
     comparator sort otherwise (hashed/full-range keys).  Payloads are
-    gathered once through perm; segment reductions run with
-    indices_are_sorted.
+    gathered once through perm.  The sorted groups are runs: a group's
+    first row is found by join_expand's search of a running count, its
+    COUNT is the distance to the next group's, its integer SUM the
+    difference of one running sum there; float sums, min and max reduce
+    by segment (indices_are_sorted).  A group's keys are those of its
+    first row, `k[perm[starts]]`, or, where ONE key's bound proves the
+    pack, the sorted image there plus the least key.
+
+    `key_spans` is what the host knows of each key column when the
+    program is built (an upper bound on max - min over the valid rows,
+    None where it knows nothing; executor._group_key_spans).  Where
+    every bound is known the sort is chosen THEN, as join_build chooses
+    its own: the pack if the bounds prove it injective, else exact
+    passes over the keys packed into as few words as the bounds allow
+    (Q18's five keys: two) — ONE of the two in the program, where the
+    `lax.cond` compiles both (each sort costs the chip's compiler most
+    of a minute, CHANGES.md PR 34).
 
     Returns (group_key_cols, agg_outputs, n_groups).  Caller guarantees
     distinct-group count <= max_groups (host retries at the next size
@@ -325,59 +343,130 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
         s_valid = valid[perm]
         first = jnp.arange(n) == 0
         boundary = s_valid & (first | (img != jnp.roll(img, 1)))
-        return perm, s_valid, boundary
+        return perm, s_valid, boundary, img
 
-    def exact(_):
-        # stable single-key passes, least significant key first and the
-        # invalid flag last: equal tuples end up adjacent, invalid rows
-        # last.  Each pass is a 2-operand sort — the TPU compiler's time
-        # for a sort grows with every operand and key, and this branch
-        # is compiled into every program even when the fast path runs
-        # (one 6-operand sort at 163840 rows was most of Q3's compile;
-        # CHANGES.md, PR 22)
+    def exact(words):
+        # stable single-word passes, least significant word first and
+        # the one that holds the invalid flag last: equal tuples end up
+        # adjacent, invalid rows last.  Each pass is a 2-operand sort —
+        # the TPU compiler's time for a sort grows with every operand
+        # and key (one 6-operand sort at 163840 rows was most of Q3's
+        # compile; CHANGES.md, PR 22)
         perm = jnp.arange(n, dtype=jnp.int32)
-        for ki in (*reversed(ints), invalid):
-            perm = jax.lax.sort([ki[perm], perm], num_keys=1)[1]
+        for w in reversed(words):
+            perm = jax.lax.sort([w[perm], perm], num_keys=1)[1]
         s_valid = valid[perm]
         first = jnp.arange(n) == 0
         differs = jnp.zeros(n, dtype=bool)
-        for ki in ints:
-            k = ki[perm]
+        for w in words:
+            k = w[perm]
             differs = differs | (k != jnp.roll(k, 1))
         boundary = s_valid & (first | differs)
         return perm.astype(jnp.int64), s_valid, boundary
 
-    perm, s_valid, boundary = jax.lax.cond(pack_ok, fast, exact, None)
+    def packed_words():
+        """The invalid flag and the keys, most significant first, in as
+        few int64 words as the host's bounds allow: a key takes the bits
+        its bound needs, as an offset from its run-time minimum (which
+        cannot pass the bound); one whose bound fills a word rides
+        alone, as its own image."""
+        bits = [1] + [max(1, math.ceil(math.log2(sp + 2)))
+                      for sp in key_spans]
+        cols = [(invalid.astype(jnp.int64), jnp.int64(0))] \
+            + list(zip(ints, mns))
+        groups, used = [[]], 0
+        for i, b in enumerate(bits):
+            if groups[-1] and used + b > 62:
+                groups.append([])
+                used = 0
+            groups[-1].append(i)
+            used += b
+        words = []
+        for grp in groups:
+            if len(grp) == 1:
+                words.append(cols[grp[0]][0])
+                continue
+            acc = jnp.zeros(n, dtype=jnp.int64)
+            for i in grp:
+                k, mn = cols[i]
+                acc = (acc << bits[i]) | jnp.clip(k - mn, 0,
+                                                  (1 << bits[i]) - 1)
+            words.append(acc)
+        return words
+
+    img = None      # the packed keys in sorted order, where the pack is proven
+    if key_spans is not None and None not in key_spans:
+        # the same sum over the bounds (the run-time spans cannot pass
+        # them), in Python: the sort is the program's, not the data's
+        proven = sum(math.log2(sp + 2) for sp in key_spans) \
+            + math.log2(n + 2) < 62
+        if proven:
+            perm, s_valid, boundary, img = fast(None)
+        else:
+            perm, s_valid, boundary = exact(packed_words())
+    else:
+        perm, s_valid, boundary = jax.lax.cond(
+            pack_ok, lambda _: fast(None)[:3],
+            lambda _: exact([invalid, *ints]), None)
     n_groups = jnp.sum(boundary)
-    gid_raw = jnp.cumsum(boundary) - 1
-    gid = jnp.where(s_valid, gid_raw, max_groups)
+    run = jnp.cumsum(boundary)      # groups begun up to and at a row
+    # each group's first row, by the search join_expand makes of a
+    # running count: row gathers, where jnp.nonzero is a scatter
+    starts = _by_passes(_lane_search(run, max_groups), max_groups) \
+        if n else jnp.zeros(max_groups, jnp.int32)
+    slot = jnp.arange(max_groups)
+    live, last = slot < n_groups, slot == n_groups - 1
+
+    def run_totals(below, total):
+        """A group's total from a running total taken BELOW each group's
+        first row (the sorted groups are runs: the next group's reading
+        less this one's; the last group's from the grand total)."""
+        above = jnp.where(last, total, jnp.roll(below, -1))
+        return jnp.where(live, above - below, 0)
+
     outs = []
     for kind, vals in zip(agg_kinds, agg_inputs):
         if kind == "count":
-            vals = s_valid.astype(jnp.int64)
+            # the valid rows are a prefix: a row's index counts them
+            outs.append(run_totals(starts.astype(jnp.int64),
+                                   jnp.sum(valid, dtype=jnp.int64)))
+            continue
+        vals = vals[perm]
+        if kind == "sum" and jnp.issubdtype(vals.dtype, jnp.integer):
+            # exact in int64 whatever wraps on the way: differences of
+            # ONE running sum, one gather, where a scatter-add over
+            # 6,291,456 sorted rows cost the chip 0.6 s (PR 34)
+            vals = _masked_for("sum", vals.astype(jnp.int64), s_valid)
+            below = (jnp.cumsum(vals) - vals)[starts]
+            outs.append(run_totals(below, jnp.sum(vals)))
+            continue
+        # float sums (a difference of running sums would cancel) and
+        # min/max: reduced by segment
+        gid = jnp.where(s_valid, run - 1, max_groups)
+        if kind == "sumf":
+            vals = _masked_for("sum", vals.astype(device_float()),
+                               s_valid)
         else:
-            vals = vals[perm]
-            if kind == "sumf":
-                vals = _masked_for("sum", vals.astype(device_float()),
-                                   s_valid)
-            else:
-                vals = _masked_for(kind, vals, s_valid)
-        if kind == "min":
-            o = jax.ops.segment_min(vals, gid,
-                                    num_segments=max_groups + 1,
-                                    indices_are_sorted=True)
-        elif kind == "max":
-            o = jax.ops.segment_max(vals, gid,
-                                    num_segments=max_groups + 1,
-                                    indices_are_sorted=True)
-        else:
-            o = jax.ops.segment_sum(vals, gid,
-                                    num_segments=max_groups + 1,
-                                    indices_are_sorted=True)
-        outs.append(o[:max_groups])
-    starts = jnp.nonzero(boundary, size=max_groups, fill_value=0)[0]
-    take = perm[starts]
-    gkeys = tuple(k[take] for k in key_cols)
+            vals = _masked_for(kind, vals, s_valid)
+        reduce = {"min": jax.ops.segment_min,
+                  "max": jax.ops.segment_max}.get(kind, jax.ops.segment_sum)
+        outs.append(reduce(vals, gid, num_segments=max_groups + 1,
+                           indices_are_sorted=True)[:max_groups])
+    if img is not None and len(key_spans) == 1 \
+            and key_spans[0] + 2 < 1 << 31 \
+            and jnp.issubdtype(key_cols[0].dtype, jnp.integer):
+        # ONE packed key: the sorted image at a group's first row is the
+        # key's offset from the least, a word.  No `k[perm[starts]]`:
+        # most slots lie past the last group (6.1 M of Q17's 6,291,456)
+        # and read ONE address, and such a gather from a table column
+        # took 118.8 or 188.4 ms by where the column lay in HBM, a Q17
+        # reply 1,210 or 1,280 ms from one run to the next of ONE seed
+        # (PERF.md section 6, PR 34)
+        off = img.astype(jnp.int32)[starts].astype(jnp.int64)
+        gkeys = ((off + mns[0]).astype(key_cols[0].dtype),)
+    else:
+        take = perm[starts]
+        gkeys = tuple(k[take] for k in key_cols)
     return gkeys, tuple(outs), n_groups
 
 
@@ -661,7 +750,8 @@ def join_expand(lo, counts, perm, out_size: int, left_outer: bool = False,
     build_idx == -1 (the null row); pass probe_valid so padding rows don't
     null-extend.  Returns (probe_idx, build_idx, total): the indices
     int32, `total` the exact int64 number of pairs (it may pass out_size
-    and a word: the size ladder compares it with out_size).
+    and a word: the size ladder compares it with out_size).  A lane at or
+    past `total` carries indices in range, spread over the two sides.
 
     Every position is below a static class, so only `total` and the
     running count behind it need 64 bits.  A lane finds its probe row p
@@ -678,8 +768,8 @@ def join_expand(lo, counts, perm, out_size: int, left_outer: bool = False,
     one row gather 216; the plain 32-bit binary search 305; what it
     replaced (a 64-bit `searchsorted` of the running count, then
     `csum[p]`, `eff[p]`, `lo[p]`, `perm[...]`, all int64) 1,120.  What
-    the lanes at or past `total` look up moved nothing (26.0 or 26.6
-    ms): they search their own j and `valid` cuts them.
+    the lanes at or past `total` look up INSIDE the kernel moved nothing
+    (26.0 or 26.6 ms): they search their own j and `valid` cuts them.
     """
     np_, nb = counts.shape[0], perm.shape[0]
     _check_word("join_expand", out_size, max(np_, nb))
@@ -709,11 +799,22 @@ def join_expand(lo, counts, perm, out_size: int, left_outer: bool = False,
         # j + INT32_MIN stays in the word: j >= 0
         build_idx = _take(perm_rows, jnp.clip(j + dp, 0, max(nb - 1, 0)))
         valid = j < total
+        # a lane at or past `total` is cut by the caller's mask, but the
+        # column gathers behind the join still read where it points.
+        # Not row 0 for all of them: 1,572,864 lanes of which a few
+        # thousand are live read ONE address of a table column in 28 to
+        # 62 ms, by where the column lies and what the live lanes ask
+        # (Q17 on one of three levels, Q18 on 1,178-1,228 ms; PERF.md
+        # section 6, PR 34).  Each reads a row of its own, spread over
+        # the side: what a gather of live lanes costs, whatever the run
+        spread = j.astype(jnp.uint32) * jnp.uint32(2654435761)
         if left_outer:
             build_idx = jnp.where(dp == _INT32_MIN, -1, build_idx)
         else:
-            build_idx = jnp.where(valid, build_idx, 0)
-        return jnp.where(valid, p, 0), build_idx
+            build_idx = jnp.where(valid, build_idx,
+                                  (spread % max(nb, 1)).astype(jnp.int32))
+        return jnp.where(valid, p, (spread % np_).astype(jnp.int32)), \
+            build_idx
 
     return (*_by_passes(pairs, out_size), total)
 

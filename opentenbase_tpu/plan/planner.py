@@ -11,7 +11,11 @@ rewrite family — "subquery -> correlated query rewrite + DN pushdown"):
 - EXISTS / IN (subquery)           -> semi / anti HashJoin
 - uncorrelated scalar subquery     -> init plan (executed once, substituted)
 - correlated scalar aggregate      -> decorrelation: grouped derived table
-                                      joined on the correlation keys
+                                      joined on the correlation keys; an
+                                      exact column compared with [k *]
+                                      AVG(exact) is decided in integers
+                                      (x * count OP k * sum), as numeric
+                                      decides it: a tie is a tie
 Join order: greedy connection-aware ordering over the equi-join conjunct
 graph (no cross joins unless forced), left-deep, new table as build side.
 """
@@ -135,6 +139,29 @@ def _hoist_or_common(q: E.Expr) -> list[E.Expr]:
 def _strpred_plain(p: E.StrPred) -> str:
     c = p.col.col if isinstance(p.col, E.TextExpr) else p.col
     return c.name.split(".", 1)[-1]
+
+
+_EXACT = (TypeKind.INT32, TypeKind.INT64, TypeKind.DECIMAL)
+
+
+def _exact_avg(e: E.Expr):
+    """(k | None, avg) when `e` is AVG over an exact (integer, decimal)
+    argument, alone or times an exact literal k; else None."""
+    def avg(x):
+        return isinstance(x, E.AggCall) and x.func == "avg" \
+            and not x.distinct and x.arg.type.kind in _EXACT
+
+    def lit(x):
+        return isinstance(x, E.Lit) and x.value is not None \
+            and x.type.kind in _EXACT
+    if avg(e):
+        return None, e
+    if isinstance(e, E.Arith) and e.op == "*":
+        if lit(e.left) and avg(e.right):
+            return e.left, e.right
+        if lit(e.right) and avg(e.left):
+            return e.right, e.left
+    return None
 
 
 def _is_equi_pair(e: E.Expr):
@@ -548,6 +575,21 @@ class Planner:
         if forced and (set(forced) != set(aliases) or outer_steps
                        or semijoins):
             forced = []          # stale/ineligible baseline: ignore
+        if not outer_steps:
+            # a semi/anti join whose outer columns all belong to ONE
+            # table filters that table before it is joined (inner joins
+            # commute with it): Q18's IN leaves 57 of 1.5 M orders for
+            # the customer join, not 1.5 M pairs for the mask
+            for sj in list(semijoins):
+                need = sj["outer_cols"] | {
+                    c for q in sj["residual"] for c in expr_cols(q)
+                    if any(c in rte_cols[a] for a in aliases)}
+                owners = [a for a in aliases if need <= rte_cols[a]]
+                if len(owners) == 1:
+                    semijoins.remove(sj)
+                    scans[owners[0]] = P.HashJoin(
+                        scans[owners[0]], sj["plan"], sj["outer_keys"],
+                        sj["inner_keys"], sj["kind"], sj["residual"])
         while remaining:
             cand = None
             if forced:
@@ -665,17 +707,47 @@ class Planner:
 
         def scalar_replacement(sl: SubLink) -> E.Expr:
             if sl.query.correlated_cols:
-                return self._decorrelate_scalar(sl, bq, init_plans)
+                return self._decorrelate_scalar(
+                    sl, bq, init_plans, [sl.query.targets[0][1]])[0]
             name = f"__initplan{next(self._ip_counter)}"
             sub = self._plan_query(sl.query, init_plans)
             t = sl.query.targets[0][1].type
             init_plans.append(InitPlan(name, sub, t))
             return E.Col(name, t)
 
+        def is_scalar(x) -> bool:
+            return isinstance(x, SubLink) and x.link_kind == "scalar"
+
+        def exact_avg_cmp(x: E.Cmp):
+            """`x OP (select [k *] avg(y) ... correlated)` over exact x and
+            y: the derived table carries sum(y) and count(y), and the
+            comparison is `x * count OP k * sum` in integers."""
+            for sub, other, sub_left in ((x.right, x.left, False),
+                                        (x.left, x.right, True)):
+                if not (is_scalar(sub) and sub.query.correlated_cols
+                        and other.type.kind in _EXACT
+                        and not any(isinstance(y, SubLink)
+                                    for y in E.walk(other))):
+                    continue
+                form = _exact_avg(sub.query.targets[0][1])
+                if form is None:
+                    continue
+                k, avg = form
+                qsum, n = self._decorrelate_scalar(
+                    sub, bq, init_plans, [E.AggCall("sum", avg.arg),
+                                          E.AggCall("count", avg.arg)])
+                mine = E.Arith("*", other, n)
+                theirs = qsum if k is None else E.Arith("*", k, qsum)
+                return E.Cmp(x.op, theirs, mine) if sub_left \
+                    else E.Cmp(x.op, mine, theirs)
+            return None
+
         def rewrite_scalars(e: E.Expr) -> E.Expr:
-            return rewrite(e, lambda x: scalar_replacement(x)
-                           if isinstance(x, SubLink)
-                           and x.link_kind == "scalar" else None)
+            def fn(x):
+                if isinstance(x, E.Cmp):
+                    return exact_avg_cmp(x)
+                return scalar_replacement(x) if is_scalar(x) else None
+            return rewrite(e, fn)
 
         def uncorrelated_exists(sl: SubLink) -> E.Expr:
             """EXISTS with no outer reference: one-row init plan probing
@@ -840,7 +912,7 @@ class Planner:
                 if qname not in corr]
 
     def _decorrelate_scalar(self, sl: SubLink, outer_bq: BoundQuery,
-                            init_plans) -> E.Expr:
+                            init_plans, values: list) -> list:
         """Correlated scalar aggregate -> grouped derived table + join.
 
         select ... where expr OP (select AGG(x) from T where T.k = outer.k
@@ -848,6 +920,10 @@ class Planner:
         group by T.k, joined on derived.k = outer.k; OP compares against
         the agg column.  (The reference implements this family of rewrites
         in its optimizer; v2.2 release note lines 3-4.)
+
+        `values` are the derived table's aggregates (the subquery's
+        target; an exact comparison's sum and count in its place); one
+        column of the derived table comes back for each.
         """
         sub = sl.query
         corr = set(sub.correlated_cols)
@@ -871,16 +947,15 @@ class Planner:
         if not outer_keys:
             raise PlanError("correlated scalar subquery without equality "
                             "correlation")
-        val_name, val_expr = sub.targets[0]
-        targets = [("__val", val_expr)] + \
-            [(f"__k{i}", k) for i, k in enumerate(inner_keys)]
+        vals = [(f"__val{i}", v) for i, v in enumerate(values)]
+        targets = vals + [(f"__k{i}", k) for i, k in enumerate(inner_keys)]
         derived = dataclasses.replace(
             sub, where=inner_where, targets=targets,
             group_by=list(inner_keys), having=[], order_by=[],
             limit=None, offset=None, correlated_cols=[])
         alias = f"__dsq{next(self._ip_counter)}"
         rte = RTE(alias, "subquery", subquery=derived,
-                  columns={"__val": (f"{alias}.__val", val_expr.type),
+                  columns={**{n: (f"{alias}.{n}", v.type) for n, v in vals},
                            **{f"__k{i}": (f"{alias}.__k{i}", k.type)
                               for i, k in enumerate(inner_keys)}})
         outer_bq.rtable.append(rte)
@@ -890,7 +965,7 @@ class Planner:
             outer_bq.where.append(E.Cmp("=", ok,
                                         E.Col(f"{alias}.__k{i}",
                                               inner_keys[i].type)))
-        return E.Col(f"{alias}.__val", val_expr.type)
+        return [E.Col(f"{alias}.{n}", v.type) for n, v in vals]
 
     # -- aggregation & projection ------------------------------------------
     def _plan_agg_project(self, bq: BoundQuery, plan: P.PhysNode):

@@ -120,11 +120,16 @@ def test_pairs_order_and_total(name):
     np.testing.assert_array_equal(pi[:k], probe)
     np.testing.assert_array_equal(bi[:k], build)
     # lanes at or past `total` point at rows in range: the executor
-    # gathers through them before `valid` cuts them
-    assert (pi[k:] == 0).all()
-    assert ((bi[k:] >= -1) & (bi[k:] < perm.shape[0])).all()
+    # gathers through them before `valid` cuts them.  Spread over the
+    # sides, not all at row 0: a gather nearly all of whose lanes read
+    # ONE address costs the chip by the run (PERF.md section 6, PR 34)
+    assert ((pi[k:] >= 0) & (pi[k:] < counts.shape[0])).all()
+    assert ((bi[k:] >= -1) & (bi[k:] < max(perm.shape[0], 1))).all()
     if not left_outer:
-        assert (bi[k:] == 0).all()
+        assert (bi[k:] >= 0).all()
+    if out_size - k >= 64 and counts.shape[0] >= 64:
+        assert len(set(pi[k:].tolist())) > min(out_size - k,
+                                               counts.shape[0]) // 4
 
 
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.int64])

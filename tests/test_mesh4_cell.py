@@ -106,7 +106,10 @@ def test_no_join_algorithm_is_chosen_by_the_data(served):
     direct-or-searched probe are chosen when the program is built
     (ops/kernels.join_build), so which arm runs is not the shard's data's
     to say.  The reading can see one: the sorted aggregate's pack test
-    (otb.agg) is still a conditional in Q3's program."""
+    (otb.agg) is still a conditional in Q5's program (its final aggregate
+    groups by a dictionary code, whose range the host does not state;
+    Q3's three keys' ranges are known and its sort is chosen when the
+    program is built, PR 34)."""
     conditionals = []
     for fn, shapes in mesh_check.PROGRAMS.values():
         for line in fn.lower(*shapes).compile().as_text().splitlines():

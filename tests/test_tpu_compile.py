@@ -121,7 +121,10 @@ def test_join_build(one_chip):
     # lineitem shard into an orders shard and an orders shard into a
     # customer shard on four; Q5's hashed (suppkey, nationkey) key
     (393216, 6291456, 6291455), (131072, 1572864, 6291455),
-    (40960, 524288, 163839), (16384, 1572864, None)])
+    (40960, 524288, 163839), (16384, 1572864, None),
+    # tpch_sf1_subq's semi joins (Q4's EXISTS, Q18's IN): lineitem's
+    # 6,291,456 lanes as the BUILD side, orders' rows probing it
+    (6291456, 1572864, 6291455)])
 def test_join_probe_counts(one_chip, nb, np_, span):
     """Sort-free, so it goes to the real classes (a second or two each).
     No `while` walks a binary search, no gather reads a 64-bit word,
@@ -171,11 +174,22 @@ def test_join_expand_at_sf1(one_chip, out_size, left_outer):
     assert (" while(" in text) is (out_size > K._MAX_LANES)
 
 
-def test_grouped_agg_sort(one_chip):
+@pytest.mark.parametrize("key_spans, sorts, chosen_by_data", [
+    (None, 3, True),            # nothing known: both sorts, a conditional
+    ((6291455,), 1, False),     # the bound proves the pack: one sort
+    ((1 << 62,), 2, False)])    # it cannot: the exact passes alone
+def test_grouped_agg_sort(one_chip, key_spans, sorts, chosen_by_data):
+    """What the host knows of the keys' range chooses the sort when the
+    program is built (Q17's 200 K- and Q18's 1.5 M-group aggregates over
+    lineitem: ONE sort where there were three; each costs the chip's
+    compiler most of a minute at 6,291,456 lanes, so this stays small)."""
     s = one_chip
-    _compile(jax.jit(lambda k, v, a: K.grouped_agg_sort(
-        (k,), v, (a,), max_groups=SMALL, agg_kinds=("sum",))),
+    text = _compile(jax.jit(lambda k, v, a: K.grouped_agg_sort(
+        (k,), v, (a,), max_groups=SMALL, agg_kinds=("sum",),
+        key_spans=key_spans)),
         s(SMALL, I64), s(SMALL, BOOL), s(SMALL, I64))
+    assert text.count(" sort(") == sorts
+    assert (" conditional(" in text) is chosen_by_data
 
 
 def test_sort_rows_top10(one_chip):
@@ -213,8 +227,9 @@ def test_q3_mesh_program_on_four_chips(topo, tpu_mode, tmp_path):
     run in the chip's dtype mode and compiled for the described 2x2 (at
     SF0.01's size classes: SF1's compile in minutes, on the chip, PERF.md
     section 6).  The compiled text carries the exchange, and no
-    `conditional` under an `otb.join_*` scope: the join kernels' algorithm
-    is chosen when the program is built, not by the shard's data."""
+    `conditional` under an `otb.join_*` or `otb.agg` scope: the join
+    kernels' algorithm and the sorted aggregate's sort are chosen when the
+    program is built, not by the shard's data."""
     from benchmarks.lib import datagen, files, mesh_check
     from benchmarks.lib import stack as stack_mod
     from benchmarks.lib.traffic import Mix, Request
@@ -254,6 +269,10 @@ def test_q3_mesh_program_on_four_chips(topo, tpu_mode, tmp_path):
     text = _compile(jax.jit(exp.call), *[described(a) for a in shapes])
     assert "all-to-all" in text
     conditionals = [ln for ln in text.splitlines() if " conditional(" in ln]
-    # the names survive the export: the sorted aggregate's pack test shows
-    assert any("otb.agg" in ln for ln in conditionals)
-    assert not [ln for ln in conditionals if "otb.join_" in ln]
+    # the names survive the export
+    assert "otb.agg" in text and "otb.join_probe" in text
+    # nor under `otb.agg` since PR 34: the ranges of Q3's three group keys
+    # are known when the program is built, and the sorted aggregate's
+    # pack-or-exact sort is chosen then
+    assert not [ln for ln in conditionals
+                if "otb.join_" in ln or "otb.agg" in ln]
