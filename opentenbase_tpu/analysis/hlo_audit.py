@@ -167,6 +167,18 @@ def check_kernels(report: dict):
                 agg_kinds=("sum", "count", "min", "max", "sumf")),
             ((i, i), v, (i, i, i, f, f)),
             f"grouped_agg_sort/{n}", report)
+        # the shape the cells run since a traced aggregate has an output
+        # class of its own (executor._agg_class): fewer slots than rows,
+        # one key of host-known span (Q17's), SUM and COUNT by running
+        # totals — ONE sort, no `lax.cond`, and a slot search in one pass
+        # (no `lax.map`).  The sort and the 64-bit `vals[perm]` are the
+        # kernel's own: those two rules cannot be declared of it
+        export_check(
+            lambda k, m, a: K.grouped_agg_sort(
+                k, m, a, max_groups=n // 4, agg_kinds=("sum", "count"),
+                key_spans=(n // 2,)),
+            ((i,), v, (i, i)), f"grouped_agg_sort/{n}/{n // 4}", report,
+            no_conditional=True, no_loop=True)
         # both arms of join_build: nothing known of the key's range
         # (exact sort) and a host-known span (packed sort); both word
         # widths of join_probe_counts' search (the int64's halves; int32

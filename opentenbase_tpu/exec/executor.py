@@ -20,6 +20,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 import os
 import threading
 from typing import Optional
@@ -388,8 +389,9 @@ class Executor:
         # what the traversal built, for the compiled tiers to put on
         # their `execute` span (obs/trace.py `summary()`): joins
         # answered by a mask, sorted aggregates, the largest one's lanes
+        # (its INPUT's padded rows) and the largest OUTPUT class
         self.shape = {"semi_joins": 0, "sorted_aggs": 0,
-                      "sorted_agg_lanes": 0}
+                      "sorted_agg_lanes": 0, "sorted_agg_groups": 0}
 
     # ------------------------------------------------------------------
     def run(self, planned: PlannedStmt):
@@ -979,10 +981,7 @@ class Executor:
             # overflow retraces one step up — the learned value persists
             # in the mesh runner's ladder memory, and every op downstream
             # of the join (agg sorts, exchanges, gathers) scales with it
-            jid = (self.frag_tag, self._join_seq)
-            self._join_seq += 1
-            factor = (self.ctx.join_factors or {}).get(
-                jid, self.ctx.join_size_factor)
+            jid, factor = self._ladder_slot()
             out_size = max(64, (max(left.padded, right.padded) // 4)
                            * factor)
             self.join_required.append((jid, total, out_size))
@@ -1274,13 +1273,56 @@ class Executor:
                                             key_nulls)]
         return tuple(spans) + (1,) * sum(nm is not None for nm in key_nulls)
 
+    def _ladder_slot(self):
+        """The next id of this fragment's laddered operators (traced
+        joins and sorted aggregates, in plan order) and the factor the
+        runner has learned for it."""
+        jid = (self.frag_tag, self._join_seq)
+        self._join_seq += 1
+        return jid, (self.ctx.join_factors or {}).get(
+            jid, self.ctx.join_size_factor)
+
+    def _agg_class(self, b: DBatch, key_spans=None):
+        """Output class of a sorted aggregate over `b`: (max_groups,
+        ladder id).  Eager, the live rows are counted on the host.
+        Traced, no count can be: where the host knows every key's range
+        (`key_spans`, _group_key_spans) their product bounds the groups
+        and no row can overflow the class (Q17: 200,000 part keys in
+        229,376 slots under 6,291,456 rows; no ladder id, nothing to
+        report).  Otherwise the class rides the ladder traced joins
+        ride: a quarter of the input's padded rows times the factor the
+        runner learned for this id, the groups found reported in
+        `join_required`, an overflowed call replayed one class up.
+        Groups never exceed rows, so the class is capped at the input's
+        and the ladder has three rungs (Q18's 1,500,000 orders fit the
+        first, 1,572,864, at SF1)."""
+        if not self._traced:
+            return next_pow2(max(b.count(), 1)), None
+        if key_spans is not None and None not in key_spans:
+            proven = size_class(math.prod(sp + 1 for sp in key_spans))
+            if proven < b.padded:
+                return proven, None
+        jid, factor = self._ladder_slot()
+        return min(b.padded, max(64, b.padded // 4) * factor), jid
+
     def _sorted_agg(self, keys, valid, inputs, max_groups, kinds,
-                    key_spans=None):
+                    key_spans=None, jid=None):
+        """grouped_agg_sort at the class `_agg_class` gave; `jid` is its
+        ladder id, None where the class cannot overflow.  The passes of
+        a DISTINCT aggregate share one class and find the same groups:
+        the first reports for all."""
         self.shape["sorted_aggs"] += 1
         self.shape["sorted_agg_lanes"] = max(
             self.shape["sorted_agg_lanes"], int(valid.shape[0]))
-        return K.grouped_agg_sort(keys, valid, inputs, max_groups, kinds,
-                                  key_spans=key_spans)
+        self.shape["sorted_agg_groups"] = max(
+            self.shape["sorted_agg_groups"], max_groups)
+        gkeys, outs, ng = K.grouped_agg_sort(
+            keys, valid, inputs, max_groups, kinds, key_spans=key_spans)
+        if jid is not None and all(j != jid for j, _r, _c
+                                   in self.join_required):
+            self.join_required.append(
+                (jid, ng.astype(jnp.int64), max_groups))
+        return gkeys, outs, ng
 
     def _assemble_agg_output(self, node: P.Agg, gkey_out, key_types,
                              key_dicts, outs, out_specs, out_valid,
@@ -1466,16 +1508,13 @@ class Executor:
                     gkey_out.insert(0, (rem % doms[i]).astype(jnp.int64))
                     rem = rem // doms[i]
             else:
-                # traced (fused) programs can't sync a group count to the
-                # host: use the worst case (every row its own group) —
-                # padding is masked out downstream either way
-                max_groups = b.padded if self._traced else \
-                    next_pow2(max(b.count(), 1))
                 key_spans = self._group_key_spans(node, b, key_dicts,
                                                   key_nulls)
+                max_groups, jid = self._agg_class(b, key_spans)
                 gkeys, outs, ng = self._sorted_agg(
                     self._grouping_arrays(key_arrs, key_nulls), b.valid,
-                    tuple(inputs), max_groups, tuple(kinds), key_spans)
+                    tuple(inputs), max_groups, tuple(kinds), key_spans,
+                    jid)
                 if not self._traced:
                     ng = int(ng)
                 padded_groups = max_groups
@@ -1517,11 +1556,10 @@ class Executor:
             out_valid = jnp.ones(1, dtype=bool)
             gkey_out = []
         else:
-            max_groups = b.padded if self._traced else \
-                next_pow2(max(b.count(), 1))
+            max_groups, jid = self._agg_class(b)
             gkeys, outs, ng = self._sorted_agg(
                 self._grouping_arrays(key_arrs, key_nulls), b.valid,
-                tuple(inputs), max_groups, tuple(kinds))
+                tuple(inputs), max_groups, tuple(kinds), jid=jid)
             if not self._traced:
                 ng = int(ng)
             out_valid = jnp.arange(max_groups) < ng
@@ -1545,8 +1583,11 @@ class Executor:
         columns with the same validity, so group ordering is identical
         and per-pass outputs align positionally."""
         gkeys_full = self._grouping_arrays(key_arrs, key_nulls)
-        max_g = b.padded if self._traced else \
-            next_pow2(max(b.count(), 1))
+        # every pass below finds the same groups in the same order: ONE
+        # output class under ONE ladder id (no GROUP BY: no span, one
+        # group, the smallest class)
+        max_g, g_jid = self._agg_class(b, self._group_key_spans(
+            node, b, key_dicts, key_nulls))
         n_gk = len(gkeys_full)
 
         out_cols: dict = {}
@@ -1566,7 +1607,7 @@ class Executor:
                                                         final=False)
             gkeys_p, outs, ng = self._sorted_agg(
                 gkeys_full or (jnp.zeros(b.padded, jnp.int64),),
-                b.valid, tuple(inputs), max_g, tuple(kinds))
+                b.valid, tuple(inputs), max_g, tuple(kinds), jid=g_jid)
             if not self._traced:
                 ng = int(ng)
             pb = self._assemble_agg_output(
@@ -1600,11 +1641,10 @@ class Executor:
             # KEEP their group alive so passes stay aligned
             enc = jnp.where(nn, 0, enc)
             keys1 = gkeys_full + (enc, nn.astype(jnp.int64))
-            g1_pad = b.padded if self._traced else \
-                next_pow2(max(b.count(), 1))
+            g1_pad, jid1 = self._agg_class(b)
             gkeys1, _, ng1 = self._sorted_agg(
                 keys1, b.valid, (b.valid.astype(jnp.int64),), g1_pad,
-                ("count",))
+                ("count",), jid=jid1)
             valid1 = jnp.arange(g1_pad) < ng1
             dval = gkeys1[n_gk]
             dnull = gkeys1[n_gk + 1].astype(bool)
@@ -1640,7 +1680,7 @@ class Executor:
             gkeys2, outs2, ng2 = self._sorted_agg(
                 tuple(gkeys1[:n_gk]) if n_gk else
                 (jnp.zeros(g1_pad, jnp.int64),),
-                valid1, ins2, max_g, kinds2)
+                valid1, ins2, max_g, kinds2, jid=g_jid)
             if not self._traced:
                 ng2 = int(ng2)
             if base is None:

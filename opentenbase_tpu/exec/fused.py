@@ -23,7 +23,10 @@ per-join required totals, and the host retraces one step up on
 overflow — the learned factors persist in _JOIN_LADDER keyed by the
 literal-masked fragment shape, so steady state is one program call with
 ZERO per-join device→host syncs (the eager path pays one `int(total)`
-sync per join per query).
+sync per join per query).  A sorted aggregate whose keys' ranges do not
+bound its groups rides the same ladder under an id of the same sequence
+(executor._agg_class): a quarter of its input's rows, its groups
+reported beside the joins' totals.
 
 Compiled programs live in the shared program cache (exec/plancache.py
 FUSED tier) under a CANONICAL FRAGMENT SIGNATURE: numeric/date literals
@@ -74,10 +77,11 @@ _STATE_LOCK = locks.Lock("exec.fused._STATE_LOCK")
 _MASK_REFUSED: dict = {}    # guarded_by: _STATE_LOCK
 _MASK_REFUSED_MAX = 512
 
-# learned join-size ladder: literal-masked fragment shape -> {join id:
-# factor} — the single-device twin of MeshRunner._ladder, so a join
-# fragment's second statement (any literal binding) starts at the
-# right output class instead of replaying the overflow walk
+# learned size-class ladder: literal-masked fragment shape -> {id of a
+# traced join or laddered sorted aggregate: factor} — the single-device
+# twin of MeshRunner._ladder, so a fragment's second statement (any
+# literal binding) starts at the right output class instead of
+# replaying the overflow walk
 _JOIN_LADDER: dict = {}     # guarded_by: _STATE_LOCK
 _JOIN_LADDER_MAX = 512
 
@@ -402,7 +406,7 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
         return None
 
     lkey = struct_key(base_key)
-    factors: dict = dict(_JOIN_LADDER.get(lkey, {})) if has_join else {}
+    factors: dict = dict(_JOIN_LADDER.get(lkey, {}))
 
     pvals = tuple(
         [jnp.asarray(ctx.params[k][0]) for k in traced_names]
@@ -453,10 +457,11 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
                 raise
             plancache.FUSED.record_call(fn, t0)
 
-            # join-size ladder: the program reports each traced join's
-            # required output rows; overflow grows exactly that join's
-            # factor and retraces (one host sync per program call —
-            # never per join).  Learned factors persist per shape.
+            # size-class ladder: the program reports each traced join's
+            # required output rows (and each laddered sorted aggregate's
+            # groups, executor._agg_class); overflow grows exactly that
+            # operator's factor and retraces (one host sync per program
+            # call — never per join).  Learned factors persist per shape.
             caps = meta.get("join_caps") or ()
             if caps:
                 req = np.asarray(jax.device_get(join_req))
@@ -484,7 +489,7 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
                     obs_trace.event("retrace", tier="fused",
                                     factors=dict(factors))
                     continue
-            if has_join:
+            if caps:
                 _ladder_remember(lkey, factors)
             # what the program holds, fixed when it was traced
             sp.set(**meta.get("shape", {}))
@@ -590,7 +595,6 @@ class FragSig:
     stores: dict           # table name -> TableStore
     cache: object          # DeviceTableCache handle for staging
     need_by_table: dict    # table name -> needed column set
-    has_join: bool
     plan_key: tuple        # _key_of(masked plan)
     lit_types: tuple
 
@@ -636,8 +640,8 @@ def batch_signature(ctx, node) -> Optional[FragSig]:
     if refused:
         return None  # masked trace host-synced before: literals bake
 
-    has_join = _plan_has_join(masked)
-    if has_join and sum(st.row_count() for st in stores.values()) \
+    if _plan_has_join(masked) \
+            and sum(st.row_count() for st in stores.values()) \
             < _fuse_join_min_rows():
         return None
 
@@ -647,7 +651,7 @@ def batch_signature(ctx, node) -> Optional[FragSig]:
             _needed_columns(node, scan.alias))
     return FragSig(sig=sig, plan=masked, lits=lits,
                    stores=stores, cache=ctx.cache,
-                   need_by_table=need_by_table, has_join=has_join,
+                   need_by_table=need_by_table,
                    plan_key=plan_key, lit_types=lit_types)
 
 
@@ -716,10 +720,8 @@ class FragmentProgram:
         self.baked = baked
         self.base_key = base_key
         self.lkey = struct_key(base_key)
-        self.has_join = _plan_has_join(exec_plan)
         with _STATE_LOCK:
-            self.factors = dict(_JOIN_LADDER.get(self.lkey, {})) \
-                if self.has_join else {}
+            self.factors = dict(_JOIN_LADDER.get(self.lkey, {}))
         return True
 
     def ok(self) -> bool:
@@ -792,7 +794,7 @@ class FragmentProgram:
                     obs_trace.event("retrace", tier="morsel",
                                     factors=dict(self.factors))
                     continue  # SAME chunk, one factor class up
-            if self.has_join:
+            if caps:
                 _ladder_remember(self.lkey, self.factors)
             return DBatch(dict(cols), valid, dict(meta["types"]),
                           dict(meta["dicts"]), dict(nulls))
@@ -885,8 +887,7 @@ def stage_fused_batch(info: FragSig, queries: list) \
     sb.staged_ns = staged_ns
 
     with _STATE_LOCK:
-        sb.factors = dict(_JOIN_LADDER.get(sb.lkey, {})) \
-            if info.has_join else {}
+        sb.factors = dict(_JOIN_LADDER.get(sb.lkey, {}))
     sb.bctx = ExecContext(info.stores, 0, 0, info.cache)
     return sb
 
@@ -984,7 +985,7 @@ def finish_fused_batch(flight: FusedFlight) -> Optional[list]:  # otblint: sync-
                 if flight is None:
                     return None
                 continue
-        if sb.info.has_join:
+        if caps:
             _ladder_remember(sb.lkey, sb.factors)
 
         # demux: per-query device views into the stacked output (the

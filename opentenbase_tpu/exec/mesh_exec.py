@@ -757,10 +757,11 @@ class MeshRunner:
             mults = {ex.index: 1 for ex in dp.exchanges
                      if ex.kind == "redistribute"}
             # per-gather output size classes: traced fragment outputs
-            # are worst-case padded (a partial aggregate's buffer is its
-            # input size), but the rows that actually cross to the CN
-            # are usually few — start small, compact in-program, grow on
-            # overflow (the same ladder joins and redistributes ride)
+            # are padded to static classes (a join's or a laddered
+            # aggregate's buffer is a quarter of its input), but the rows
+            # that actually cross to the CN are usually few — start
+            # small, compact in-program, grow on overflow (the same
+            # ladder joins, aggregates and redistributes ride)
             gathers = {ex.index: min(base_pad, 1 << 16)
                        for ex in dp.exchanges
                        if ex.kind in ("gather", "gather_one")}
@@ -1083,8 +1084,10 @@ class MeshRunner:
                 b = exe.exec_node(plan)
                 join_reqs.extend(exe.join_required)
                 for k, v in exe.shape.items():
+                    # the largest aggregate's lanes and class; counts add
                     shape[k] = max(shape.get(k, 0), v) \
-                        if k == "sorted_agg_lanes" else shape.get(k, 0) + v
+                        if k in ("sorted_agg_lanes", "sorted_agg_groups") \
+                        else shape.get(k, 0) + v
                 for ex in dp.exchanges:
                     if ex.source_fragment != frag.index:
                         continue

@@ -394,7 +394,8 @@ def _trace_explain_lines() -> str:
     summary = qt.summary()
     lines.append("Shape: " + " ".join(
         f"{k}={summary[k]}" for k in ("semi_joins", "sorted_aggs",
-                                      "sorted_agg_lanes", "initplans")))
+                                      "sorted_agg_lanes",
+                                      "sorted_agg_groups", "initplans")))
     rounds = int(qt.sum_attr("exchange", "rounds"))
     if rounds:
         lines.append(

@@ -361,6 +361,7 @@ class QueryTrace:
         exchanges = exchange_bytes = 0
         traced = baked = retraces = 0
         semi_joins = sorted_aggs = sorted_agg_lanes = initplans = 0
+        sorted_agg_groups = 0
         hits = misses = 0
         work = [(self.root, ())]
         while work:
@@ -393,6 +394,9 @@ class QueryTrace:
                         sorted_agg_lanes = max(
                             sorted_agg_lanes,
                             a.get("sorted_agg_lanes", 0) or 0)
+                        sorted_agg_groups = max(
+                            sorted_agg_groups,
+                            a.get("sorted_agg_groups", 0) or 0)
                 elif name == "bind":
                     traced += a.get("traced", 0) or 0
                     baked += a.get("baked", 0) or 0
@@ -447,11 +451,13 @@ class QueryTrace:
         d["exchange_bytes"] = int(exchange_bytes)
         # the shape of the compiled programs that answered, fixed when
         # they were traced: joins answered by a mask (semi, anti: no
-        # expansion), sorted aggregates and the padded rows of the
-        # largest; and the scalar subqueries run before the statement
+        # expansion), sorted aggregates, the padded rows of the largest
+        # and the largest output class (group slots); and the scalar
+        # subqueries run before the statement
         d["semi_joins"] = int(semi_joins)
         d["sorted_aggs"] = int(sorted_aggs)
         d["sorted_agg_lanes"] = int(sorted_agg_lanes)
+        d["sorted_agg_groups"] = int(sorted_agg_groups)
         d["initplans"] = int(initplans)
         d["unattributed_ms"] = self.root.self_ms()
         return d

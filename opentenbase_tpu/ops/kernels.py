@@ -297,9 +297,16 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
     `lax.cond` compiles both (each sort costs the chip's compiler most
     of a minute, CHANGES.md PR 34).
 
-    Returns (group_key_cols, agg_outputs, n_groups).  Caller guarantees
-    distinct-group count <= max_groups (host retries at the next size
-    class otherwise — count returned lets it check).
+    Returns (group_key_cols, agg_outputs, n_groups).  Everything per
+    group SLOT (the search for first rows, one gather a SUM and a key)
+    runs at `max_groups` lanes, whatever the rows.  The caller picks it
+    (executor._agg_class): the eager tier from the live rows, a traced
+    program from the keys' host-known spans where they bound the groups
+    below the rows, else a quarter of the rows on the joins' size-class
+    ladder.  A call whose n_groups passes max_groups answers for the
+    first max_groups groups only: the traced caller reports n_groups
+    beside its joins' totals and the runner (fused._try_fused,
+    MeshRunner.run) discards the reply and replays one class up.
     """
     n = valid.shape[0]
     invalid = ~valid

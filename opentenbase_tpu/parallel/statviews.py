@@ -204,7 +204,8 @@ STAT_TABLES = {
         # the shape of the compiled programs that answered (summary())
         ColumnDef("semi_joins", T.INT64), ColumnDef("sorted_aggs", T.INT64),
         ColumnDef("sorted_agg_lanes", T.INT64),
-        ColumnDef("initplans", T.INT64)],
+        ColumnDef("initplans", T.INT64),
+        ColumnDef("sorted_agg_groups", T.INT64)],
     # per-node guard health (net/guard.py): breaker state + failure
     # accounting for every RPC peer this coordinator talks to
     # (reference: pgxc_node health columns fed by clustermon pings;
@@ -343,7 +344,8 @@ def refresh(cluster, names: list[str]):
                     s["rows"], s["bytes_staged"],
                     s["bytes_materialized"], s["pool_hits"],
                     s["pool_misses"], s["semi_joins"], s["sorted_aggs"],
-                    s["sorted_agg_lanes"], s["initplans"]))
+                    s["sorted_agg_lanes"], s["initplans"],
+                    s["sorted_agg_groups"]))
         elif name == "otb_node_health":
             from ..net.guard import health_rows
             rows = list(health_rows())

@@ -87,6 +87,34 @@ class TestTracecheckCensus:
         assert check_census({"entries": ents2}) == []
 
 
+    def test_an_aggregates_factor_is_a_ladder_shaped_key_component(
+            self, monkeypatch):
+        """A traced sorted aggregate whose keys' ranges bound nothing rides
+        the joins' size-class ladder (executor._agg_class): the factor it
+        learns is a `factor:` class of the program key like a join's, a
+        power of two under the cap, one compile a class."""
+        import numpy as np
+        monkeypatch.setenv("OTB_TRACECHECK", "1")
+        plancache.reset_census()
+        node = LocalNode()
+        s = Session(node)
+        s.execute("create table ct (k bigint, v bigint)")
+        n = 1500
+        s._insert_rows(node.catalog.table("ct"), node.stores["ct"],
+                       {"k": np.arange(n), "v": np.arange(n) % 7}, n)
+        # 1,500 groups, a first rung of a quarter of the padded rows
+        rows = s.query("select k + 0 as kk, sum(v) as sv from ct "
+                       "group by k + 0 order by kk limit 3")
+        assert rows == [(0, 0), (1, 1), (2, 2)]
+        ents = plancache.census()
+        factors = sorted(v for e in ents for dim, v in e["classes"]
+                         if dim.startswith("factor:('__fused', 0)"))
+        assert factors == [4], ents
+        assert len({e["frag"] for e in ents}) == 1 and len(ents) == 2
+        assert check_census({"entries": ents}) == []
+        plancache.reset_census()
+
+
 class TestCommittedCensus:
     def test_repo_census_is_clean(self):
         path = os.path.join(_REPO, "opentenbase_tpu", "analysis",

@@ -26,7 +26,8 @@ from opentenbase_tpu.tpch.queries import Q
 
 SF = 0.01
 LIMITS = files.load_json("lib", "limits.json")
-SHAPE_KEYS = ("semi_joins", "sorted_aggs", "sorted_agg_lanes", "initplans")
+SHAPE_KEYS = ("semi_joins", "sorted_aggs", "sorted_agg_lanes",
+              "sorted_agg_groups", "initplans")
 # the spec's validation values (tpch/queries.py holds them as literals)
 VALIDATION = {"q4": {"date": "1993-07-01"},
               "q17": {"brand": "Brand#23", "container": "MED BOX"},
@@ -100,8 +101,21 @@ def test_subquery_statement_answers_as_the_reference(served, qname,
         if qname != "q4":
             # the largest sorted aggregate runs over lineitem's padded rows
             assert stats["sorted_agg_lanes"] >= lanes // ndn
+            # into a class of its own (ISSUE 35): Q17's proven from its
+            # key's span (the part keys' codec class), Q18's the first
+            # rung of the ladder (its order keys' span bounds nothing
+            # below the rows; every order is a group and fits, as at SF1)
+            groups = stats["sorted_agg_groups"]
+            assert 0 < groups <= stats["sorted_agg_lanes"]
+            if ndn == 1 and qname == "q17":
+                assert len(data["part"]["p_partkey"]) <= groups \
+                    < stats["sorted_agg_lanes"] // 2
+            elif ndn == 1:
+                assert len(data["orders"]["o_orderkey"]) <= groups \
+                    == stats["sorted_agg_lanes"] // 4
         else:
             assert (stats["sorted_agg_lanes"] > 0) == (sorted_aggs > 0)
+            assert (stats["sorted_agg_groups"] > 0) == (sorted_aggs > 0)
         assert stats["retraces"] == 0 or n == 0
     assert session.fallbacks == []
     if quantity != 300:
@@ -130,6 +144,37 @@ def test_explain_analyze_shows_the_shape(served):
     assert line.split()[1:3] == [
         "semi_joins=1", f"sorted_aggs={SHAPES['q18', ndn][1]}"], text
     assert "initplans=0" in line
+    shape = dict(f.split("=") for f in line.split()[1:])
+    assert set(SHAPE_KEYS) == set(shape)
+    # an instrumented run is eager: its classes follow the counted rows
+    assert 0 < int(shape["sorted_agg_groups"])
+
+
+COMPILED_AND_EAGER = {
+    "q3": Q[3], "q17": Q[17], "q18": Q[18].replace("> 300", "> 250"),
+    "distinct": "select l_returnflag, count(distinct l_suppkey) as "
+                "suppliers, sum(l_quantity) as qty from lineitem "
+                "group by l_returnflag order by l_returnflag"}
+
+
+@pytest.mark.parametrize("name", list(COMPILED_AND_EAGER))
+def test_the_compiled_answer_is_the_eager_tiers(served, name):
+    """A class is a buffer size: the rows of the mesh program (sorted
+    aggregates at a class of their own, an overflowed call replayed) are
+    those of the host tier, where every operator counts its rows."""
+    _ndn, _seed, _data, client, session, _shared = served
+    sql = COMPILED_AND_EAGER[name]
+    got = client.query(sql)
+    stats = session.last_query_stats()
+    assert stats["tier"] == "mesh" and stats["fallback"] == ""
+    assert 0 < stats["sorted_agg_groups"] <= stats["sorted_agg_lanes"]
+    session.execute("set enable_mesh_exchange = off")
+    try:
+        want = client.query(sql)
+        assert session.last_query_stats()["tier"] == "host"
+    finally:
+        session.execute("set enable_mesh_exchange = on")
+    assert want and agrees(got, want, ())
 
 
 # ---------------------------------------------------------------------------
@@ -294,7 +339,8 @@ REHEARSALS = [
     (SUBQ, 1, {"compiles_in_window": 0, "programs_built.fresh": 0,
                "params_baked.fresh": 1, "retraces.fresh": 0,
                "semi_joins.subq": 1, "initplans.subq": 0,
-               "sorted_agg_lanes.subq": None, "execute_ms.analytic": None}),
+               "sorted_agg_lanes.subq": None, "sorted_agg_groups.subq": None,
+               "execute_ms.analytic": None}),
     (THROUGHPUT, 0, {"stmt_rate": None, "setup_s": None}),
     (THROUGHPUT, 1, {"compiles_in_window.throughput": 0,
                      "programs_built.throughput": 0,

@@ -192,6 +192,20 @@ def test_grouped_agg_sort(one_chip, key_spans, sorts, chosen_by_data):
     assert (" conditional(" in text) is chosen_by_data
 
 
+@pytest.mark.parametrize("slots", [SMALL // 4, 96])
+def test_grouped_agg_sort_with_fewer_slots_than_rows(one_chip, slots):
+    """A traced aggregate's output class is its own (executor._agg_class:
+    Q17's 229,376 slots under 6,291,456 rows): the chip's compiler takes
+    a class below the rows, one that is no power of two among them, and
+    the slot search runs in one pass (no `while`)."""
+    s = one_chip
+    text = _compile(jax.jit(lambda k, v, a: K.grouped_agg_sort(
+        (k,), v, (a, a), max_groups=slots, agg_kinds=("sum", "count"),
+        key_spans=(SMALL - 1,))),
+        s(SMALL, I64), s(SMALL, BOOL), s(SMALL, I64))
+    assert text.count(" sort(") == 1 and " while(" not in text
+
+
 def test_sort_rows_top10(one_chip):
     """Payloads stay out of the variadic sort (flag + 2 keys + row index
     = 4 operands): the chip's compile time grows with every operand."""
