@@ -1101,6 +1101,9 @@ class ClusterSession:
             if queue is not None:
                 queue.release()
         names, rows = materialize(batch, dp.output_names)
+        # the result batch's device buffers go here (see fused.py)
+        with obs_trace.span("release"):
+            del batch
         self.tier_counts[ex.tier] = self.tier_counts.get(ex.tier, 0) + 1
         if ex.tier == "host" and ex.fallback_reason:
             self.fallbacks.append(ex.fallback_reason)

@@ -153,6 +153,11 @@ class LazyCol:
             m = self.null_out if m is None else (m | self.null_out)
         return m
 
+    def dispatches(self) -> int:
+        """The device ops `value()` and `null()` launch, run eagerly."""
+        return 1 + (self.null_src is not None) * (
+            1 + (self.null_out is not None))
+
 
 # a pytree, so a whole program can take one (executor._gather_live)
 jax.tree_util.register_dataclass(
@@ -2200,8 +2205,9 @@ def _fetch_live(b: DBatch, names: list[str], srcs: list, nullable: list):
              for d in (b.cols, b.nulls, b.lazy)]
     out_size = _LIVE_FLOOR
     while True:
-        with obs_trace.span("finalize.gather"):
+        with obs_trace.span("finalize.gather") as sp:
             dev = _gather_live(b.valid, *parts, out_size=out_size)
+            sp.set(calls=1)
         with obs_trace.span("finalize.fetch") as sp:
             buf = np.asarray(dev)
             sp.set(fetches=1, bytes=buf.nbytes, compacted=out_size)
@@ -2239,7 +2245,10 @@ def _materialize(b: DBatch, names: Optional[list[str]] = None):
     if b.padded * row_bytes >= _COMPACT_MIN_BYTES:
         live = _fetch_live(b, names, srcs, nullable)
     if live is None:
-        with obs_trace.span("finalize.gather"):
+        with obs_trace.span("finalize.gather") as sp:
+            if b.lazy:
+                sp.set(calls=sum(b.lazy[n].dispatches()
+                                 for n in names if n in b.lazy))
             b.ensure(names)
         with obs_trace.span("finalize.fetch") as sp:
             valid = np.asarray(b.valid)

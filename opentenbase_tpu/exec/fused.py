@@ -369,10 +369,17 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
             _needed_columns(node, scan.alias))
     staged_arrs: dict = {}
     staged_ns: dict = {}
-    for t, need in sorted(need_by_table.items()):
-        arrs, n = ctx.cache.get(stores[t], sorted(need))
-        staged_arrs[t] = arrs
-        staged_ns[t] = jnp.int64(n)
+    # `inputs`: what the program is called with, made ready on the
+    # device — here the staged arrays (a pool lookup; a miss stages
+    # under it) and each table's row count, below the parameters; every
+    # scalar is a put and an eager convert of its own (`h2d` counts
+    # them; `h2d_bytes` is for arrays: a scalar's 8 bytes are not added)
+    with obs_trace.span("inputs") as isp:
+        for t, need in sorted(need_by_table.items()):
+            arrs, n = ctx.cache.get(stores[t], sorted(need))
+            staged_arrs[t] = arrs
+            staged_ns[t] = jnp.int64(n)
+        isp.set(h2d=len(staged_ns))
 
     table_sig = _table_sig(stores)
     ctx = _bound_ctx(ctx, exec_node_plan)
@@ -408,9 +415,11 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
     lkey = struct_key(base_key)
     factors: dict = dict(_JOIN_LADDER.get(lkey, {}))
 
-    pvals = tuple(
-        [jnp.asarray(ctx.params[k][0]) for k in traced_names]
-        + [jnp.asarray(v) for _n, v, _t in lits])
+    with obs_trace.span("inputs") as isp:
+        pvals = tuple(
+            [jnp.asarray(ctx.params[k][0]) for k in traced_names]
+            + [jnp.asarray(v) for _n, v, _t in lits])
+        isp.set(h2d=len(pvals))
     from .executor import bump_stat, stats_tier
 
     for _attempt in range(24):
@@ -438,6 +447,7 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
                     cols, valid, nulls, join_req = fn(
                         staged_arrs, jnp.int64(ctx.snapshot_ts),
                         jnp.int64(ctx.txid), pvals, staged_ns)
+                sp.set(h2d=2)                   # the snapshot, the txid
             except (jax.errors.TracerBoolConversionError,
                     jax.errors.ConcretizationTypeError,
                     jax.errors.TracerArrayConversionError):
@@ -465,6 +475,7 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
             caps = meta.get("join_caps") or ()
             if caps:
                 req = np.asarray(jax.device_get(join_req))
+                sp.set(d2h=1, d2h_bytes=req.nbytes)
                 grew = False
                 for (jid, cap), r in zip(caps, req):
                     if r <= cap:
@@ -486,8 +497,6 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
                     # statement replays one class up (`retraces` of
                     # summary() sums these)
                     sp.set(retraces=1)
-                    obs_trace.event("retrace", tier="fused",
-                                    factors=dict(factors))
                     continue
             if caps:
                 _ladder_remember(lkey, factors)
@@ -498,8 +507,14 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
                             (staged_arrs, jnp.int64(ctx.snapshot_ts),
                              jnp.int64(ctx.txid), pvals, staged_ns))
             from .executor import DBatch
-            return DBatch(dict(cols), valid, dict(meta["types"]),
-                          dict(meta["dicts"]), dict(nulls))
+            out = DBatch(dict(cols), valid, dict(meta["types"]),
+                         dict(meta["dicts"]), dict(nulls))
+        # `release`: the call's own device scalars (row counts,
+        # parameters, the overflow vector) are dropped here, not on the
+        # way out, so that what freeing them costs has a name
+        with obs_trace.span("release"):
+            del staged_ns, pvals, join_req
+        return out
     return None  # overflow never converged: eager fallback
 
 
@@ -778,6 +793,7 @@ class FragmentProgram:
             caps = meta.get("join_caps") or ()
             if caps:
                 req = np.asarray(jax.device_get(join_req))
+                obs_trace.count(d2h=1, d2h_bytes=req.nbytes)
                 grew = False
                 for (jid, cap), r in zip(caps, req):
                     if r <= cap:
@@ -791,8 +807,6 @@ class FragmentProgram:
                     grew = True
                 if grew:
                     _ladder_remember(self.lkey, self.factors)
-                    obs_trace.event("retrace", tier="morsel",
-                                    factors=dict(self.factors))
                     continue  # SAME chunk, one factor class up
             if caps:
                 _ladder_remember(self.lkey, self.factors)
@@ -965,7 +979,9 @@ def finish_fused_batch(flight: FusedFlight) -> Optional[list]:  # otblint: sync-
         if caps:
             # per-join required totals arrive stacked (K, njoins):
             # grow to the max any batch element needs
-            req = np.asarray(jax.device_get(flight.join_req)).max(axis=0)
+            req = np.asarray(jax.device_get(flight.join_req))
+            obs_trace.count(d2h=1, d2h_bytes=req.nbytes)
+            req = req.max(axis=0)
             grew = False
             for (jid, cap), r in zip(caps, req):
                 if r <= cap:

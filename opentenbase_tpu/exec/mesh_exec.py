@@ -419,7 +419,7 @@ class MeshRunner:
         view = _MeshStoreView(td, union_dicts, dict_state, null_columns)
         codec.note_staged(view, encs)
         staged = _StagedTable(arrs, nrows, padded, view, vkey)
-        POOL.note_upload(nbytes)
+        POOL.note_upload(nbytes, puts=len(arrs) + 1)
         POOL.mesh_put(self, name, MeshEntry(
             name, vkey, staged, list(counts), dict_state,
             set(null_columns), nbytes, encs=encs,
@@ -560,7 +560,9 @@ class MeshRunner:
         nrows = jax.device_put(np.asarray(new_counts, np.int64), sh)
         staged = _StagedTable(arrs, nrows, P, view, vkey)
         nbytes = sum(int(a.nbytes) for a in arrs.values())
-        POOL.note_upload(up, tail_rows=tail_total)
+        POOL.note_upload(up, tail_rows=tail_total,
+                         puts=1 + sum(1 for k, a in arrs.items()
+                                      if ent.staged.arrs.get(k) is not a))
         return MeshEntry(name, vkey, staged, list(new_counts),
                          ent.dict_state, new_null, nbytes,
                          encs=ent.encs,
@@ -797,7 +799,10 @@ class MeshRunner:
                 # the gather span times the device→host pull of every
                 # CN-bound exchange output — the mesh tier's terminal
                 # materialization boundary
-                with obs_trace.span("gather", tier="mesh"):
+                with obs_trace.span("gather", tier="mesh") as gsp:
+                    # every array comes down by a blocking copy of its
+                    # own and goes back by a put of its own: counted
+                    d2h = d2h_bytes = h2d = h2d_bytes = 0
                     for gi, (cols, valid, nulls) in out.items():
                         gmeta = meta[gi]
                         # only the live rows go on to the CN fragment,
@@ -807,18 +812,26 @@ class MeshRunner:
                         # v5e the 12-operand final sort of Q1's 4 groups
                         # in a 65536-row buffer compiled in 443 s
                         valid = np.asarray(valid)
+                        d2h += 1
+                        d2h_bytes += valid.nbytes
                         live = np.flatnonzero(valid)
                         rows = next_pow2(len(live))
                         if rows >= len(valid):
                             live, rows = None, len(valid)
 
                         def to_cn(a):
-                            a = np.asarray(a)
+                            nonlocal d2h, d2h_bytes, h2d, h2d_bytes
+                            if a is not valid:  # that one is down already
+                                a = np.asarray(a)
+                                d2h += 1
+                                d2h_bytes += a.nbytes
                             if live is not None:
                                 t = np.zeros((rows,) + a.shape[1:],
                                              a.dtype)
                                 t[:len(live)] = a[live]
                                 a = t
+                            h2d += 1
+                            h2d_bytes += a.nbytes
                             return jnp.asarray(a)
 
                         result[gi] = DBatch(
@@ -826,11 +839,9 @@ class MeshRunner:
                             to_cn(valid),
                             dict(gmeta["types"]), dict(gmeta["dicts"]),
                             {n: to_cn(a) for n, a in nulls.items()})
+                    gsp.set(d2h=d2h, d2h_bytes=d2h_bytes, h2d=h2d,
+                            h2d_bytes=h2d_bytes)
                 return result, included
-            obs_trace.event("retrace", tier="mesh",
-                            joins=len(over_jids),
-                            exchanges=len(a2a_over),
-                            gathers=len(g_over))
         raise MeshUnsupported("size-class ladder exhausted")
 
     def warm(self, dp: DistPlan, snapshot_ts: int, params: dict) -> bool:
@@ -1166,10 +1177,15 @@ class MeshRunner:
     def _call_program(self, fn, meta, gather_idx, staged, table_names,
                       snapshot_ts, txid, params):  # otblint: sync-boundary
         from .executor import stats_tier
-        flat_args = [jnp.int64(snapshot_ts), jnp.int64(txid)]
-        for k in meta.get("traced", ()):
-            v, t = params[k]
-            flat_args.append(jnp.asarray(v, dtype=dev_dtype(t)))
+        # `inputs`: the snapshot, the txid and every traced parameter
+        # put on the device, a transfer and an eager convert each; the
+        # staged arrays are resident
+        with obs_trace.span("inputs") as isp:
+            flat_args = [jnp.int64(snapshot_ts), jnp.int64(txid)]
+            for k in meta.get("traced", ()):
+                v, t = params[k]
+                flat_args.append(jnp.asarray(v, dtype=dev_dtype(t)))
+            isp.set(h2d=len(flat_args))
         for t in table_names:
             for n in sorted(staged[t].arrs):
                 flat_args.append(staged[t].arrs[n])
@@ -1201,6 +1217,8 @@ class MeshRunner:
                                zip(meta.get("ex_order", ()), av)
                                if ov > 0})
             gv = np.asarray(jax.device_get(g_over_vec))
+            sp.set(d2h=3,
+                   d2h_bytes=over_vec.nbytes + av.nbytes + gv.nbytes)
             g_over = sorted({gi for gi, ov in
                              zip(meta.get("gi_order", ()), gv) if ov > 0})
             # a class overflowed: run() replays the statement one class

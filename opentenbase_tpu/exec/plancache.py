@@ -194,7 +194,10 @@ class ProgramCache:
     def record_call(self, fn, t0: float):
         """Post-execution compile detection: a grown per-fn cache means
         this call traced+compiled (a new shape/dtype bucket); attribute
-        the call's wall time to compile_ms and re-check the budget."""
+        the call's wall time to compile_ms and re-check the budget.
+        Every call of a compiled program passes here once: it counts as
+        one of the open span's `calls`."""
+        obs_trace.count(calls=1)
         after = _fn_live(fn)
         before = getattr(fn, "_otb_seen", 0)
         if after > before:

@@ -392,6 +392,11 @@ def _trace_explain_lines() -> str:
             f"dict_miss={int(qt.sum_attr('bind', 'dict_miss'))} "
             f"retraces={int(qt.sum_attr('execute', 'retraces'))}")
     summary = qt.summary()
+    # host<->device round trips of the inner run, counted where made
+    lines.append("Transfers: " + " ".join(
+        f"{k}={summary[k]}" for k in ("host_syncs", "d2h_bytes",
+                                      "h2d_puts", "h2d_bytes",
+                                      "program_calls")))
     lines.append("Shape: " + " ".join(
         f"{k}={summary[k]}" for k in ("semi_joins", "sorted_aggs",
                                       "sorted_agg_lanes",

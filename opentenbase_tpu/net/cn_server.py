@@ -207,13 +207,14 @@ class CnServer:
         inside it; with a scheduler the dispatcher thread adopts it
         (one trace a statement, whichever thread runs it)."""
         t0 = time.perf_counter()
+        c0 = obs_trace.thread_cpu() if obs_trace.ENABLED else None
         msg = decode_msg(blob)
         recv_ms = (time.perf_counter() - t0) * 1e3
         op = msg.get("op")
         if op != "query":
             return self._serve_op(sess, sock, op)
         sig = str(msg.get("sql", "")).strip()[:200]
-        with obs_trace.trace_query(sig, since=t0) as qt:
+        with obs_trace.trace_query(sig, since=t0, cpu_since=c0) as qt:
             obs_trace.record("wire.recv", recv_ms, bytes=len(blob))
             try:
                 if self.scheduler is not None:

@@ -25,6 +25,7 @@ already read under their subsystem's lock).
 
 from __future__ import annotations
 
+import functools
 import math
 import threading
 from typing import Callable, Iterable, Optional
@@ -266,13 +267,30 @@ def _sample_line(name: str, labels: tuple, value) -> str:
 REGISTRY = Registry()
 
 
+_OBSERVED_PHASES = ("plan", "stage", "execute", "finalize")
+
+
+# `observe_query`'s own metrics, looked up in the registry once a tier
+# and once a phase (a metric is never unregistered), not every statement
+@functools.lru_cache(maxsize=32)
+def _query_metrics(tier: str) -> tuple:
+    return (REGISTRY.counter("otb_queries_total", tier=tier),
+            REGISTRY.histogram("otb_query_ms", tier=tier))
+
+
+@functools.lru_cache(maxsize=len(_OBSERVED_PHASES))
+def _phase_histogram(phase: str) -> Histogram:
+    return REGISTRY.histogram("otb_phase_ms", phase=phase)
+
+
 def observe_query(qt) -> None:
-    """Trace-finish hook: fold one QueryTrace into the registry."""
-    tier = qt.tier or "single"
-    REGISTRY.counter("otb_queries_total", tier=tier).inc()
-    REGISTRY.histogram("otb_query_ms", tier=tier).observe(
-        max(qt.total_ms, 0.0))
-    for ph in ("plan", "stage", "execute", "finalize"):
-        ms = qt.phase_ms(ph)
+    """Trace-finish hook: fold one QueryTrace into the registry.  This
+    runs on the connection's own thread, and a closed-loop client's
+    next statement waits for it: one walk of the tree for the four
+    phases, no registry lookup."""
+    total, ms_total = _query_metrics(qt.tier or "single")
+    total.inc()
+    ms_total.observe(max(qt.total_ms, 0.0))
+    for ph, ms in qt.phases_ms(_OBSERVED_PHASES).items():
         if ms > 0:
-            REGISTRY.histogram("otb_phase_ms", phase=ph).observe(ms)
+            _phase_histogram(ph).observe(ms)

@@ -22,6 +22,19 @@ Design constraints (TPU-first):
   started by anyone records the span on the host plane of the same
   ``.xplane.pb`` as the device's "XLA Ops".  Outside a session the
   annotation is a no-op in the profiler; there is no flag.
+- Thread CPU beside wall time, on the statement's ROOT only: the
+  serving thread's CPU clock is read where the trace opens and where it
+  closes (``cpu_ms``).  Wall less CPU is the time that thread was NOT
+  running: blocked on the device, a socket or a lock, or runnable and
+  waiting for the interpreter lock or a core.  Not on every span: on the
+  chip tool's host a read of that clock is a 6 us system call that held
+  up four serving threads for ~60 us, two a span cost a point read 15 %
+  (PERF.md section 6, PR 36); and it ticks in 10 ms there, so only a
+  MEAN over many statements says anything.
+- Host↔device traffic is COUNTED where it happens, as span attributes
+  (``d2h``/``d2h_bytes``: blocking device→host copies; ``h2d``/
+  ``h2d_bytes``: puts; ``calls``: compiled programs launched): only at
+  the sync points above, by the code that makes the copy or the call.
 
 Env vars: ``OTB_TRACE`` (default on), ``OTB_SLOW_MS`` (slow-query log
 threshold, 0 = off), ``OTB_TRACE_RING`` (recent-trace ring size).
@@ -64,7 +77,7 @@ PHASES = ("plan", "stage", "execute", "exchange", "finalize")
 # every span name `summary()` sums into a `*_ms` key
 _SUMMED = PHASES + ("wire.recv", "wire.send", "parse", "autoprep", "bind",
                     "wait", "finalize.gather", "finalize.fetch",
-                    "finalize.decode")
+                    "finalize.decode", "gather", "inputs", "release")
 _BY_START = operator.attrgetter("t0_ms")
 
 
@@ -179,6 +192,18 @@ def _stack() -> Optional[list]:
     return getattr(_TLS, "stack", None)
 
 
+def _thread_clock() -> int:
+    """The calling thread's CPU-time clock: `time.thread_time()`'s, by
+    an id another thread can read too."""
+    return time.pthread_getcpuclockid(threading.get_ident())
+
+
+def thread_cpu() -> float:
+    """The calling thread's CPU time, in seconds, on the clock a
+    trace reads: what `trace_query(cpu_since=...)` takes."""
+    return time.clock_gettime(_thread_clock())
+
+
 def _now_ms(st: list) -> float:
     """Now, on the timeline of the statement whose root is st[0]."""
     return (time.perf_counter() - st[0]._t0) * 1e3
@@ -224,6 +249,16 @@ def annotate(**kw) -> None:
     st = getattr(_TLS, "stack", None)
     if st:
         st[-1].attrs.update(kw)
+
+
+def count(**kw) -> None:
+    """Add to counters of the innermost open span, if any (a compiled
+    program launched, a device↔host copy made: by whoever makes it)."""
+    st = getattr(_TLS, "stack", None)
+    if st:
+        a = st[-1].attrs
+        for k, v in kw.items():
+            a[k] = a.get(k, 0) + v
 
 
 # ---------------------------------------------------------------------------
@@ -278,7 +313,7 @@ class QueryTrace:
     """One statement's span tree plus identity/summary fields."""
 
     __slots__ = ("qid", "signature", "root", "tier", "rows", "started",
-                 "trace_id", "failed")
+                 "trace_id", "failed", "cpu_ms", "_c0", "_clk")
 
     def __init__(self, signature: str):
         self.qid = next(_IDS)
@@ -291,24 +326,63 @@ class QueryTrace:
         # set by whoever catches the statement's error INSIDE the trace
         # (the CN server, which still has the reply to send)
         self.failed = False
+        # the owning thread's CPU over the statement (`_open` to
+        # `_close`): an `adopt`ed thread's spans spend their own
+        self.cpu_ms = 0.0
+        self._c0 = 0.0
+        self._clk = None             # that thread's CPU clock, while open
 
     @property
     def total_ms(self) -> float:
         return self.root.elapsed_ms()
 
-    def phase_ms(self, name: str) -> float:
-        """Sum of ms over spans named `name`, counting only the
-        outermost of any nested same-name runs."""
-        total = 0.0
-        work = [self.root]
+    def _open(self, st: list, since: Optional[float],
+              cpu_since: Optional[float]) -> None:
+        """Start the root, on both clocks.  The CPU clock is read
+        inside the wall clock's interval at both ends, so that `cpu_ms
+        <= total_ms` where it is as fine as the wall's; no reading is
+        clamped, so that on a clock that TICKS the mean over many
+        statements stays what the thread spent."""
+        self.root._start(st, since)
+        self._clk = _thread_clock()
+        self._c0 = time.clock_gettime(self._clk) if cpu_since is None \
+            else cpu_since
+
+    def _close(self) -> None:
+        self.cpu_ms = self.elapsed_cpu_ms()
+        self._clk = None
+        self.root._stop()
+
+    def elapsed_cpu_ms(self) -> float:
+        """`cpu_ms` once the trace has closed; until then the owning
+        thread's CPU so far, whichever thread asks (by the clock's id):
+        `last_query_stats()` reads the CN server's open trace from the
+        client's side."""
+        clk = self._clk
+        if clk is None:
+            return self.cpu_ms
+        try:
+            return (time.clock_gettime(clk) - self._c0) * 1e3
+        except OSError:              # the owning thread is gone
+            return 0.0
+
+    def phases_ms(self, names) -> dict:
+        """{name: sum of ms over the spans of that name}, counting only
+        the outermost of any nested same-name runs, in one walk."""
+        ms = dict.fromkeys(names, 0.0)
+        work = [(self.root, ())]
         while work:
-            s = work.pop()
+            s, inside = work.pop()
             for c in s.children:
-                if c.name == name:
-                    total += c.ms
-                else:
-                    work.append(c)
-        return total
+                hit = c.name in ms and c.name not in inside
+                if hit:
+                    ms[c.name] += c.ms
+                if c.children:
+                    work.append((c, inside + (c.name,) if hit else inside))
+        return ms
+
+    def phase_ms(self, name: str) -> float:
+        return self.phases_ms((name,))[name]
 
     def sum_attr(self, span_name: str, key: str) -> float:
         total = 0.0
@@ -331,24 +405,6 @@ class QueryTrace:
             work.extend(s.children)
         return n
 
-    def self_ms(self, name: str) -> float:
-        """Self time of the spans named `name` (outermost of nested
-        same-name runs, as `phase_ms`): duration less what their
-        children cover.  `self_ms("query")` is the root's: what no
-        span of the statement accounts for."""
-        if name == self.root.name:
-            return self.root.self_ms()
-        total = 0.0
-        work = [self.root]
-        while work:
-            s = work.pop()
-            for c in s.children:
-                if c.name == name:
-                    total += c.self_ms()
-                else:
-                    work.append(c)
-        return total
-
     def summary(self) -> dict:
         """The statement's numbers by key (`last_query_stats()`, the
         `otb_stat_query` view, the slow log).  One walk of the tree:
@@ -363,6 +419,7 @@ class QueryTrace:
         semi_joins = sorted_aggs = sorted_agg_lanes = initplans = 0
         sorted_agg_groups = 0
         hits = misses = 0
+        d2h = d2h_bytes = h2d = h2d_bytes = calls = 0
         work = [(self.root, ())]
         while work:
             s, inside = work.pop()
@@ -373,6 +430,12 @@ class QueryTrace:
                 inside_c = inside + (name,)     # nested runs count once
             a = s.attrs
             if a:
+                # host<->device traffic, on whichever span made it
+                d2h += a.get("d2h", 0)
+                d2h_bytes += a.get("d2h_bytes", 0)
+                h2d += a.get("h2d", 0)
+                h2d_bytes += a.get("h2d_bytes", 0)
+                calls += a.get("calls", 0)
                 if name == "upload":
                     staged += a.get("bytes", 0) or 0
                 elif name == "finalize":
@@ -460,6 +523,33 @@ class QueryTrace:
         d["sorted_agg_groups"] = int(sorted_agg_groups)
         d["initplans"] = int(initplans)
         d["unattributed_ms"] = self.root.self_ms()
+        # the host path around a program call: its inputs made ready
+        # on the device (staged arrays looked up, scalars put), the
+        # mesh tier's pull of the gathered outputs and their way back
+        # for the CN fragment, device buffers dropped
+        d["inputs_ms"] = ms["inputs"]
+        d["gather_ms"] = ms["gather"]
+        d["release_ms"] = ms["release"]
+        # thread CPU beside wall time.  `cpu_ms` is the serving
+        # thread's for the statement (an adopted thread's spans spent
+        # their own thread's and are not in it); `offcpu_ms` the part of
+        # `total_ms` that thread did not run: blocked on the device, a
+        # socket or a lock, or waiting for the interpreter lock or a
+        # core.  Not clamped (see `_open`): on a tick clock one
+        # statement's reading can pass its wall time, the mean does not
+        d["cpu_ms"] = self.elapsed_cpu_ms()
+        d["offcpu_ms"] = self.root.elapsed_ms() - d["cpu_ms"]
+        # host<->device round trips, counted where they are made:
+        # blocking device->host copies (`execute`'s overflow reads,
+        # `gather`'s pulls, `finalize.fetch`'s copies), host->device
+        # puts (`inputs`' and `execute`'s scalars, `gather`'s way back,
+        # `upload`s; `h2d_bytes` adds up the arrays', not the scalars')
+        # and compiled programs launched (`execute`, `finalize.gather`)
+        d["host_syncs"] = int(d2h + fetches)
+        d["d2h_bytes"] = int(d2h_bytes + fetch_bytes)
+        d["h2d_puts"] = int(h2d)
+        d["h2d_bytes"] = int(h2d_bytes + staged)
+        d["program_calls"] = int(calls)
         return d
 
     def to_dict(self) -> dict:
@@ -473,11 +563,13 @@ class _TraceCtx:
     already active on this thread (nested statements — triggers, the
     EXPLAIN ANALYZE inner run — ride the enclosing trace)."""
 
-    __slots__ = ("signature", "since", "owned")
+    __slots__ = ("signature", "since", "cpu_since", "owned")
 
-    def __init__(self, signature: str, since: Optional[float]):
+    def __init__(self, signature: str, since: Optional[float],
+                 cpu_since: Optional[float]):
         self.signature = signature
         self.since = since
+        self.cpu_since = cpu_since
         self.owned = None
 
     def __enter__(self) -> Optional[QueryTrace]:
@@ -491,13 +583,13 @@ class _TraceCtx:
         qt = QueryTrace(self.signature)
         self.owned = qt
         _TLS.trace = qt
-        qt.root._start(st, self.since)
+        qt._open(st, self.since, self.cpu_since)
         return qt
 
     def __exit__(self, et, ev, tb):
         qt = self.owned
         if qt is not None:
-            qt.root._stop()
+            qt._close()
             _TLS.stack.pop()
             _TLS.trace = None
             _finish(qt, failed=qt.failed or et is not None)
@@ -519,13 +611,15 @@ class _NullTraceCtx:
 _NULL_CTX = _NullTraceCtx()
 
 
-def trace_query(signature: str = "", since: Optional[float] = None):
+def trace_query(signature: str = "", since: Optional[float] = None,
+                cpu_since: Optional[float] = None):
     """The statement's trace; `since` (a `time.perf_counter()` reading)
-    backdates its start to when the caller says the statement began:
-    the CN server decodes a message before it knows it is one."""
+    and `cpu_since` (a `thread_cpu()` one) backdate its start to when
+    the caller says the statement began: the CN server decodes a
+    message before it knows it is one."""
     if not ENABLED:
         return _NULL_CTX
-    return _TraceCtx(signature, since)
+    return _TraceCtx(signature, since, cpu_since)
 
 
 class _Adopted:

@@ -205,7 +205,17 @@ STAT_TABLES = {
         ColumnDef("semi_joins", T.INT64), ColumnDef("sorted_aggs", T.INT64),
         ColumnDef("sorted_agg_lanes", T.INT64),
         ColumnDef("initplans", T.INT64),
-        ColumnDef("sorted_agg_groups", T.INT64)],
+        ColumnDef("sorted_agg_groups", T.INT64),
+        # the serving thread's CPU beside wall time, the spans under
+        # the root's self time, host<->device round trips (summary())
+        ColumnDef("cpu_ms", T.FLOAT64), ColumnDef("offcpu_ms", T.FLOAT64),
+        ColumnDef("unattributed_ms", T.FLOAT64),
+        ColumnDef("inputs_ms", T.FLOAT64),
+        ColumnDef("gather_ms", T.FLOAT64),
+        ColumnDef("release_ms", T.FLOAT64),
+        ColumnDef("host_syncs", T.INT64), ColumnDef("d2h_bytes", T.INT64),
+        ColumnDef("h2d_puts", T.INT64), ColumnDef("h2d_bytes", T.INT64),
+        ColumnDef("program_calls", T.INT64)],
     # per-node guard health (net/guard.py): breaker state + failure
     # accounting for every RPC peer this coordinator talks to
     # (reference: pgxc_node health columns fed by clustermon pings;
@@ -345,7 +355,11 @@ def refresh(cluster, names: list[str]):
                     s["bytes_materialized"], s["pool_hits"],
                     s["pool_misses"], s["semi_joins"], s["sorted_aggs"],
                     s["sorted_agg_lanes"], s["initplans"],
-                    s["sorted_agg_groups"]))
+                    s["sorted_agg_groups"], s["cpu_ms"], s["offcpu_ms"],
+                    s["unattributed_ms"], s["inputs_ms"],
+                    s["gather_ms"], s["release_ms"], s["host_syncs"],
+                    s["d2h_bytes"], s["h2d_puts"], s["h2d_bytes"],
+                    s["program_calls"]))
         elif name == "otb_node_health":
             from ..net.guard import health_rows
             rows = list(health_rows())

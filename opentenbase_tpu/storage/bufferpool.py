@@ -218,13 +218,16 @@ class DeviceBufferPool:
             s.extend([0] * (6 - len(s)))
         return s
 
-    def note_upload(self, nbytes: int, tail_rows: int = 0):
+    def note_upload(self, nbytes: int, tail_rows: int = 0,
+                    puts: int = 0):
+        """`puts`: the device arrays the upload put (`h2d_puts` of a
+        statement's summary)."""
         with _LOCK:
             self.uploaded_bytes += int(nbytes)
             self.tail_rows += int(tail_rows)
         if nbytes:
             obs_trace.event("upload", bytes=int(nbytes),
-                            tail_rows=int(tail_rows))
+                            tail_rows=int(tail_rows), h2d=int(puts))
 
     def stats_rows(self) -> list[tuple]:
         """(table, hits, misses, bytes_live, evictions, invalidations,
@@ -538,7 +541,11 @@ class DeviceBufferPool:
                 tail = 0
         stage_span.set(rows=n, tail_rows=tail)
         if up:
-            obs_trace.event("upload", table=table, bytes=int(up))
+            # every array that is not the resident entry's own was put
+            had = e.arrs if e is not None else {}
+            obs_trace.event("upload", table=table, bytes=int(up),
+                            h2d=sum(1 for k, a in arrs.items()
+                                    if had.get(k) is not a))
         nbytes = sum(int(a.nbytes) for a in arrs.values())
         codec.note_staged(store, encs)
         with _LOCK:

@@ -128,6 +128,67 @@ class TestWireProtocol:
         c.close()
 
 
+class TestServedPathAccounting:
+    """A point read through the CN server, by span and by count: the
+    spans under the root's self time, the thread's CPU beside the wall
+    time, the compiled programs it launched (PR 36)."""
+
+    def test_a_point_read_by_span_and_by_count(self, served):
+        from opentenbase_tpu.obs import trace as obs_trace
+        srv, cluster = served
+        sessions = []
+        srv.make_session = lambda: sessions.append(
+            ClusterSession(cluster)) or sessions[-1]
+        c = _client(srv)
+        c.execute("create table pr (k bigint primary key, v bigint, "
+                  "w text) distribute by shard(k)")
+        c.execute("insert into pr values " + ", ".join(
+            f"({i}, {i * 10}, 'w{i % 3}')" for i in range(1, 41)))
+        sql = "select k, v, w from pr where k = {}"
+        assert c.query(sql.format(7)) == [(7, 70, "w1")]   # builds
+        assert c.query(sql.format(8)) == [(8, 80, "w2")]   # its node stages
+        while not obs_trace.recent()[-1].signature.endswith("= 8"):
+            time.sleep(0.01)                    # that trace has finished
+        last = obs_trace.recent()[-1].qid
+        assert c.query(sql.format(8)) == [(8, 80, "w2")]
+        st = sessions[0].last_query_stats()     # at the reply: still open
+        assert st["tier"] == "fqs"
+        # one compiled program answered; its inputs went up as scalars
+        # (row count, literal, snapshot, txid); the validity and three
+        # columns came down, a copy each
+        assert st["program_calls"] == 1
+        assert st["h2d_puts"] == 4 and st["h2d_bytes"] == 0
+        assert st["host_syncs"] == st["finalize_fetches"] == 1 + 3
+        assert st["d2h_bytes"] == st["finalize_fetch_bytes"]
+        # the steps that were the root's self time have names now, and
+        # what is left is no larger than it was: before, all of it read
+        # as `unattributed_ms`
+        for key in ("inputs_ms", "release_ms"):
+            assert st[key] > 0, key
+        was = st["unattributed_ms"] + st["inputs_ms"] + st["release_ms"]
+        assert st["unattributed_ms"] < was < st["total_ms"]
+        assert st["cpu_ms"] >= 0
+        assert st["offcpu_ms"] == pytest.approx(
+            st["total_ms"] - st["cpu_ms"], abs=0.5)
+        c.close()
+        deadline = time.time() + 5
+        done = []
+        while not done and time.time() < deadline:
+            done = [q for q in obs_trace.recent() if q.qid > last
+                    and q.signature == sql.format(8)]
+            time.sleep(0.01)
+        qt = done[0]
+        assert [s.name for s in qt.root.children if s.ms > 0] == [
+            "wire.recv", "parse", "autoprep", "bind", "inputs", "inputs",
+            "execute", "release", "finalize", "release", "wire.send"]
+        fin = qt.summary()
+        # the serving thread's CPU, read at the statement's two ends
+        assert fin["cpu_ms"] == qt.cpu_ms
+        for key in ("program_calls", "host_syncs", "h2d_puts",
+                    "d2h_bytes", "h2d_bytes", "inputs_ms", "release_ms"):
+            assert fin[key] == st[key], key
+
+
 class TestTpchOverWire:
     def test_tpch_suite_over_tcp(self, served):
         """An external-process-shaped client (wire protocol only) runs

@@ -183,14 +183,15 @@ class TestSpans:
                mk("stage", 12, 8), mk("execute", 30, 20)),
             mk("finalize", 60, 35,
                mk("finalize.fetch", 61, 29), mk("remote", 85, 14)))
-        assert qt.self_ms("query") == pytest.approx(100 - 3 - 50 - 35)
-        assert qt.self_ms("parse") == pytest.approx(3)
-        # the outermost execute only, less its two children
-        assert qt.self_ms("execute") == pytest.approx(50 - 8 - 20)
+        parse, execute, finalize = qt.root.children
+        assert qt.root.self_ms() == pytest.approx(100 - 3 - 50 - 35)
+        assert parse.self_ms() == pytest.approx(3)
+        # the outer execute less its two children; the inner has none
+        assert execute.self_ms() == pytest.approx(50 - 8 - 20)
+        assert execute.children[1].self_ms() == pytest.approx(20)
         # children cover [61,95) of finalize's [60,95): the overlap
         # counts once and what sticks out does not count
-        assert qt.self_ms("finalize") == pytest.approx(1)
-        assert qt.self_ms("nothing") == 0.0
+        assert finalize.self_ms() == pytest.approx(1)
         s = qt.summary()
         assert s["unattributed_ms"] == pytest.approx(12)
         assert s["parse_ms"] == 3 and s["execute_ms"] == 50
@@ -234,16 +235,21 @@ class TestSpans:
             assert qt is None
             for name in ("wire.recv", "parse", "autoprep", "finalize",
                          "finalize.gather", "finalize.fetch",
-                         "finalize.decode", "wire.send"):
+                         "finalize.decode", "wire.send", "inputs",
+                         "gather", "release"):
                 with obs_trace.span(name) as sp:
                     assert sp is obs_trace.NULL_SPAN
-                    sp.set(bytes=1)
+                    sp.set(bytes=1, d2h=1, h2d=1, calls=1)
             obs_trace.event("pool", hit=True)
             obs_trace.record("wait", 1.0, event="lockmgr")
+            obs_trace.count(calls=1, d2h=3)     # lands nowhere
             with obs_trace.adopt(qt) as adopted:
                 assert adopted is None
         assert made == []
         assert not obs_trace.active()
+        # the no-op span holds nothing: no clock reading, no attribute
+        assert obs_trace._NullSpan.__slots__ == ()
+        assert not hasattr(obs_trace.NULL_SPAN, "cpu_ms")
 
     def test_a_real_span_is_an_annotation_on_the_profilers_clock(
             self, tmp_path):
@@ -272,6 +278,207 @@ class TestSpans:
 # ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
+
+def _spans(sp):
+    yield sp
+    for c in sp.children:
+        yield from _spans(c)
+
+
+def _cpu_tick_ms():
+    """The thread CPU clock's step: nanoseconds on most hosts, 10 ms on
+    one whose kernel accounts CPU in ticks (PERF.md, PR 36); there a
+    span's reading may pass its wall time by a tick."""
+    seen = set()
+    end = time.perf_counter() + 0.03
+    while time.perf_counter() < end:
+        seen.add(obs_trace.thread_cpu())
+    seen = sorted(seen)
+    return min((b - a for a, b in zip(seen, seen[1:])), default=0.03) * 1e3
+
+
+def _busy(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def _burn(seconds):
+    """Spin until this thread has SPENT `seconds` of CPU (a loaded
+    machine stretches the wall time, not this)."""
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+class TestCpuBesideWall:
+    """The serving thread's CPU beside the statement's wall time, and
+    the host<->device counters of `summary()` (PR 36)."""
+
+    @pytest.mark.parametrize("kind", ["sleep", "busy"])
+    def test_a_sleep_is_off_cpu_and_a_busy_loop_is_cpu(self, kind):
+        with obs_trace.trace_query("q") as qt:
+            with obs_trace.span("execute"):
+                (time.sleep if kind == "sleep" else _burn)(0.03)
+        st = qt.summary()
+        assert st["total_ms"] >= 29
+        assert st["cpu_ms"] == qt.cpu_ms
+        assert st["offcpu_ms"] == pytest.approx(
+            st["total_ms"] - st["cpu_ms"])
+        if kind == "sleep":
+            if _cpu_tick_ms() > 0.5:
+                pytest.skip("a tick-granular CPU clock says this of a "
+                            "mean only")
+            assert st["cpu_ms"] < 10 and st["offcpu_ms"] > 20
+        else:
+            # what the loop burnt is in it, however long the machine
+            # made it wait for a core meanwhile
+            assert st["cpu_ms"] >= 29 - _cpu_tick_ms()
+
+    def test_cpu_is_within_wall_on_every_finished_trace(self):
+        tick = _cpu_tick_ms()
+        slack = tick if tick > 0.5 else 0.0     # none on a fine clock
+        for work in (0.0, 0.002, 0.01):
+            for _ in range(20):
+                with obs_trace.trace_query("q") as qt:
+                    with obs_trace.span("parse"):
+                        _busy(work / 2)
+                    with obs_trace.span("execute"):
+                        time.sleep(work / 2)
+                    obs_trace.record("wait", 1.5, event="lockmgr")
+                st = qt.summary()
+                assert 0 <= st["cpu_ms"] <= st["total_ms"] + slack, st
+                assert st["offcpu_ms"] >= -slack
+        # the clock is the trace's, not a span's: a span ships as before
+        d = qt.root.to_dict()
+        assert "cpu_ms" not in d and "cpu_ms" not in d["children"][0]
+        assert qt.to_dict()["cpu_ms"] == qt.cpu_ms
+
+    def test_the_cpu_is_the_owning_threads_not_an_adopted_ones(self):
+        """The serving tier: the connection thread owns the trace and
+        waits, a dispatcher thread runs the statement under it.  The
+        trace's CPU is the owner's; the dispatcher's is not in it."""
+        with obs_trace.trace_query("q") as qt:
+            with obs_trace.span("parse"):
+                _busy(0.005)
+
+            def work():
+                with obs_trace.adopt(qt):
+                    with obs_trace.span("execute"):
+                        _busy(0.03)
+
+            th = threading.Thread(target=work)
+            th.start()
+            th.join(10)
+            assert not th.is_alive()
+        st = qt.summary()
+        assert st["execute_ms"] >= 29 and st["total_ms"] >= 34
+        # the owner slept in join() while the other thread burnt CPU
+        assert st["cpu_ms"] < 0.5 * st["total_ms"] + _cpu_tick_ms()
+        assert st["offcpu_ms"] == pytest.approx(
+            st["total_ms"] - st["cpu_ms"])
+
+    def test_an_open_traces_cpu_reads_from_another_thread(self):
+        """`last_query_stats()` reads the CN server's open trace from
+        the client's side: the CPU is the trace's OWN thread's so far."""
+        opened, done = threading.Event(), threading.Event()
+        box = {}
+
+        def serve():
+            with obs_trace.trace_query(
+                    "q", since=time.perf_counter(),
+                    cpu_since=obs_trace.thread_cpu()) as qt:
+                box["qt"] = qt
+                _burn(0.03)
+                opened.set()
+                done.wait(10)
+
+        th = threading.Thread(target=serve)
+        th.start()
+        assert opened.wait(10)
+        st = box["qt"].summary()            # this thread has burnt none
+        done.set()
+        th.join(10)
+        assert not th.is_alive()
+        assert 10 < st["cpu_ms"] <= st["total_ms"] + _cpu_tick_ms()
+        assert box["qt"].summary()["cpu_ms"] >= st["cpu_ms"]
+        assert box["qt"].cpu_ms == box["qt"].summary()["cpu_ms"]
+
+    def test_cpu_since_backdates_the_cpu_clock(self):
+        c0 = obs_trace.thread_cpu()
+        _burn(0.02)
+        with obs_trace.trace_query("q", since=time.perf_counter() - 0.02,
+                                   cpu_since=c0) as qt:
+            pass
+        with obs_trace.trace_query("q") as bare:
+            pass
+        assert qt.cpu_ms > 10 > bare.cpu_ms or _cpu_tick_ms() > 0.5
+
+    def test_transfers_are_summed_where_they_were_counted(self):
+        def mk(name, ms=1.0, **attrs):
+            sp = obs_trace.Span(name, attrs)
+            sp.ms = ms
+            return sp
+
+        qt = obs_trace.QueryTrace("hand")
+        ex = mk("execute", 9.0, d2h=3, d2h_bytes=24, calls=1, h2d=2,
+                h2d_bytes=16)
+        fin = mk("finalize", 4.0)
+        fin.children = [mk("finalize.gather", calls=2),
+                        mk("finalize.fetch", fetches=5, bytes=100)]
+        qt.root.children = [
+            mk("inputs", 0.5, h2d=1, h2d_bytes=8), ex,
+            mk("gather", 7.0, d2h=14, d2h_bytes=1000, h2d=14,
+               h2d_bytes=900),
+            mk("upload", 0.0, bytes=4096, h2d=3), fin,
+            mk("release", 0.75), mk("inputs", 0.25, h2d=1, h2d_bytes=8)]
+        st = qt.summary()
+        assert st["host_syncs"] == 3 + 14 + 5
+        assert st["d2h_bytes"] == 24 + 1000 + 100
+        assert st["h2d_puts"] == 1 + 2 + 14 + 3 + 1
+        assert st["h2d_bytes"] == 8 + 16 + 900 + 4096 + 8
+        assert st["program_calls"] == 1 + 2
+        assert st["gather_ms"] == 7.0 and st["inputs_ms"] == 0.75
+        assert st["release_ms"] == 0.75
+        assert st["finalize_fetches"] == 5 and st["bytes_staged"] == 4096
+
+    def test_count_adds_to_the_innermost_open_span(self):
+        obs_trace.count(calls=1)                # no trace: nothing
+        with obs_trace.trace_query("q") as qt:
+            with obs_trace.span("execute") as sp:
+                obs_trace.count(calls=1)
+                obs_trace.count(calls=1, d2h=2)
+            obs_trace.count(d2h=1, d2h_bytes=8)  # on the root
+        assert sp.attrs == {"calls": 2, "d2h": 2}
+        st = qt.summary()
+        assert st["program_calls"] == 2 and st["host_syncs"] == 3
+        assert st["d2h_bytes"] == 8
+
+    def test_phases_come_from_one_walk(self, monkeypatch):
+        with obs_trace.trace_query("q") as qt:
+            with obs_trace.span("plan"):
+                pass
+            with obs_trace.span("execute"):
+                with obs_trace.span("stage"):
+                    pass
+                with obs_trace.span("execute"):
+                    time.sleep(0.001)
+            with obs_trace.span("finalize"):
+                pass
+        names = ("plan", "stage", "execute", "finalize", "nothing")
+        got = qt.phases_ms(names)
+        assert got == {n: qt.phase_ms(n) for n in names}
+        assert got["execute"] == qt.root.children[1].ms  # outermost only
+        assert got["nothing"] == 0.0
+        # the registry hook asks once for its four phases
+        walks = []
+        real = obs_trace.QueryTrace.phases_ms
+        monkeypatch.setattr(
+            obs_trace.QueryTrace, "phases_ms",
+            lambda self, names: walks.append(names) or real(self, names))
+        obs_metrics.observe_query(qt)
+        assert walks == [("plan", "stage", "execute", "finalize")]
+
 
 class TestMetrics:
     def test_counter_gauge(self):
@@ -506,6 +713,86 @@ class TestClusterTier:
         assert "rows=" in r.text and "time=" in r.text
         assert "Execution Time:" in r.text
 
+    def test_q1_shaped_transfers_are_exact(self, cluster_env):
+        """A grouped aggregate through the mesh tier: every blocking
+        device->host copy and every put of the statement is counted
+        where it is made, and on CPU devices the counts are exact: they
+        follow from the answer's column count."""
+        s = cluster_env
+        sql = ("select l_returnflag, l_linestatus, sum(l_quantity), "
+               "count(*) from lineitem where l_shipdate <= "
+               "date '1998-09-01' group by l_returnflag, l_linestatus "
+               "order by l_returnflag, l_linestatus")
+        s.query(sql)                            # builds, learns classes
+        seen = []
+        for _ in range(2):
+            rows = s.query(sql)
+            st = s.last_query_stats()
+            seen.append((st["host_syncs"], st["h2d_puts"],
+                         st["d2h_bytes"], st["h2d_bytes"],
+                         st["program_calls"]))
+        assert seen[0] == seen[1]               # the same on every reply
+        assert st["tier"] == "mesh" and st["retraces"] == 0
+        arrays = len(rows[0]) + 1   # the columns and the validity: the
+        # partial aggregate gathers what the answer holds, no null mask
+        # three overflow vectors, the gather's pulls, finalize's copies
+        assert st["host_syncs"] == 3 + arrays + arrays
+        assert st["finalize_fetches"] == arrays
+        # the snapshot, the txid and the lifted date; the gather's way back
+        assert st["h2d_puts"] == 3 + arrays
+        assert st["program_calls"] == 1
+        assert st["gather_ms"] > 0 and st["inputs_ms"] > 0
+        qt = obs_trace.last_trace()
+        row_bytes = 4 + 4 + 8 + 8 + 1   # two codes, two int64, validity
+        padded = max(sp.attrs["padded"] for sp in _spans(qt.root)
+                     if sp.name == "stage")
+        down = row_bytes * 2 * min(padded, 1 << 16)  # the gather class,
+        up = row_bytes * 256        # two shards; the live rows' class
+        assert qt.sum_attr("gather", "d2h") == arrays
+        assert qt.sum_attr("gather", "d2h_bytes") == down
+        assert qt.sum_attr("gather", "h2d_bytes") == up
+        assert st["finalize_fetch_bytes"] == up
+        assert qt.sum_attr("execute", "d2h") == 3
+        assert st["d2h_bytes"] == down + up + qt.sum_attr(
+            "execute", "d2h_bytes")
+        assert st["h2d_bytes"] == up        # arrays: scalars add none
+        # root's children by name: the host path around the program
+        assert [c.name for c in qt.root.children if c.ms > 0] == [
+            "parse", "autoprep", "bind", "stage", "inputs", "execute",
+            "gather", "execute", "finalize", "release"]
+
+    def test_new_summary_keys_reach_view_slow_log_and_explain(
+            self, cluster_env, monkeypatch):
+        s = cluster_env
+        keys = ("cpu_ms", "offcpu_ms", "unattributed_ms", "inputs_ms",
+                "gather_ms",
+                "release_ms", "host_syncs", "d2h_bytes", "h2d_puts",
+                "h2d_bytes", "program_calls")
+        buf = io.StringIO()
+        monkeypatch.setattr(obs_trace, "SLOW_STREAM", buf)
+        monkeypatch.setattr(obs_trace, "SLOW_MS", 0.001)
+        s.query(Q[1])
+        monkeypatch.setattr(obs_trace, "SLOW_MS", 0)
+        st = s.last_query_stats()
+        rec = json.loads(buf.getvalue().splitlines()[-1])
+        assert rec["event"] == "slow_query"
+        for k in keys:
+            assert rec[k] == st[k], k
+        row = s.query("select qid, " + ", ".join(keys)
+                      + " from otb_stat_query where qid = "
+                      + str(st["qid"]))
+        assert len(row) == 1
+        for k, v in zip(keys, row[0][1:]):
+            assert v == pytest.approx(st[k]), k
+        assert st["host_syncs"] > 3 and st["h2d_puts"] > 3
+        text = s.execute("explain analyze " + Q[1])[0].text
+        m = re.search(r"Transfers: host_syncs=(\d+) d2h_bytes=(\d+) "
+                      r"h2d_puts=(\d+) h2d_bytes=(\d+) "
+                      r"program_calls=(\d+)", text)
+        assert m, text
+        assert int(m.group(1)) >= 3 and int(m.group(5)) >= 1
+        assert text.index("Programs:") < text.index("Transfers:")
+
     def test_otb_stat_query_view(self, cluster_env):
         s = cluster_env
         s.query(Q[1])
@@ -680,7 +967,7 @@ class TestPointReadSpans:
         assert st["tier"] == "fqs"
         # what no span covers is the root's self time
         qt = obs_trace.last_trace()
-        assert st["unattributed_ms"] == pytest.approx(qt.self_ms("query"))
+        assert st["unattributed_ms"] == pytest.approx(qt.root.self_ms())
         assert 0 < st["unattributed_ms"] < st["total_ms"]
         total = st["unattributed_ms"] + sum(
             c.ms for c in qt.root.children)
