@@ -2228,14 +2228,16 @@ def _fetch_live(b: DBatch, names: list[str], srcs: list, nullable: list):
     return count, cols, nulls
 
 
-def _materialize(b: DBatch, names: Optional[list[str]] = None):
+def _materialize(b: DBatch,  # otblint: sync-boundary
+                 names: Optional[list[str]] = None):
     """Three kinds of work, a span each: the device programs that ready
-    the columns (dispatched, not waited for), every device-to-host copy,
-    and the numpy-to-Python decode.  `finalize.fetch` adds no sync: the
-    first copy also waits for those programs and for whatever program
-    produced the batch.  A batch under _COMPACT_MIN_BYTES is copied
-    whole and its live rows found on the host; a wider one leaves only
-    its live rows (_fetch_live)."""
+    the columns (dispatched, not waited for), the device-to-host copy,
+    and the numpy-to-Python decode.  The ONE sync of finalize is
+    `finalize.fetch`'s: it also waits for those programs and for
+    whatever program produced the batch.  A batch under
+    _COMPACT_MIN_BYTES is copied whole, validity, columns and null masks
+    in one `device_get`, and its live rows found on the host; a wider
+    one leaves only its live rows, one buffer (_fetch_live)."""
     if names is None:
         names = b.names()
     srcs = [b.lazy[n].src if n in b.lazy else b.cols[n] for n in names]
@@ -2251,11 +2253,12 @@ def _materialize(b: DBatch, names: Optional[list[str]] = None):
                                  for n in names if n in b.lazy))
             b.ensure(names)
         with obs_trace.span("finalize.fetch") as sp:
-            valid = np.asarray(b.valid)
-            cols = [np.asarray(b.cols[n]) for n in names]
-            nulls = {n: np.asarray(b.nulls[n])
-                     for n in names if n in b.nulls}
-            sp.set(fetches=1 + len(cols) + len(nulls),
+            # one batched copy: every array's transfer starts before
+            # any is waited for
+            valid, cols, nulls = jax.device_get((
+                b.valid, [b.cols[n] for n in names],
+                {n: b.nulls[n] for n in names if n in b.nulls}))
+            sp.set(fetches=1,
                    bytes=valid.nbytes + sum(a.nbytes for a in cols)
                    + sum(a.nbytes for a in nulls.values()),
                    compacted=0)

@@ -796,51 +796,41 @@ class MeshRunner:
                 if len(self._ladder) > 256:
                     self._ladder.pop(next(iter(self._ladder)))
                 result = {}
-                # the gather span times the device→host pull of every
-                # CN-bound exchange output — the mesh tier's terminal
-                # materialization boundary
+                # the gather span is the host side of every CN-bound
+                # exchange output, which _call_program brought down with
+                # the overflow vectors: live rows selected and re-padded,
+                # then ONE batched put back for the CN fragment
                 with obs_trace.span("gather", tier="mesh") as gsp:
-                    # every array comes down by a blocking copy of its
-                    # own and goes back by a put of its own: counted
-                    d2h = d2h_bytes = h2d = h2d_bytes = 0
-                    for gi, (cols, valid, nulls) in out.items():
-                        gmeta = meta[gi]
+                    host = []
+                    for batch in out.values():
                         # only the live rows go on to the CN fragment,
                         # re-padded to their OWN size class: its eager
                         # kernels (final agg, sort) then compile and run
                         # at that size, not at the gather class — on a
                         # v5e the 12-operand final sort of Q1's 4 groups
                         # in a 65536-row buffer compiled in 443 s
-                        valid = np.asarray(valid)
-                        d2h += 1
-                        d2h_bytes += valid.nbytes
+                        valid = batch[1]
                         live = np.flatnonzero(valid)
                         rows = next_pow2(len(live))
                         if rows >= len(valid):
-                            live, rows = None, len(valid)
+                            host.append(batch)      # as it came down
+                            continue
 
                         def to_cn(a):
-                            nonlocal d2h, d2h_bytes, h2d, h2d_bytes
-                            if a is not valid:  # that one is down already
-                                a = np.asarray(a)
-                                d2h += 1
-                                d2h_bytes += a.nbytes
-                            if live is not None:
-                                t = np.zeros((rows,) + a.shape[1:],
-                                             a.dtype)
-                                t[:len(live)] = a[live]
-                                a = t
-                            h2d += 1
-                            h2d_bytes += a.nbytes
-                            return jnp.asarray(a)
+                            t = np.zeros((rows,) + a.shape[1:], a.dtype)
+                            t[:len(live)] = a[live]
+                            return t
 
+                        host.append(jax.tree_util.tree_map(to_cn, batch))
+                    # a statement's transient batch, a few KB freed
+                    # with the statement: no residency for the pool
+                    back = jax.device_put(host)  # otblint: disable=device-residency
+                    for gi, (cols, valid, nulls) in zip(out, back):
                         result[gi] = DBatch(
-                            {n: to_cn(a) for n, a in cols.items()},
-                            to_cn(valid),
-                            dict(gmeta["types"]), dict(gmeta["dicts"]),
-                            {n: to_cn(a) for n, a in nulls.items()})
-                    gsp.set(d2h=d2h, d2h_bytes=d2h_bytes, h2d=h2d,
-                            h2d_bytes=h2d_bytes)
+                            cols, valid, dict(meta[gi]["types"]),
+                            dict(meta[gi]["dicts"]), nulls)
+                    gsp.set(h2d=1, h2d_bytes=sum(
+                        a.nbytes for a in jax.tree_util.tree_leaves(host)))
                 return result, included
         raise MeshUnsupported("size-class ladder exhausted")
 
@@ -1176,29 +1166,31 @@ class MeshRunner:
 
     def _call_program(self, fn, meta, gather_idx, staged, table_names,
                       snapshot_ts, txid, params):  # otblint: sync-boundary
+        # the ONE sync of a mesh program call, after the call: the three
+        # overflow vectors and every gathered array in one device_get
         from .executor import stats_tier
-        # `inputs`: the snapshot, the txid and every traced parameter
-        # put on the device, a transfer and an eager convert each; the
-        # staged arrays are resident
-        with obs_trace.span("inputs") as isp:
-            flat_args = [jnp.int64(snapshot_ts), jnp.int64(txid)]
+        # `inputs`: the snapshot, the txid and every traced parameter as
+        # numpy scalars of the device dtype: they travel with the
+        # program's own argument transfer (no put, no eager convert of
+        # their own); the staged arrays are resident
+        with obs_trace.span("inputs"):
+            flat_args = [np.int64(snapshot_ts), np.int64(txid)]
             for k in meta.get("traced", ()):
                 v, t = params[k]
-                flat_args.append(jnp.asarray(v, dtype=dev_dtype(t)))
-            isp.set(h2d=len(flat_args))
+                flat_args.append(np.asarray(v, dtype=dev_dtype(t)))
         for t in table_names:
             for n in sorted(staged[t].arrs):
                 flat_args.append(staged[t].arrs[n])
             flat_args.append(staged[t].nrows)
         t0 = time.perf_counter()
-        # the execute span covers the program call and the overflow
-        # device_gets — the mesh tier's one legal sync point per call,
-        # so the span's wall time includes the device work
+        # the execute span covers the program call and the device_get of
+        # what it returned — the mesh tier's one legal sync point per
+        # call, so the span's wall time includes the device work
         with obs_trace.span("execute", tier="mesh") as sp:
             with stats_tier("mesh"):
                 # executor counters inside the trace attribute to the
                 # mesh tier (first call of a fresh program traces here)
-                outs, a2a_over_vec, join_over, g_over_vec = fn(*flat_args)
+                dev = fn(*flat_args)
             # the all_to_all exchanges this program holds and the bytes
             # one chip sends in them: fixed when the program was traced
             # (meta is filled by the trace the first call made)
@@ -1208,17 +1200,19 @@ class MeshRunner:
             plancache.MESH.record_call(fn, t0)
             if EXPORT_HOOK is not None:
                 EXPORT_HOOK("mesh", fn, tuple(flat_args))
-            over_vec = np.asarray(jax.device_get(join_over))
+            # every leaf's copy starts before any is waited for: one
+            # round trip.  The gathered arrays come down WITH their
+            # overflow vectors and are thrown away on the rare overflow
+            host = jax.device_get(dev)
+            outs, av, over_vec, gv = host
+            sp.set(d2h=1, d2h_bytes=sum(
+                a.nbytes for a in jax.tree_util.tree_leaves(host)))
             over_jids = sorted({jid for jid, ov in
                                 zip(meta.get("jid_order", ()), over_vec)
                                 if ov > 0})
-            av = np.asarray(jax.device_get(a2a_over_vec))
             a2a_over = sorted({ei for ei, ov in
                                zip(meta.get("ex_order", ()), av)
                                if ov > 0})
-            gv = np.asarray(jax.device_get(g_over_vec))
-            sp.set(d2h=3,
-                   d2h_bytes=over_vec.nbytes + av.nbytes + gv.nbytes)
             g_over = sorted({gi for gi, ov in
                              zip(meta.get("gi_order", ()), gv) if ov > 0})
             # a class overflowed: run() replays the statement one class

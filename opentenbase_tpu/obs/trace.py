@@ -32,9 +32,12 @@ Design constraints (TPU-first):
   (PERF.md section 6, PR 36); and it ticks in 10 ms there, so only a
   MEAN over many statements says anything.
 - Host↔device traffic is COUNTED where it happens, as span attributes
-  (``d2h``/``d2h_bytes``: blocking device→host copies; ``h2d``/
-  ``h2d_bytes``: puts; ``calls``: compiled programs launched): only at
-  the sync points above, by the code that makes the copy or the call.
+  (``d2h``/``d2h_bytes``: blocking device→host round trips, a batched
+  ``device_get`` of many arrays ONE, and the bytes that crossed;
+  ``h2d``/``h2d_bytes``: put calls, a batched ``device_put`` ONE, a
+  scalar riding a program's own argument transfer none; ``calls``:
+  compiled programs launched): only at the sync points above, by the
+  code that makes the copy or the call.
 
 Env vars: ``OTB_TRACE`` (default on), ``OTB_SLOW_MS`` (slow-query log
 threshold, 0 = off), ``OTB_TRACE_RING`` (recent-trace ring size).
@@ -524,8 +527,8 @@ class QueryTrace:
         d["initplans"] = int(initplans)
         d["unattributed_ms"] = self.root.self_ms()
         # the host path around a program call: its inputs made ready
-        # on the device (staged arrays looked up, scalars put), the
-        # mesh tier's pull of the gathered outputs and their way back
+        # (staged arrays looked up; the fused tier's scalars put), the
+        # mesh tier's gathered rows re-padded on the host and put back
         # for the CN fragment, device buffers dropped
         d["inputs_ms"] = ms["inputs"]
         d["gather_ms"] = ms["gather"]
@@ -540,9 +543,11 @@ class QueryTrace:
         d["cpu_ms"] = self.elapsed_cpu_ms()
         d["offcpu_ms"] = self.root.elapsed_ms() - d["cpu_ms"]
         # host<->device round trips, counted where they are made:
-        # blocking device->host copies (`execute`'s overflow reads,
-        # `gather`'s pulls, `finalize.fetch`'s copies), host->device
-        # puts (`inputs`' and `execute`'s scalars, `gather`'s way back,
+        # blocking device->host round trips (`execute`'s one copy of
+        # the overflow vectors, in the mesh tier with every gathered
+        # array; `finalize.fetch`'s one copy: a batched copy counts
+        # once), host->device put calls (the fused tier's scalars in
+        # `inputs` and `execute`, `gather`'s one batched put back,
         # `upload`s; `h2d_bytes` adds up the arrays', not the scalars')
         # and compiled programs launched (`execute`, `finalize.gather`)
         d["host_syncs"] = int(d2h + fetches)

@@ -155,10 +155,10 @@ class TestServedPathAccounting:
         assert st["tier"] == "fqs"
         # one compiled program answered; its inputs went up as scalars
         # (row count, literal, snapshot, txid); the validity and three
-        # columns came down, a copy each
+        # columns came down in ONE batched copy
         assert st["program_calls"] == 1
         assert st["h2d_puts"] == 4 and st["h2d_bytes"] == 0
-        assert st["host_syncs"] == st["finalize_fetches"] == 1 + 3
+        assert st["host_syncs"] == st["finalize_fetches"] == 1
         assert st["d2h_bytes"] == st["finalize_fetch_bytes"]
         # the steps that were the root's self time have names now, and
         # what is left is no larger than it was: before, all of it read

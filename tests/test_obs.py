@@ -733,13 +733,14 @@ class TestClusterTier:
                          st["program_calls"]))
         assert seen[0] == seen[1]               # the same on every reply
         assert st["tier"] == "mesh" and st["retraces"] == 0
-        arrays = len(rows[0]) + 1   # the columns and the validity: the
-        # partial aggregate gathers what the answer holds, no null mask
-        # three overflow vectors, the gather's pulls, finalize's copies
-        assert st["host_syncs"] == 3 + arrays + arrays
-        assert st["finalize_fetches"] == arrays
-        # the snapshot, the txid and the lifted date; the gather's way back
-        assert st["h2d_puts"] == 3 + arrays
+        # one batched copy after the program call (the three overflow
+        # vectors and every gathered array), one at finalize: a batched
+        # copy is ONE round trip however many arrays it carries
+        assert st["host_syncs"] == 2
+        assert st["finalize_fetches"] == 1
+        # the gather's way back is one put call; the snapshot, the txid
+        # and the lifted date ride the program's own argument transfer
+        assert st["h2d_puts"] == 1
         assert st["program_calls"] == 1
         assert st["gather_ms"] > 0 and st["inputs_ms"] > 0
         qt = obs_trace.last_trace()
@@ -748,13 +749,15 @@ class TestClusterTier:
                      if sp.name == "stage")
         down = row_bytes * 2 * min(padded, 1 << 16)  # the gather class,
         up = row_bytes * 256        # two shards; the live rows' class
-        assert qt.sum_attr("gather", "d2h") == arrays
-        assert qt.sum_attr("gather", "d2h_bytes") == down
+        over = 3 * 8    # the overflow vectors: one int64 flag each here
+        assert qt.sum_attr("inputs", "h2d") == 0
+        assert qt.sum_attr("execute", "d2h") == 1
+        assert qt.sum_attr("execute", "d2h_bytes") == down + over
+        assert qt.sum_attr("gather", "d2h") == 0
+        assert qt.sum_attr("gather", "h2d") == 1
         assert qt.sum_attr("gather", "h2d_bytes") == up
         assert st["finalize_fetch_bytes"] == up
-        assert qt.sum_attr("execute", "d2h") == 3
-        assert st["d2h_bytes"] == down + up + qt.sum_attr(
-            "execute", "d2h_bytes")
+        assert st["d2h_bytes"] == down + over + up   # bytes: unchanged
         assert st["h2d_bytes"] == up        # arrays: scalars add none
         # root's children by name: the host path around the program
         assert [c.name for c in qt.root.children if c.ms > 0] == [
@@ -784,13 +787,15 @@ class TestClusterTier:
         assert len(row) == 1
         for k, v in zip(keys, row[0][1:]):
             assert v == pytest.approx(st[k]), k
-        assert st["host_syncs"] > 3 and st["h2d_puts"] > 3
+        # Q1 in the mesh tier: a batched copy after the call, one at
+        # finalize; the gather's one put back
+        assert st["host_syncs"] == 2 and st["h2d_puts"] == 1
         text = s.execute("explain analyze " + Q[1])[0].text
         m = re.search(r"Transfers: host_syncs=(\d+) d2h_bytes=(\d+) "
                       r"h2d_puts=(\d+) h2d_bytes=(\d+) "
                       r"program_calls=(\d+)", text)
         assert m, text
-        assert int(m.group(1)) >= 3 and int(m.group(5)) >= 1
+        assert int(m.group(1)) >= 2 and int(m.group(5)) >= 1
         assert text.index("Programs:") < text.index("Transfers:")
 
     def test_otb_stat_query_view(self, cluster_env):
@@ -943,8 +948,8 @@ class TestPointReadSpans:
         fetches, nbytes, materialized = seen.pop()
         if side == "under":
             # `valid` and the four columns read, no null mask: five
-            # copies of the whole padded table
-            assert fetches == 5
+            # arrays of the whole padded table in ONE batched copy
+            assert fetches == 1
             assert nbytes == padded * (1 + row)
             assert materialized == padded * row
         else:
@@ -974,7 +979,7 @@ class TestPointReadSpans:
         assert total == pytest.approx(st["total_ms"], rel=1e-6)
 
     @pytest.mark.parametrize("side, fetches, fetch_bytes", [
-        ("under", 5, 16384 * 29), ("over", 1, 4 + 256 * 28)])
+        ("under", 1, 16384 * 29), ("over", 1, 4 + 256 * 28)])
     def test_wire_and_parse_for_a_statement_sent_through_cnserver(
             self, point_env, side, fetches, fetch_bytes, monkeypatch):
         from opentenbase_tpu.exec import executor
