@@ -201,6 +201,23 @@ def check_kernels(report: dict):
                                            left_outer=True,
                                            probe_valid=None),
             (i, i, i), f"join_expand/{n}", report, no_wide_gather=True)
+        # a semi or anti join's `<>` residual answered by a mask: the
+        # packed single sort (both spans known) and the two-key sort,
+        # then two row gathers of 32-bit words a probe row: no loop, no
+        # scatter, no conditional, no 64-bit gather
+        for spans in ((None, None), (n // 2, 7)):
+            export_check(
+                lambda k, m, c, spans=spans: K.join_build_minor(
+                    k, m, c, key_span=spans[0], minor_span=spans[1]),
+                (i, v, i), f"join_build_minor/{n}/{spans[0]}", report,
+                no_conditional=True)
+        i32 = jnp.zeros(n, jnp.int32)
+        export_check(
+            lambda lo, c, sm, pm, ok: K.range_differs(
+                lo, c, sm, pm[0], pm, ok),
+            (i32, i32, i32, i, v), f"range_differs/{n}", report,
+            no_scatter_sort=True, no_conditional=True,
+            no_wide_gather=True, no_loop=True)
         export_check(K.semi_mask, (i,), f"semi_mask/{n}", report)
         export_check(lambda c, pv: K.anti_mask(c, pv), (i, v),
                      f"anti_mask/{n}", report)

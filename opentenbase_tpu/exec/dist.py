@@ -41,7 +41,8 @@ from ..plan import physical as P
 from ..plan.planner import PlannedStmt
 from ..storage.batch import next_pow2
 from ..utils.hashing import hash_columns_np, hash_string
-from .executor import DBatch, ExecContext, ExecError, Executor, materialize
+from .executor import (DBatch, ExecContext, ExecError, Executor, materialize,
+                       scalars_from_batch)
 
 
 def _walk_plan(node):
@@ -184,14 +185,10 @@ class DistExecutor:
                 PlannedStmt(_copy.deepcopy(ip.plan), [], []), None)
             # one span an init plan: `initplans` of summary() counts them
             with obs_trace.span("initplan", plan=ip.name):
-                batch = self._run_distplan(sub)
-                val = self._scalar(batch)
-            self.params[ip.name] = (val, ip.type)
+                vals = scalars_from_batch(self._run_distplan(sub),
+                                          ip.outputs())
+            self.params.update(vals)
         return self._run_distplan(dp)
-
-    def _scalar(self, b: DBatch):
-        from .executor import scalar_from_batch
-        return scalar_from_batch(b)
 
     def _scan_exceeds_budget(self, dp, budget: int) -> bool:
         """Does any per-DN scan of this plan exceed the work_mem

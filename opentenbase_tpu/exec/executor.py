@@ -43,6 +43,7 @@ from ..utils.dtypes import (bits_to_float, dev_dtype, device_float,
                             float_to_bits)
 from ..utils.hashing import hash_columns_jax
 from ..utils import locks
+from . import strtable
 
 
 class ExecError(Exception):
@@ -367,12 +368,22 @@ class ExecContext:
     # small-probe/large-output join can grow without inflating every
     # other join's buffers.
     join_factors: Optional[dict] = None
+    # a compiled tier's string-predicate bitmaps (exec/strtable.py):
+    # literal StrPred -> (the dictionary's list it was resolved against,
+    # the traced int32 words), bound where a batch carries that very list
+    str_tables: Optional[dict] = None
 
 
 # the device-side name of each plan node's own ops (ops/kernels.py has
 # the vocabulary); a node not listed scans, filters or projects
 _NODE_SCOPES = {"Agg": "otb.agg", "HashJoin": "otb.join_expand",
                 "Sort": "otb.sort", "Window": "otb.sort"}
+
+
+@dataclasses.dataclass
+class _DictView:
+    """A batch's dictionary as the expression compiler reads one."""
+    values: list
 
 
 class Executor:
@@ -394,27 +405,33 @@ class Executor:
         # what the traversal built, for the compiled tiers to put on
         # their `execute` span (obs/trace.py `summary()`): joins
         # answered by a mask, sorted aggregates, the largest one's lanes
-        # (its INPUT's padded rows) and the largest OUTPUT class
-        self.shape = {"semi_joins": 0, "sorted_aggs": 0,
-                      "sorted_agg_lanes": 0, "sorted_agg_groups": 0}
+        # (its INPUT's padded rows) and the largest OUTPUT class; of
+        # those joins the anti ones, joins through join_expand's
+        # left_outer arm, the largest class a semi or anti join with a
+        # residual EXPANDS into (0: every one was answered by a mask) and
+        # the largest code set or bitmap a string predicate brings
+        self.shape = dict.fromkeys(
+            obs_trace.SHAPE_SUMS + obs_trace.SHAPE_MAXIMA, 0)
 
     # ------------------------------------------------------------------
     def run(self, planned: PlannedStmt):
         for ip in planned.init_plans:
             with obs_trace.span("initplan", plan=ip.name):
-                batch = self.exec_node(ip.plan)
-                val = self._scalar_from_batch(batch, ip.type)
-            self.ctx.params[ip.name] = (val, ip.type)
+                vals = scalars_from_batch(self.exec_node(ip.plan),
+                                          ip.outputs())
+            self.ctx.params.update(vals)
         out = self.exec_node(planned.plan)
         return out
 
-    def _scalar_from_batch(self, b: DBatch, t: SqlType):
-        return scalar_from_batch(b)
-
     # ------------------------------------------------------------------
-    def _prep(self, e: E.Expr) -> E.Expr:
+    def _prep(self, e: E.Expr, batch: Optional[DBatch] = None) -> E.Expr:
         """Substitute init-plan results and bound parameters before
-        compiling.  A text parameter compared with a column
+        compiling; with the `batch` the expression is about to read,
+        also a literal string predicate's bitmap where a compiled tier
+        bound one for the dictionary that batch carries (CodeBitmap;
+        any other literal compiles against the batch's dictionaries),
+        and count what the predicate brings (`strpred_codes`).  A text
+        parameter compared with a column
         (StrPred.param) has ONE reading, here: where a compiled tier
         bound it to the column's dictionary code (bind_text_params), a
         compare of the column's codes with that traced scalar; where the
@@ -424,12 +441,29 @@ class Executor:
         (re-encoded after an exchange, so no stored code would do).
         Both keep SQL's three-valued result for NULL rows."""
         params = self.ctx.params
+        tables = self.ctx.str_tables or {}
 
         def sub(x: E.Expr):
             if isinstance(x, E.Col) and x.name in params:
                 v, t = params[x.name]
                 return E.Lit(v, t)
-            if isinstance(x, E.StrPred) and x.param is not None:
+            if isinstance(x, E.StrPred) and x.param is None:
+                values = batch.dicts.get(strtable.column_of(x)) \
+                    if batch is not None else None
+                if values is None:
+                    return None
+                bound = tables.get(x)
+                if bound is not None and bound[0] is values:
+                    brings = len(values)
+                    x = E.CodeBitmap(x.col, bound[1],
+                                     x.kind in strtable.NEGATED)
+                else:
+                    codes, _words = strtable.resolve(x, values)
+                    brings = len(values) if codes is None else len(codes)
+                self.shape["strpred_codes"] = max(
+                    self.shape["strpred_codes"], brings)
+                return x
+            if isinstance(x, E.StrPred):
                 code = params.get(text_param_key(x.param))
                 if code is not None:
                     return E.Cmp("=" if x.kind == "eq" else "<>", x.col,
@@ -444,14 +478,6 @@ class Executor:
 
     @staticmethod
     def _dictviews(batch: DBatch):
-        class _DictView:
-            def __init__(self, values):
-                self.values = values
-
-            def codes_matching(self, pred):
-                return np.asarray([i for i, v in enumerate(self.values)
-                                   if pred(v)], dtype=np.int32)
-
         return {n: _DictView(v) for n, v in batch.dicts.items()}
 
     @staticmethod
@@ -470,7 +496,7 @@ class Executor:
         touches — expression eval gathers on demand, never the whole
         carried width.  Must run BEFORE compile: the null-awareness set
         (frozenset(batch.nulls)) is part of the compiled program."""
-        pe = self._prep(e)
+        pe = self._prep(e, batch)
         if batch.lazy:
             # sorted: a set of names iterates in string-hash order, which
             # differs from process to process — the gathers would be
@@ -949,32 +975,57 @@ class Executor:
         with jax.named_scope("otb.join_build"):
             rkey, rhashed, rcheck, span = self._join_key(node.right_keys,
                                                          right)
-        # one sort and one probe algorithm per program, chosen here, when
-        # the program is built, from what the host knows of the build
-        # key's range; nothing known (a computed or hashed key, a column
-        # whose codec proves no range) takes the algorithms that need no
-        # range
-        skeys, perm = K.join_build(rkey, right.valid, key_span=span)
-        lo, counts = K.join_probe_counts(skeys, lkey, left.valid,
-                                         key_span=span)
-
         hash_recheck = []
         if lhashed or rhashed:
             hash_recheck = [
                 (lk, rk) for (lk, rk), lok, rok in
                 zip(zip(node.left_keys, node.right_keys), lcheck, rcheck)
                 if lok and rok]
+        differ = None if hash_recheck \
+            else self._differ_residual(node, left, right)
+        # one sort and one probe algorithm per program, chosen here, when
+        # the program is built, from what the host knows of the build
+        # key's range; nothing known (a computed or hashed key, a column
+        # whose codec proves no range) takes the algorithms that need no
+        # range
+        if differ is None:
+            skeys, perm = K.join_build(rkey, right.valid, key_span=span)
+        else:
+            # EXISTS / NOT EXISTS (... and build.c <> probe.c): the build
+            # side sorted by (key, c), so that a probe row's matches show
+            # their smallest and largest c at the ends of its range
+            probe_c, build_c = differ
+            with jax.named_scope("otb.join_build"):
+                bc, bnull = self._eval_pair(build_c, right)
+            skeys, _perm, sminor, base = K.join_build_minor(
+                rkey, right.valid if bnull is None
+                else right.valid & ~bnull, bc, key_span=span,
+                minor_span=right.spans.get(build_c.name))
+        lo, counts = K.join_probe_counts(skeys, lkey, left.valid,
+                                         key_span=span)
 
         _bump("joins")
-        if node.kind in ("semi", "anti") and not node.residual \
-                and not hash_recheck:
-            mask = K.semi_mask(counts) if node.kind == "semi" \
-                else K.anti_mask(counts, left.valid)
+        if node.kind in ("semi", "anti") and not hash_recheck \
+                and (differ is not None or not node.residual):
+            # answered by a mask: no pair is made
+            if differ is not None:
+                with jax.named_scope("otb.join_probe"):
+                    pc, pnull = self._eval_pair(probe_c, left)
+                found = K.range_differs(
+                    lo, counts, sminor, base, pc, left.valid
+                    if pnull is None else left.valid & ~pnull)
+                mask = found if node.kind == "semi" else ~found
+            elif node.kind == "semi":
+                mask = K.semi_mask(counts)
+            else:
+                mask = K.anti_mask(counts, left.valid)
             self.shape["semi_joins"] += 1
+            self.shape["anti_joins"] += int(node.kind == "anti")
             return DBatch(left.cols, left.valid & mask, left.types,
                           left.dicts, left.nulls, left.lazy, left.spans)
 
         left_outer = node.kind in ("left", "full")
+        self.shape["outer_joins"] += int(left_outer)
         # the counts are int32 words; their sum may pass one
         total = jnp.sum(jnp.where(left.valid, jnp.maximum(counts, 1), 0)
                         if left_outer else counts, dtype=jnp.int64)
@@ -986,12 +1037,19 @@ class Executor:
             # overflow retraces one step up — the learned value persists
             # in the mesh runner's ladder memory, and every op downstream
             # of the join (agg sorts, exchanges, gathers) scales with it
+            # (an outer join emits every valid probe row at least and,
+            # joined to the many side, every build row: it starts at the
+            # larger input, where a quarter would be overflowed twice)
             jid, factor = self._ladder_slot()
-            out_size = max(64, (max(left.padded, right.padded) // 4)
-                           * factor)
+            out_size = max(64, (max(left.padded, right.padded)
+                                // (1 if left_outer else 4)) * factor)
             self.join_required.append((jid, total, out_size))
         else:
             out_size = next_pow2(max(int(total), 1))
+        if node.kind in ("semi", "anti"):
+            # a residual no mask answers: every pair is made and judged
+            self.shape["residual_semi_lanes"] = max(
+                self.shape["residual_semi_lanes"], out_size)
         pi, bi, tot = K.join_expand(lo, counts, perm, out_size,
                                     left_outer=left_outer,
                                     probe_valid=left.valid)
@@ -1086,6 +1144,28 @@ class Executor:
             return DBatch(cols2, valid2, out.types, out.dicts, nulls2)
         out.valid = res_valid
         return out
+
+    @staticmethod
+    def _differ_residual(node: P.HashJoin, left: DBatch, right: DBatch):
+        """(probe column, build column) where a semi or anti join's ONLY
+        residual is `a <> b` between a plain column of each side, of one
+        exact type (integers, dates, decimals of one scale: the stored
+        words compare as the values do); else None."""
+        if node.kind not in ("semi", "anti") or len(node.residual) != 1:
+            return None
+        q = node.residual[0]
+        if not (isinstance(q, E.Cmp) and q.op == "<>"
+                and isinstance(q.left, E.Col)
+                and isinstance(q.right, E.Col)
+                and q.left.type == q.right.type
+                and q.left.type.kind in (TypeKind.INT32, TypeKind.INT64,
+                                         TypeKind.DATE, TypeKind.DECIMAL)):
+            return None
+        for a, b in ((q.left, q.right), (q.right, q.left)):
+            if left.has_col(a.name) and not right.has_col(a.name) \
+                    and right.has_col(b.name) and not left.has_col(b.name):
+                return a, b
+        return None
 
     def _cross_join(self, left: DBatch, right: DBatch) -> DBatch:
         ln, rn = left.count(), right.count()
@@ -1736,10 +1816,7 @@ class Executor:
         d = _dict_for_expr(e, b.dicts)
         if d is not None and for_order:
             # dictionary codes are unordered: map code -> rank
-            order = np.argsort(np.asarray(d, dtype=object))
-            rank = np.empty(max(len(d), 1), dtype=np.int32)
-            rank[order] = np.arange(len(d), dtype=np.int32)
-            arr = jnp.asarray(rank)[jnp.clip(arr, 0, len(d) - 1)]
+            arr = jnp.asarray(_text_ranks(d))[jnp.clip(arr, 0, len(d) - 1)]
         if arr.dtype == jnp.bool_:
             arr = arr.astype(jnp.int32)
         if not jnp.issubdtype(arr.dtype, jnp.floating):
@@ -2035,10 +2112,8 @@ class Executor:
             d = _dict_for_expr(ke, b.dicts)
             if d is not None:
                 # dictionary codes are unordered: map code -> rank
-                order = np.argsort(np.asarray(d, dtype=object))
-                rank = np.empty(max(len(d), 1), dtype=np.int32)
-                rank[order] = np.arange(len(d), dtype=np.int32)
-                arr = jnp.asarray(rank)[jnp.clip(arr, 0, len(d) - 1)]
+                arr = jnp.asarray(_text_ranks(d))[
+                    jnp.clip(arr, 0, len(d) - 1)]
             if nm is not None:
                 # NULLs sort as +infinity: last under ASC, first under
                 # DESC — PostgreSQL's default NULLS LAST/FIRST pairing
@@ -2133,21 +2208,37 @@ def _dict_for_expr(e: E.Expr, dicts: dict):
     return None
 
 
-def scalar_from_batch(b: DBatch):
-    """One value or SQL NULL (None) from a scalar-subquery result — an
-    empty subquery is NULL, not 0 (reference: ExecScanSubPlan's
-    unset-param NULL).  Shared by the local and distributed executors."""
+def _text_ranks(d) -> np.ndarray:
+    """code -> the rank of its string among the dictionary's DISTINCT
+    strings, int32.  Two codes of one string (a transformed column's:
+    `substring(c_phone from 1 for 2)` keeps c_phone's 150,000 codes over
+    25 strings) share a rank: they are ONE sort key, and the next key
+    orders their rows.  Sorting the distinct strings, not an object array
+    of every code's: Q22's ORDER BY cntrycode ranked 150,000 strings a
+    statement, ~100 ms of a 141 ms reply on the chip's host (PERF.md
+    section 6, PR 39)."""
+    place = {v: i for i, v in enumerate(sorted(set(d)))}
+    return np.fromiter(map(place.__getitem__, d), np.int32, len(d)) \
+        if d else np.zeros(1, np.int32)
+
+
+def scalars_from_batch(b: DBatch, outputs: list) -> dict:
+    """{parameter: (value | None, type)} of an init plan's result: the
+    batch's columns in order, one a name of `outputs` (InitPlan.outputs:
+    one, or the several values one run gives).  An empty subquery is SQL
+    NULL (None), not 0 (reference: ExecScanSubPlan's unset-param NULL).
+    Shared by the local and distributed executors."""
     b.ensure_all()
-    name = next(iter(b.cols))
     valid = np.asarray(b.valid)
-    vals = np.asarray(b.cols[name])[valid]
-    if len(vals) == 0:
-        return None
-    if len(vals) > 1:
+    if int(valid.sum()) > 1:
         raise ExecError("scalar subquery returned more than one row")
-    if name in b.nulls and bool(np.asarray(b.nulls[name])[valid][0]):
-        return None
-    return vals[0].item()
+    out = {}
+    for col, (name, t) in zip(b.cols, outputs):
+        vals = np.asarray(b.cols[col])[valid]
+        null = len(vals) == 0 or (
+            col in b.nulls and bool(np.asarray(b.nulls[col])[valid][0]))
+        out[name] = (None if null else vals[0].item(), t)
+    return out
 
 
 def materialize(b: DBatch, names: Optional[list[str]] = None):

@@ -38,6 +38,7 @@ import numpy as np
 from ..catalog.types import TypeKind
 from ..plan import exprs as E
 from ..utils.dtypes import device_float, dev_dtype
+from . import strtable
 
 Arrays = dict  # name -> jnp array (null masks under NULLKEY + name)
 
@@ -93,40 +94,6 @@ def case_text_dict(e) -> "list | None":
         if s not in values:
             values.append(s)
     return values or [""]
-
-
-def _strpred_colname(pred: E.StrPred) -> str:
-    c = pred.col
-    return c.col.name if isinstance(c, E.TextExpr) else c.name
-
-
-def _codes_for_strpred(pred: E.StrPred, dicts: dict) -> np.ndarray:
-    if pred.param is not None:
-        # Executor._prep gives a run-time string its value before
-        # compiling: reaching here would compile an empty pattern set,
-        # a wrong answer
-        raise E.ExprError(f"text parameter {pred.param[0]} is not bound")
-    name = _strpred_colname(pred)
-    d = dicts.get(name)
-    if d is None:
-        raise E.ExprError(f"no dictionary for TEXT column {name!r}")
-    transform = (pred.col.apply if isinstance(pred.col, E.TextExpr)
-                 else (lambda s: s))
-    k = pred.kind
-    if k in ("eq", "ne", "in", "not_in"):
-        wanted = set(pred.patterns)
-        test = lambda s: transform(s) in wanted
-    elif k in ("like", "not_like"):
-        rx = like_to_regex(pred.patterns[0])
-        test = lambda s: rx.match(transform(s)) is not None
-    elif k in ("lt", "le", "gt", "ge"):
-        p = pred.patterns[0]
-        base = {"lt": lambda s: s < p, "le": lambda s: s <= p,
-                "gt": lambda s: s > p, "ge": lambda s: s >= p}[k]
-        test = lambda s: base(transform(s))
-    else:
-        raise E.ExprError(f"unknown string predicate {k}")
-    return d.codes_matching(test)
 
 
 def _membership(arr, codes: np.ndarray):
@@ -507,15 +474,31 @@ def compile_pair(e: E.Expr, dicts: dict, nullable=frozenset()):
             vals = np.asarray(x.values)
             return (lambda cols: _membership(f(cols), vals)), nf
 
-        if isinstance(x, E.StrPred):
-            codes = _codes_for_strpred(x, dicts)
-            name = _strpred_colname(x)
-            neg = x.kind in ("ne", "not_like", "not_in")
+        if isinstance(x, (E.StrPred, E.CodeBitmap)):
+            # the verdicts a dictionary value (exec/strtable.py): a few
+            # codes to compare with, or a bitmap over the codes, which a
+            # compiled tier may already have bound as a program argument
+            # (CodeBitmap: Executor._ensure_expr)
+            name = strtable.column_of(x)
+            if isinstance(x, E.StrPred):
+                d = dicts.get(name)
+                if d is None:
+                    raise E.ExprError(
+                        f"no dictionary for TEXT column {name!r}")
+                codes, words = strtable.resolve(x, d.values)
+                neg = x.kind in strtable.NEGATED
+            else:
+                codes, words, neg = None, x.words, x.negated
+            if codes is not None:
+                member = lambda cols: _membership(cols[name], codes)
+            else:
+                member = lambda cols: strtable.bit_of(cols[name],
+                                                      jnp.asarray(words))
             nf = (lambda env, _k=NULLKEY + name: env[_k]) \
                 if name in nullable else None
             if neg:
-                return (lambda cols: ~_membership(cols[name], codes)), nf
-            return (lambda cols: _membership(cols[name], codes)), nf
+                return (lambda cols: ~member(cols)), nf
+            return member, nf
 
         if isinstance(x, E.TextExpr):
             # codes pass through; only the decode dictionary changes

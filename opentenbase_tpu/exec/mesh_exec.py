@@ -997,6 +997,12 @@ class MeshRunner:
         traced_names = tuple(sorted(
             k for k, (v, _t) in params.items()
             if isinstance(v, (int, float)) and not isinstance(v, bool)))
+        # a literal string predicate with more verdicts than a program
+        # unrolls arrives as a bitmap over its dictionary's codes, an
+        # ARGUMENT like the parameters above: which predicates those are
+        # follows from the plan and the dictionaries' lengths, both in
+        # the key, and the verdicts are the host's (exec/strtable.py)
+        str_tables = self._str_tables(dp, included, staged)
         baked = {k: params[k] for k in params if k not in traced_names}
         prog_key = (
             id(self),
@@ -1043,7 +1049,7 @@ class MeshRunner:
                 bump_stat("mesh", "fused_join_hits")
             return self._call_program(fn, meta, gather_idx, staged,
                                       table_names, snapshot_ts, txid,
-                                      params)
+                                      params, str_tables)
 
         meta: dict = {"traced": traced_names}
 
@@ -1053,6 +1059,9 @@ class MeshRunner:
             run_params = dict(baked)
             for name, pv in zip(traced_names, pvals):
                 run_params[name] = (pv, params[name][1])
+            bitmaps = {pred: (values, words) for (pred, values, _w), words
+                       in zip(str_tables, flat)}
+            flat = flat[len(str_tables):]
             arrs_by_table = {}
             i = 0
             for t in table_names:
@@ -1066,7 +1075,8 @@ class MeshRunner:
                 snapshot_ts=snap, txid=txn, cache=None,
                 params=run_params,
                 staged=arrs_by_table,
-                join_factors=dict(factors))
+                join_factors=dict(factors),
+                str_tables=bitmaps)
             ex_batches: dict = {}
             overflows = []
             meta["ex_order"] = []
@@ -1085,9 +1095,8 @@ class MeshRunner:
                 b = exe.exec_node(plan)
                 join_reqs.extend(exe.join_required)
                 for k, v in exe.shape.items():
-                    # the largest aggregate's lanes and class; counts add
                     shape[k] = max(shape.get(k, 0), v) \
-                        if k in ("sorted_agg_lanes", "sorted_agg_groups") \
+                        if k in obs_trace.SHAPE_MAXIMA \
                         else shape.get(k, 0) + v
                 for ex in dp.exchanges:
                     if ex.source_fragment != frag.index:
@@ -1145,7 +1154,8 @@ class MeshRunner:
             return (tuple(gather_out[gi] for gi in gather_idx),
                     a2a_over, join_over, g_over)
 
-        in_specs = [PS(), PS()] + [PS()] * len(traced_names)
+        in_specs = [PS(), PS()] + [PS()] * (len(traced_names)
+                                            + len(str_tables))
         for t in table_names:
             in_specs.extend([PS(self.axis)] * (len(staged[t].arrs) + 1))
 
@@ -1162,10 +1172,45 @@ class MeshRunner:
             self._programs.pop(next(iter(self._programs)))
         return self._call_program(fn, meta, gather_idx, staged,
                                   table_names, snapshot_ts, txid,
-                                  params)
+                                  params, str_tables)
+
+    def _str_tables(self, dp, included, staged) -> list:
+        """[(literal StrPred, its dictionary's list, bitmap words)] of the
+        included fragments, in plan order, one a predicate: those over a
+        scanned table's TEXT column whose verdicts are a bitmap."""
+        from . import strtable
+        # which predicates read which table's column is the plan's own:
+        # walked once a DistPlan (the plan cache hands the same one back)
+        memo = dp.__dict__.setdefault("_literal_strpreds", {})
+        preds = memo.get(tuple(sorted(included)))
+        if preds is None:
+            scans, found = {}, {}
+            for f in dp.fragments:
+                if f.index not in included:
+                    continue
+                for nd in self._walk(f.plan):
+                    if isinstance(nd, P.SeqScan):
+                        scans[nd.alias] = nd.table.name
+                for x in P.walk_exprs(f.plan):
+                    if isinstance(x, E.StrPred) and x.param is None:
+                        found[x] = strtable.column_of(x).partition(".")
+            # (a derived column's predicate stays the trace's constant)
+            preds = memo[tuple(sorted(included))] = [
+                (x, scans[alias], column)
+                for x, (alias, _dot, column) in found.items()
+                if alias in scans]
+        out = []
+        for x, table, column in preds:
+            d = staged[table].view.dicts.get(column)
+            words = strtable.resolve(x, d.values)[1] if d is not None \
+                else None
+            if words is not None:
+                out.append((x, d.values, words))
+        return out
 
     def _call_program(self, fn, meta, gather_idx, staged, table_names,
-                      snapshot_ts, txid, params):  # otblint: sync-boundary
+                      snapshot_ts, txid, params,
+                      str_tables):  # otblint: sync-boundary
         # the ONE sync of a mesh program call, after the call: the three
         # overflow vectors and every gathered array in one device_get
         from .executor import stats_tier
@@ -1178,6 +1223,9 @@ class MeshRunner:
             for k in meta.get("traced", ()):
                 v, t = params[k]
                 flat_args.append(np.asarray(v, dtype=dev_dtype(t)))
+            # the string predicates' bitmaps: host arrays of a few KB
+            # to ~190 KB (1.5 M codes), riding the same transfer
+            flat_args.extend(words for _p, _v, words in str_tables)
         for t in table_names:
             for n in sorted(staged[t].arrs):
                 flat_args.append(staged[t].arrs[n])

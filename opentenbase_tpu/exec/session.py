@@ -33,7 +33,7 @@ from ..sql.parser import parse_sql
 from ..storage.store import TableStore
 from ..storage.wal import Wal, checkpoint_store, restore_store
 from .executor import (DBatch, DeviceTableCache, ExecContext, ExecError,
-                       Executor, materialize)
+                       Executor, materialize, scalars_from_batch)
 
 
 @dataclasses.dataclass
@@ -398,9 +398,10 @@ def _trace_explain_lines() -> str:
                                       "h2d_puts", "h2d_bytes",
                                       "program_calls")))
     lines.append("Shape: " + " ".join(
-        f"{k}={summary[k]}" for k in ("semi_joins", "sorted_aggs",
-                                      "sorted_agg_lanes",
-                                      "sorted_agg_groups", "initplans")))
+        f"{k}={summary[k]}" for k in (
+            "semi_joins", "sorted_aggs", "sorted_agg_lanes",
+            "sorted_agg_groups", "initplans", "anti_joins", "outer_joins",
+            "residual_semi_lanes", "strpred_codes")))
     rounds = int(qt.sum_attr("exchange", "rounds"))
     if rounds:
         lines.append(
@@ -1135,10 +1136,8 @@ class Session:
                                t.txid, self.node.cache)
             ex0 = Executor(ctx0)
             for ip in planned.init_plans:
-                b0 = ex0.exec_node(ip.plan)
-                from .executor import scalar_from_batch
-                ctx0.params[ip.name] = (scalar_from_batch(b0),
-                                        ip.type)
+                ctx0.params.update(scalars_from_batch(
+                    ex0.exec_node(ip.plan), ip.outputs()))
             return dict(ctx0.params), PlannedStmt(
                 planned.plan, [], planned.output_names)
 

@@ -80,7 +80,15 @@ PHASES = ("plan", "stage", "execute", "exchange", "finalize")
 # every span name `summary()` sums into a `*_ms` key
 _SUMMED = PHASES + ("wire.recv", "wire.send", "parse", "autoprep", "bind",
                     "wait", "finalize.gather", "finalize.fetch",
-                    "finalize.decode", "gather", "inputs", "release")
+                    "finalize.decode", "gather", "inputs", "release",
+                    "initplan")
+# what the `execute` span of a compiled tier says of the program that
+# answered (`Executor.shape`, counted while it is traced): the ones that
+# add up over a program's fragments and a statement's programs, and the
+# ones that are a largest
+SHAPE_SUMS = ("semi_joins", "anti_joins", "outer_joins", "sorted_aggs")
+SHAPE_MAXIMA = ("residual_semi_lanes", "sorted_agg_lanes",
+                 "sorted_agg_groups", "strpred_codes")
 _BY_START = operator.attrgetter("t0_ms")
 
 
@@ -419,8 +427,8 @@ class QueryTrace:
         fetches = fetch_bytes = 0
         exchanges = exchange_bytes = 0
         traced = baked = retraces = 0
-        semi_joins = sorted_aggs = sorted_agg_lanes = initplans = 0
-        sorted_agg_groups = 0
+        shape = dict.fromkeys(SHAPE_SUMS + SHAPE_MAXIMA, 0)
+        initplans = 0
         hits = misses = 0
         d2h = d2h_bytes = h2d = h2d_bytes = calls = 0
         work = [(self.root, ())]
@@ -455,14 +463,10 @@ class QueryTrace:
                     if not a.get("retraces"):
                         # the program that answered, not one whose
                         # size class overflowed and was replayed
-                        semi_joins += a.get("semi_joins", 0) or 0
-                        sorted_aggs += a.get("sorted_aggs", 0) or 0
-                        sorted_agg_lanes = max(
-                            sorted_agg_lanes,
-                            a.get("sorted_agg_lanes", 0) or 0)
-                        sorted_agg_groups = max(
-                            sorted_agg_groups,
-                            a.get("sorted_agg_groups", 0) or 0)
+                        for k in SHAPE_SUMS:
+                            shape[k] += a.get(k, 0) or 0
+                        for k in SHAPE_MAXIMA:
+                            shape[k] = max(shape[k], a.get(k, 0) or 0)
                 elif name == "bind":
                     traced += a.get("traced", 0) or 0
                     baked += a.get("baked", 0) or 0
@@ -517,14 +521,17 @@ class QueryTrace:
         d["exchange_bytes"] = int(exchange_bytes)
         # the shape of the compiled programs that answered, fixed when
         # they were traced: joins answered by a mask (semi, anti: no
-        # expansion), sorted aggregates, the padded rows of the largest
-        # and the largest output class (group slots); and the scalar
-        # subqueries run before the statement
-        d["semi_joins"] = int(semi_joins)
-        d["sorted_aggs"] = int(sorted_aggs)
-        d["sorted_agg_lanes"] = int(sorted_agg_lanes)
-        d["sorted_agg_groups"] = int(sorted_agg_groups)
+        # expansion) and the anti ones among them, joins through the
+        # expansion's left-outer arm, the largest class a semi or anti
+        # join with a residual expands into (0: none does), sorted
+        # aggregates, the padded rows of the largest and the largest
+        # output class (group slots), the largest code set or bitmap a
+        # string predicate brings; and the scalar subqueries run before
+        # the statement, with their time
+        for k, v in shape.items():
+            d[k] = int(v)
         d["initplans"] = int(initplans)
+        d["initplan_ms"] = ms["initplan"]
         d["unattributed_ms"] = self.root.self_ms()
         # the host path around a program call: its inputs made ready
         # (staged arrays looked up; the fused tier's scalars put), the

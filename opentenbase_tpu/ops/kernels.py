@@ -43,9 +43,10 @@ def _scoped(scope: str):
     traces carries the scope in its metadata, so a device trace can say
     which kernel an XLA op came from, whatever number XLA gave it.  One
     flat vocabulary, shared with the program steps of exec/: otb.scan,
-    otb.agg, otb.join_build, otb.join_probe, otb.join_expand, otb.sort,
-    otb.exchange, otb.finalize.  A scope is metadata only: no op, no
-    cost at run time, no part of the persistent cache's key."""
+    otb.agg, otb.join_build, otb.join_probe, otb.join_expand,
+    otb.join_residual, otb.sort, otb.exchange, otb.finalize.  A scope is
+    metadata only: no op, no cost at run time, no part of the persistent
+    cache's key."""
     def deco(fn):
         @functools.wraps(fn)
         def scoped(*args, **kwargs):
@@ -481,6 +482,46 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
 # join: sort build side once, probe by row gathers, expand pairs
 # ---------------------------------------------------------------------------
 
+def _sorted_build(build_keys, build_valid, key_span, minor=None,
+                  minor_span=None):
+    """join_build's sort, with or without a minor column: (sorted keys,
+    perm, minor in that order | None, the minor's base)."""
+    n = build_keys.shape[0]
+    slots = (key_span + 2) * (n + 1) if key_span is not None else None
+    if minor is not None and slots is not None:
+        slots = slots * (minor_span + 1) if minor_span is not None else None
+    if slots is not None and slots < 1 << 62:
+        # a NULL key arrives as INT64_MAX (executor._join_key): outside
+        # the host's bound, and unmatchable like an invalid row
+        ok = build_valid & (build_keys != INT64_MAX)
+        mn = jnp.min(jnp.where(ok, build_keys, INT64_MAX))
+        iota = jnp.arange(n, dtype=jnp.int64)
+        rng = key_span + 1
+        acc = jnp.where(ok, jnp.clip(build_keys - mn, 0, rng - 1), rng)
+        if minor is not None:
+            mrng = minor_span + 1
+            base = jnp.min(jnp.where(ok, minor, INT64_MAX))
+            acc = acc * mrng + jnp.where(
+                ok, jnp.clip(minor - base, 0, mrng - 1), 0)
+        sw = jnp.sort(acc * n + iota)
+        perm = (sw % n).astype(jnp.int32)
+        acc_s = sw // n
+        sminor = None
+        if minor is not None:
+            # offsets from the base, in a word: the probe subtracts it
+            sminor = (acc_s % mrng).astype(jnp.int32)
+            acc_s = acc_s // mrng
+        skeys = jnp.where(acc_s >= rng, INT64_MAX, acc_s + mn)
+        return skeys, perm, sminor, (base if minor is not None else None)
+    keys = jnp.where(build_valid, build_keys, INT64_MAX)
+    if minor is None:
+        perm = jnp.argsort(keys).astype(jnp.int32)
+        return keys[perm], perm, None, None
+    perm = jax.lax.sort([keys, minor, jnp.arange(n, dtype=jnp.int32)],
+                        num_keys=2)[-1]
+    return keys[perm], perm, minor[perm], jnp.int64(0)
+
+
 @functools.partial(jax.jit, static_argnames=("key_span",))
 @_scoped("otb.join_build")
 def join_build(build_keys, build_valid, key_span: int | None = None):
@@ -502,22 +543,28 @@ def join_build(build_keys, build_valid, key_span: int | None = None):
     The choice used to be a `lax.cond` on the shard's own span: both
     sorts compiled into every program, and which one ran was the
     data's."""
-    n = build_keys.shape[0]
-    if key_span is not None and (key_span + 2) * (n + 1) < 1 << 62:
-        # a NULL key arrives as INT64_MAX (executor._join_key): outside
-        # the host's bound, and unmatchable like an invalid row
-        ok = build_valid & (build_keys != INT64_MAX)
-        mn = jnp.min(jnp.where(ok, build_keys, INT64_MAX))
-        iota = jnp.arange(n, dtype=jnp.int64)
-        rng = key_span + 1
-        acc = jnp.where(ok, jnp.clip(build_keys - mn, 0, rng - 1), rng)
-        sw = jnp.sort(acc * n + iota)
-        perm = (sw % n).astype(jnp.int32)
-        acc_s = sw // n
-        return jnp.where(acc_s >= rng, INT64_MAX, acc_s + mn), perm
-    keys = jnp.where(build_valid, build_keys, INT64_MAX)
-    perm = jnp.argsort(keys).astype(jnp.int32)
-    return keys[perm], perm
+    return _sorted_build(build_keys, build_valid, key_span)[:2]
+
+
+@functools.partial(jax.jit, static_argnames=("key_span", "minor_span"))
+@_scoped("otb.join_build")
+def join_build_minor(build_keys, build_valid, minor,
+                     key_span: int | None = None,
+                     minor_span: int | None = None):
+    """join_build with the rows of one key in the order of a second
+    column: (sorted keys, perm, the minor column in that order, its
+    base).  For a semi or anti join whose residual is `minor <> x`: a
+    probe row's matches are the slots [lo, lo + count) of ONE sort, the
+    smallest minor among them stands at lo and the largest at lo + count
+    - 1, and some match differs from x exactly when one of those two
+    does (`range_differs`).  The same single sort as join_build's where
+    the host's bounds on both columns (`key_span`, `minor_span`) let
+    (key, minor, position) pack into one int64: the minor comes back as
+    int32 offsets from `base`; else a two-key `lax.sort`, the minor as
+    it came and a base of 0.  A row whose minor is NULL is the caller's
+    to leave out of `build_valid`: `<>` with NULL is not true."""
+    return _sorted_build(build_keys, build_valid, key_span,
+                         minor.astype(jnp.int64), minor_span)
 
 
 #: words per row of join_expand's tables: a row of a [n / 128, 128] int32
@@ -848,6 +895,40 @@ def semi_mask(counts):
 @_scoped("otb.join_probe")
 def anti_mask(counts, probe_valid):
     return probe_valid & (counts == 0)
+
+
+@jax.jit
+@_scoped("otb.join_residual")
+def range_differs(lo, counts, sorted_minor, base, probe_minor, probe_ok):
+    """Per probe row: does its match range [lo, lo + count) of a build
+    side sorted by (key, minor) (`join_build_minor`) hold a row whose
+    minor differs from the probe row's own?  EXISTS (... and b.c <> a.c)
+    as a mask: the range's smallest minor stands first and its largest
+    last, and all of them equal x exactly when both do.  Two gathers a
+    probe row and a compare, where the expanded form pays a pair a match
+    (`join_expand`, the residual over the pairs, a scatter-add back).
+    `probe_ok` is the probe row's validity and its minor's not being
+    NULL.  The minor as int32 offsets is read by row gathers (`_take`),
+    at most _MAX_LANES probe rows a pass."""
+    nb = sorted_minor.shape[0]
+    if not nb or not lo.shape[0]:
+        return jnp.zeros(lo.shape[0], bool)
+    x = probe_minor.astype(jnp.int64) - base
+    first = jnp.clip(lo, 0, nb - 1)
+    last = jnp.clip(lo + counts - 1, 0, nb - 1)
+    if sorted_minor.dtype == jnp.int32:
+        rows = _rows_of(sorted_minor, 0)
+
+        def ends(a, b):
+            a = _take(rows, a)
+            # one gather's rows in memory at a time
+            a, b = jax.lax.optimization_barrier((a, b))
+            return a, _take(rows, b)
+        smallest, largest = _in_passes(ends, (first, last), _MAX_LANES)
+    else:
+        smallest, largest = sorted_minor[first], sorted_minor[last]
+    return probe_ok & (counts > 0) & ((smallest.astype(jnp.int64) != x)
+                                      | (largest.astype(jnp.int64) != x))
 
 
 # ---------------------------------------------------------------------------

@@ -215,7 +215,15 @@ STAT_TABLES = {
         ColumnDef("release_ms", T.FLOAT64),
         ColumnDef("host_syncs", T.INT64), ColumnDef("d2h_bytes", T.INT64),
         ColumnDef("h2d_puts", T.INT64), ColumnDef("h2d_bytes", T.INT64),
-        ColumnDef("program_calls", T.INT64)],
+        ColumnDef("program_calls", T.INT64),
+        # more of the programs' shape: the anti joins among the masks,
+        # left-outer expansions, the largest class a residual semi or
+        # anti join expands into, the largest string-predicate code set
+        # or bitmap; the scalar subqueries' time
+        ColumnDef("anti_joins", T.INT64), ColumnDef("outer_joins", T.INT64),
+        ColumnDef("residual_semi_lanes", T.INT64),
+        ColumnDef("strpred_codes", T.INT64),
+        ColumnDef("initplan_ms", T.FLOAT64)],
     # per-node guard health (net/guard.py): breaker state + failure
     # accounting for every RPC peer this coordinator talks to
     # (reference: pgxc_node health columns fed by clustermon pings;
@@ -359,7 +367,9 @@ def refresh(cluster, names: list[str]):
                     s["unattributed_ms"], s["inputs_ms"],
                     s["gather_ms"], s["release_ms"], s["host_syncs"],
                     s["d2h_bytes"], s["h2d_puts"], s["h2d_bytes"],
-                    s["program_calls"]))
+                    s["program_calls"], s["anti_joins"], s["outer_joins"],
+                    s["residual_semi_lanes"], s["strpred_codes"],
+                    s["initplan_ms"]))
         elif name == "otb_node_health":
             from ..net.guard import health_rows
             rows = list(health_rows())

@@ -242,6 +242,25 @@ class StrPred(Expr):
         return (self.col,)
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class CodeBitmap(Expr):
+    """A literal StrPred whose verdicts a dictionary value arrive as a
+    bitmap over the column's codes (exec/strtable.py), bound by a compiled
+    tier as a program ARGUMENT: `words` is the traced int32 array, 32
+    codes a word, `negated` the predicate's `ne` / `not_like` /
+    `not_in`.  Made by the executor just before compiling and never part
+    of a plan or a key."""
+    col: Expr                 # Col or TextExpr over a TEXT column
+    words: object
+    negated: bool
+
+    def __post_init__(self):
+        object.__setattr__(self, "type", BOOL)
+
+    def children(self):
+        return (self.col,)
+
+
 @dataclasses.dataclass(frozen=True)
 class IsNull(Expr):
     """expr IS [NOT] NULL — non-strict: consumes the null mask, never

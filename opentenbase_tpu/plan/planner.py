@@ -42,9 +42,17 @@ class PlanError(Exception):
 
 @dataclasses.dataclass
 class InitPlan:
+    """A scalar subquery run before the statement: the first column of
+    its one row is the parameter `name`; `more` names the row's further
+    columns, in order ([(name, type)]: the SUM and COUNT behind an exact
+    AVG come from ONE run)."""
     name: str
     plan: P.PhysNode
     type: T.SqlType
+    more: list = dataclasses.field(default_factory=list)
+
+    def outputs(self) -> list:
+        return [(self.name, self.type)] + list(self.more)
 
 
 @dataclasses.dataclass
@@ -651,6 +659,20 @@ class Planner:
                     if step.kind == "full" and res:
                         raise PlanError("FULL JOIN supports only "
                                         "equi-key ON conditions")
+                    # an ON conjunct of a LEFT join that names the right
+                    # side's columns alone decides which right rows can
+                    # match at all: it filters the right input (a left
+                    # row none of whose matches pass it is null-extended
+                    # either way), and the join judges no pair by it
+                    own = [q for q in res
+                           if (cols := expr_cols(q))
+                           and cols <= rte_cols[cand]]
+                    if own:
+                        res = [q for q in res if q not in own]
+                        right = dataclasses.replace(
+                            right, filters=list(right.filters) + own) \
+                            if isinstance(right, P.SeqScan) \
+                            else P.Filter(right, own)
                     plan = P.HashJoin(plan, right, lk, rk, step.kind,
                                       res)
                 else:
@@ -719,13 +741,15 @@ class Planner:
             return isinstance(x, SubLink) and x.link_kind == "scalar"
 
         def exact_avg_cmp(x: E.Cmp):
-            """`x OP (select [k *] avg(y) ... correlated)` over exact x and
-            y: the derived table carries sum(y) and count(y), and the
-            comparison is `x * count OP k * sum` in integers."""
+            """`x OP (select [k *] avg(y) ...)` over exact x and y: the
+            subquery gives sum(y) and count(y) (a correlated one as a
+            derived table's columns, an uncorrelated one as ONE init
+            plan's two values), and the comparison is `x * count OP k *
+            sum` in integers.  AVG over no row is NULL: so is the SUM,
+            and the comparison with it."""
             for sub, other, sub_left in ((x.right, x.left, False),
                                         (x.left, x.right, True)):
-                if not (is_scalar(sub) and sub.query.correlated_cols
-                        and other.type.kind in _EXACT
+                if not (is_scalar(sub) and other.type.kind in _EXACT
                         and not any(isinstance(y, SubLink)
                                     for y in E.walk(other))):
                     continue
@@ -733,9 +757,24 @@ class Planner:
                 if form is None:
                     continue
                 k, avg = form
-                qsum, n = self._decorrelate_scalar(
-                    sub, bq, init_plans, [E.AggCall("sum", avg.arg),
-                                          E.AggCall("count", avg.arg)])
+                values = [E.AggCall("sum", avg.arg),
+                          E.AggCall("count", avg.arg)]
+                if sub.query.correlated_cols:
+                    qsum, n = self._decorrelate_scalar(
+                        sub, bq, init_plans, values)
+                elif sub.query.group_by or sub.query.having:
+                    continue
+                else:
+                    vals = [(f"__val{i}", v) for i, v in enumerate(values)]
+                    names = [f"__initplan{next(self._ip_counter)}"
+                             for _ in vals]
+                    plan = self._plan_query(dataclasses.replace(
+                        sub.query, targets=vals, order_by=[]), init_plans)
+                    init_plans.append(InitPlan(
+                        names[0], plan, values[0].type,
+                        [(names[1], values[1].type)]))
+                    qsum, n = (E.Col(nm, v.type)
+                               for nm, v in zip(names, values))
                 mine = E.Arith("*", other, n)
                 theirs = qsum if k is None else E.Arith("*", k, qsum)
                 return E.Cmp(x.op, theirs, mine) if sub_left \

@@ -104,8 +104,9 @@ def test_the_groups_found_are_reported_beside_the_joins_totals():
                                        (299,), jid)
     assert (cls, jid, len(ex.join_required)) == (320, None, 1)
     assert int(ng) == 300 and int(counts[:300].min()) == 3
-    assert ex.shape == {"semi_joins": 0, "sorted_aggs": 3,
-                        "sorted_agg_lanes": 1_024, "sorted_agg_groups": 320}
+    assert {k: v for k, v in ex.shape.items() if v} == {
+        "sorted_aggs": 3, "sorted_agg_lanes": 1_024,
+        "sorted_agg_groups": 320}
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,8 @@ from opentenbase_tpu.ops import kernels as K
 from opentenbase_tpu.parallel.cluster import Cluster
 
 VOCABULARY = {"otb.scan", "otb.agg", "otb.join_build", "otb.join_probe",
-              "otb.join_expand", "otb.sort", "otb.exchange", "otb.finalize"}
+              "otb.join_expand", "otb.join_residual", "otb.sort",
+              "otb.exchange", "otb.finalize"}
 
 N = 64
 I = jnp.arange(N, dtype=jnp.int64)
@@ -52,6 +53,8 @@ KERNELS = [
      lambda: _lowered(K.grouped_agg_sort, (I % 4,), B, (I,), max_groups=N,
                       agg_kinds=("sum",))),
     ("join_build", "otb.join_build", lambda: _lowered(K.join_build, I, B)),
+    ("join_build_minor", "otb.join_build",
+     lambda: _lowered(K.join_build_minor, I, B, I % 4)),
     ("join_probe_counts", "otb.join_probe",
      lambda: _lowered(K.join_probe_counts, I, I, B)),
     ("lane_rows", "otb.join_expand",
@@ -62,6 +65,10 @@ KERNELS = [
      lambda: _lowered(K.compose_index, I, I)),
     ("semi_mask", "otb.join_probe", lambda: _lowered(K.semi_mask, I)),
     ("anti_mask", "otb.join_probe", lambda: _lowered(K.anti_mask, I, B)),
+    ("range_differs", "otb.join_residual",
+     lambda: _lowered(K.range_differs, I.astype(jnp.int32),
+                      (I % 2).astype(jnp.int32), I.astype(jnp.int32), I[0],
+                      I, B)),
     ("sort_rows", "otb.sort",
      lambda: _lowered(K.sort_rows, (I,), B, (I,), descs=(True,), limit=8)),
     ("bucket_ids", "otb.exchange",

@@ -27,7 +27,8 @@ from opentenbase_tpu.tpch.queries import Q
 SF = 0.01
 LIMITS = files.load_json("lib", "limits.json")
 SHAPE_KEYS = ("semi_joins", "sorted_aggs", "sorted_agg_lanes",
-              "sorted_agg_groups", "initplans")
+              "sorted_agg_groups", "initplans", "anti_joins", "outer_joins",
+              "residual_semi_lanes", "strpred_codes")
 # the spec's validation values (tpch/queries.py holds them as literals)
 VALIDATION = {"q4": {"date": "1993-07-01"},
               "q17": {"brand": "Brand#23", "container": "MED BOX"},
@@ -136,18 +137,26 @@ def test_an_uncorrelated_scalar_subquery_is_an_initplan(served):
     assert set(SHAPE_KEYS) <= set(stats)
 
 
-def test_explain_analyze_shows_the_shape(served):
+@pytest.mark.parametrize("qname, sql, more", [
+    ("q18", Q[18].replace("> 300", "> 250"), {}),
+    # the anti join among the masks; none expands, no string set
+    ("q4", Q[4].replace("and exists", "and not exists"),
+     {"anti_joins": 1})])
+def test_explain_analyze_shows_the_shape(served, qname, sql, more):
     ndn, _seed, _data, client, _session, _shared = served
-    text = "\n".join(r[0] for r in client.query(
-        "explain analyze " + Q[18].replace("> 300", "> 250")))
+    text = "\n".join(r[0] for r in client.query("explain analyze " + sql))
     line = next(ln for ln in text.splitlines() if ln.startswith("Shape: "))
     assert line.split()[1:3] == [
-        "semi_joins=1", f"sorted_aggs={SHAPES['q18', ndn][1]}"], text
+        "semi_joins=1", f"sorted_aggs={SHAPES[qname, ndn][1]}"], text
     assert "initplans=0" in line
     shape = dict(f.split("=") for f in line.split()[1:])
     assert set(SHAPE_KEYS) == set(shape)
     # an instrumented run is eager: its classes follow the counted rows
-    assert 0 < int(shape["sorted_agg_groups"])
+    assert (0 < int(shape["sorted_agg_groups"])) == (
+        SHAPES[qname, ndn][1] > 0)
+    want = dict({"anti_joins": 0, "outer_joins": 0,
+                 "residual_semi_lanes": 0, "strpred_codes": 0}, **more)
+    assert {k: int(shape[k]) for k in want} == want
 
 
 COMPILED_AND_EAGER = {

@@ -115,6 +115,30 @@ def test_join_build(one_chip):
     _compile(K.join_build, s(SMALL, I64), s(SMALL, BOOL))
 
 
+def test_join_build_minor(one_chip):
+    """Q21's build side: (order, supplier, position) in one sorted word."""
+    s = one_chip
+    text = _compile(jax.jit(lambda k, v, c: K.join_build_minor(
+        k, v, c, key_span=4 * SMALL, minor_span=10_000)),
+        s(SMALL, I64), s(SMALL, BOOL), s(SMALL, I64))
+    assert text.count(" sort(") == 1
+
+
+def test_range_differs_at_lineitems_class(one_chip):
+    """Sort-free, so it goes to Q21's real class: 6,291,456 probe rows
+    against 6,291,456 build rows, in passes whose gathered rows stay
+    under 2 GiB; no `while`, no scatter, no 64-bit gather."""
+    s = one_chip
+    compiled = jax.jit(K.range_differs).lower(
+        s(BIG * 3 // 4, I32), s(BIG * 3 // 4, I32), s(BIG * 3 // 4, I32),
+        s(0, I64), s(BIG * 3 // 4, I32), s(BIG * 3 // 4, BOOL)).compile()
+    text = compiled.as_text()
+    assert " while(" not in text and " scatter(" not in text
+    assert "s64[" not in "".join(
+        ln for ln in text.splitlines() if " gather(" in ln)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
 @pytest.mark.parametrize("nb, np_, span", [
     (SMALL, 4 * SMALL, None), (SMALL, 4 * SMALL, SMALL // 2),
     # the cells' joins at SF1: lineitem into orders on one chip; a
