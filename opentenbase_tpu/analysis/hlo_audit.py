@@ -218,6 +218,21 @@ def check_kernels(report: dict):
             (i32, i32, i32, i, v), f"range_differs/{n}", report,
             no_scatter_sort=True, no_conditional=True,
             no_wide_gather=True, no_loop=True)
+        # the exchange's pack: a destination's slot finds its source row
+        # by rows of 32-bit pivots (four destinations, one level of row
+        # gathers at the larger class) and the columns, null masks and
+        # floats among them, come through that index as ONE gather of
+        # 32-bit rows: no scatter, no sort, no loop, no conditional, no
+        # 64-bit gather
+        export_check(
+            lambda d: K.bucket_rows(d, ndn=4, bucket=n // 4), (i32,),
+            f"bucket_rows/{n}", report, no_scatter_sort=True,
+            no_conditional=True, no_wide_gather=True, no_loop=True)
+        export_check(
+            lambda a, b, c, m, at, ok: K.take_rows((a, b, c, m), at, ok),
+            (i, i32, f, v, i32, v), f"take_rows/{n}", report,
+            no_scatter_sort=True, no_conditional=True,
+            no_wide_gather=True, no_loop=True)
         export_check(K.semi_mask, (i,), f"semi_mask/{n}", report)
         export_check(lambda c, pv: K.anti_mask(c, pv), (i, v),
                      f"anti_mask/{n}", report)

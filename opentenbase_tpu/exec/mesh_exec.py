@@ -609,10 +609,15 @@ class MeshRunner:
         where `mult` is this exchange's ladder value — 1 assumes a
         uniform spread, overflow doubles it, and `next_pow2(src_pad)`
         is an absolute cap at which overflow is impossible (a source
-        shard cannot send more rows than it has).  Packing computes
-        each row's slot with one cumsum per destination — no argsort —
-        and the scatter drops dead rows, so the exchange also compacts."""
+        shard cannot send more rows than it has).  The pack is a gather:
+        every slot of a destination's bucket finds its source row
+        (`kernels.bucket_rows`: a search of one running count per
+        destination — no argsort, no scatter) and the batch's columns
+        and null masks come through that index as ONE gather of 32-bit
+        rows (`kernels.take_rows`).  Dead rows are bound nowhere, so the
+        exchange also compacts."""
         from .executor import DBatch
+        from ..ops import kernels as K
         ndn = self.cluster.ndn
         if ndn == 1:
             # single-node mesh: routing is the identity; no collective —
@@ -628,38 +633,18 @@ class MeshRunner:
         smap = jnp.asarray(
             np.asarray(self.cluster.catalog.shard_map, np.int32))
         dest = jnp.where(b.valid, smap[sid].astype(jnp.int32), ndn)
+        src, keep, overflow = K.bucket_rows(dest, ndn, bucket)
+        moved = iter(K.take_rows(
+            (*b.cols.values(), *b.nulls.values()), src, keep))
 
-        # slot = rank of this row among live rows bound for the same
-        # destination (ndn cumsums, each a cheap scan)
-        slot = jnp.zeros(src_pad, jnp.int32)
-        for d in range(ndn):
-            m = dest == d
-            slot = jnp.where(m, jnp.cumsum(m.astype(jnp.int32)) - 1,
-                             slot)
-        live = dest < ndn
-        keep = (slot < bucket) & live
-        overflow = jnp.sum((slot >= bucket) & live)
-        oob = ndn * bucket
-        # dropped rows get distinct out-of-range indices so the scatter
-        # stays unique-indexed (mode="drop" discards them)
-        pack_idx = jnp.where(keep, dest * bucket + slot,
-                             oob + jnp.arange(src_pad, dtype=jnp.int32))
-
-        def a2a(arr):
-            buf = jnp.zeros((oob, *arr.shape[1:]), arr.dtype)
-            buf = buf.at[pack_idx].set(arr, mode="drop",
-                                       unique_indices=True)
+        def a2a(buf):
             return jax.lax.all_to_all(
-                buf.reshape(ndn, bucket, *arr.shape[1:]),
-                self.axis, 0, 0).reshape(oob, *arr.shape[1:])
+                buf.reshape(ndn, bucket, *buf.shape[1:]),
+                self.axis, 0, 0).reshape(buf.shape)
 
-        cols = {n: a2a(a) for n, a in b.cols.items()}
-        nulls = {n: a2a(a) for n, a in b.nulls.items()}
-        mask = jnp.zeros(oob, jnp.bool_).at[pack_idx].set(
-            keep, mode="drop", unique_indices=True)
-        new_valid = jax.lax.all_to_all(
-            mask.reshape(ndn, bucket), self.axis, 0, 0).reshape(-1)
-        return (DBatch(cols, new_valid, dict(b.types), dict(b.dicts),
+        cols = {n: a2a(next(moved)) for n in b.cols}
+        nulls = {n: a2a(next(moved)) for n in b.nulls}
+        return (DBatch(cols, a2a(keep), dict(b.types), dict(b.dicts),
                        nulls, spans=dict(b.spans)),
                 jax.lax.psum(overflow, self.axis))
 
@@ -1081,6 +1066,7 @@ class MeshRunner:
             overflows = []
             meta["ex_order"] = []
             meta["exchanges"] = meta["exchange_bytes"] = 0
+            meta["pack_lanes"] = 0
             shape = meta["shape"] = {}
             join_reqs = []
             gather_out: dict = {}
@@ -1110,6 +1096,8 @@ class MeshRunner:
                         if rb is not b:
                             meta["exchanges"] += 1
                             meta["exchange_bytes"] += self._a2a_sent_bytes(rb)
+                            # the pack's destination slots: ndn * bucket
+                            meta["pack_lanes"] += int(rb.valid.shape[0])
                         overflows.append(over)
                     elif ex.kind == "broadcast":
                         with jax.named_scope("otb.exchange"):
@@ -1244,6 +1232,7 @@ class MeshRunner:
             # (meta is filled by the trace the first call made)
             sp.set(exchanges=meta.get("exchanges", 0),
                    exchange_bytes=meta.get("exchange_bytes", 0),
+                   pack_lanes=meta.get("pack_lanes", 0),
                    **meta.get("shape", {}))
             plancache.MESH.record_call(fn, t0)
             if EXPORT_HOOK is not None:

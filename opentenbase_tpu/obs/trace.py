@@ -425,7 +425,7 @@ class QueryTrace:
         ms = dict.fromkeys(_SUMMED, 0.0)
         staged = materialized = overlapped = 0.0
         fetches = fetch_bytes = 0
-        exchanges = exchange_bytes = 0
+        exchanges = exchange_bytes = pack_lanes = 0
         traced = baked = retraces = 0
         shape = dict.fromkeys(SHAPE_SUMS + SHAPE_MAXIMA, 0)
         initplans = 0
@@ -459,6 +459,7 @@ class QueryTrace:
                 elif name == "execute":
                     exchanges += a.get("exchanges", 0) or 0
                     exchange_bytes += a.get("exchange_bytes", 0) or 0
+                    pack_lanes += a.get("pack_lanes", 0) or 0
                     retraces += a.get("retraces", 0) or 0
                     if not a.get("retraces"):
                         # the program that answered, not one whose
@@ -519,6 +520,10 @@ class QueryTrace:
         d["finalize_fetch_bytes"] = int(fetch_bytes)
         d["exchanges"] = int(exchanges)
         d["exchange_bytes"] = int(exchange_bytes)
+        # the destination slots those exchanges' packs search and fetch
+        # (`kernels.bucket_rows`: ndn * bucket a redistribute), fixed
+        # when the program was traced like the two above
+        d["pack_lanes"] = int(pack_lanes)
         # the shape of the compiled programs that answered, fixed when
         # they were traced: joins answered by a mask (semi, anti: no
         # expansion) and the anti ones among them, joins through the

@@ -29,6 +29,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.custom_dce import custom_dce
 
 from ..utils.dtypes import device_float
 
@@ -977,3 +978,141 @@ def bucket_ids(key_cols: tuple, num_buckets: int):
     from ..utils.hashing import hash_columns_jax
     h = hash_columns_jax(list(key_cols))
     return (h % jnp.uint64(num_buckets)).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("ndn", "bucket"))
+@_scoped("otb.exchange")
+def bucket_rows(dest, ndn: int, bucket: int):
+    """The exchange's pack, seen from where the rows arrive: (src, keep,
+    overflow) for `ndn` buckets of `bucket` slots each.  `dest[n]` is a
+    row's destination, int32, `ndn` for a row that goes nowhere (dead).
+    Slot s of destination d takes the s-th row bound for d IN SOURCE
+    ORDER, `src[d * bucket + s]` (int32); `keep` says which slots a row
+    fills (`s < min(count_d, bucket)`), `overflow` how many rows found
+    no slot (`sum(max(count_d - bucket, 0))`, int64: the size ladder
+    compares it with 0).  An unfilled slot points at some row in range.
+
+    A gather formulation: every DESTINATION slot finds its row, by
+    `_lane_search` of the running count of `dest == d` (int32 words, rows
+    of 128 pivots, `ndn` searches of `bucket` lanes), where every SOURCE
+    row used to compute its slot and be scattered there, a column at a
+    time (62-166 ms alone for 4 to 8 arrays of 393,216 rows on a v5e,
+    this pack 5.5-6.6 with `take_rows`: PERF.md section 6, PR 41).  No
+    scatter, no sort, no `while`, no 64-bit gather."""
+    n = dest.shape[0]
+    _check_word("bucket_rows", ndn * bucket, n)
+    if not n:
+        return (jnp.zeros(ndn * bucket, jnp.int32),
+                jnp.zeros(ndn * bucket, bool), jnp.int64(0))
+    slot = jnp.arange(bucket, dtype=jnp.int32)
+    src, keep, overflow = [], [], jnp.int64(0)
+    for d in range(ndn):
+        csum = jnp.cumsum(dest == d, dtype=jnp.int32)
+        # a level's gathered rows are [lanes, _ROW] words in HBM
+        src.append(_in_passes(_lane_search(csum, bucket), (slot,),
+                              _MAX_LANES))
+        count = csum[-1]
+        keep.append(slot < count)
+        overflow += jnp.maximum(count - bucket, 0)
+    return jnp.concatenate(src), jnp.concatenate(keep), overflow
+
+
+#: the signed integer of a float's width: its bits, by bitcast
+_BITS = {2: jnp.int16, 4: jnp.int32, 8: jnp.int64}
+
+
+def _words_of(a):
+    """The bits of `a[n, ...]`, any fixed-width dtype, as [n] int32
+    planes: a 64-bit element is two (high, low), a narrower one widens.
+    Floats by `bitcast_convert_type`, never arithmetic: -0.0 and a NaN's
+    payload come back as they went (`_of_words`)."""
+    a = a.reshape(a.shape[0], -1)
+    if jnp.issubdtype(a.dtype, jnp.floating):
+        a = jax.lax.bitcast_convert_type(a, _BITS[a.dtype.itemsize])
+    if a.dtype.itemsize == 8:
+        u = a.astype(jnp.uint64)
+        halves = ((u >> 32).astype(jnp.uint32),
+                  (u & 0xFFFFFFFF).astype(jnp.uint32))
+        a = jnp.stack([jax.lax.bitcast_convert_type(h, jnp.int32)
+                       for h in halves], axis=2).reshape(a.shape[0], -1)
+    elif a.dtype == jnp.uint32:
+        a = jax.lax.bitcast_convert_type(a, jnp.int32)
+    else:
+        a = a.astype(jnp.int32)
+    return [a[:, i] for i in range(a.shape[1])]
+
+
+def _of_words(planes, like):
+    """`_words_of` undone: the [m] int32 planes of one array back in the
+    dtype and trailing shape of `like`."""
+    dt = like.dtype
+    bits = _BITS[dt.itemsize] if jnp.issubdtype(dt, jnp.floating) else dt
+    if dt.itemsize == 8:
+        high, low = (jax.lax.bitcast_convert_type(
+            jnp.stack(half, axis=1), jnp.uint32).astype(jnp.uint64)
+            for half in (planes[0::2], planes[1::2]))
+        w = ((high << 32) | low).astype(bits)
+    elif bits == jnp.uint32:
+        w = jax.lax.bitcast_convert_type(jnp.stack(planes, axis=1),
+                                         jnp.uint32)
+    else:
+        w = jnp.stack(planes, axis=1).astype(bits)
+    if bits != dt:
+        w = jax.lax.bitcast_convert_type(w, dt)
+    return w.reshape(w.shape[0], *like.shape[1:])
+
+
+def _take_rows(arrays: tuple, idx, keep):
+    if not arrays:
+        return ()
+    if not arrays[0].shape[0]:
+        return tuple(jnp.zeros((idx.shape[0], *a.shape[1:]), a.dtype)
+                     for a in arrays)
+    words = [_words_of(a) for a in arrays]
+    flat = [p for planes in words for p in planes]
+
+    def rows(i, k):
+        out = []
+        for at in range(0, len(flat), _ROW):
+            part = flat[at:at + _ROW]
+            matrix = jnp.pad(jnp.stack(part, axis=1),
+                             ((0, 0), (0, _ROW - len(part))))
+            got = jnp.where(k[:, None], matrix[i], 0)
+            out.extend(got[:, w] for w in range(len(part)))
+        return tuple(out)
+    got = iter(_in_passes(rows, (idx, keep), _MAX_LANES))
+    return tuple(_of_words([next(got) for _ in planes], a)
+                 for planes, a in zip(words, arrays))
+
+
+_take_live_rows = custom_dce(_take_rows)
+
+
+@_take_live_rows.def_dce
+def _take_rows_read(used: tuple, arrays: tuple, idx, keep):
+    read = iter(_take_rows(tuple(a for a, u in zip(arrays, used) if u),
+                           idx, keep))
+    return tuple(next(read) if u else None for u in used)
+
+
+@jax.jit
+@_scoped("otb.exchange")
+def take_rows(arrays: tuple, idx, keep):
+    """`where(keep, a[idx], 0)` for every array of `arrays` (columns and
+    null masks of one batch, [n, ...] of any dtype; idx int32 in range),
+    bit for bit, through ONE gather of 32-bit rows: the arrays' words
+    stand side by side in a [n, _ROW] int32 matrix (`_words_of`; a second
+    matrix past _ROW words), `matrix[idx]` fetches a lane's whole row,
+    and each array is cut out of the gathered rows and put back together
+    in its own dtype.  A scalar gather an array costs the chip three
+    times a row gather a lane, six from an int64 table (PERF.md section
+    6, PR 30), and an exchange moves 6 to 12 words a row.  At most
+    _MAX_LANES lanes a pass, as join_expand.
+
+    An array whose taken rows nothing reads stays out of the matrix, and
+    what made it is dead code with it, as if it had a gather of its own:
+    jax removes an equation none of whose results is read, and one
+    matrix of every array would keep them all (`custom_dce`: the rule
+    builds the matrix of the arrays that ARE read).  An exchange hands
+    on its batch's every column, the plan's consumers read a few."""
+    return _take_live_rows(arrays, idx, keep)

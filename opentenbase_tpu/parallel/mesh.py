@@ -18,7 +18,10 @@ join/aggregate pipelines.
 
 Static-shape contract: all_to_all needs equal-sized buckets, so each
 source packs at most `bucket` rows per destination per step (the FnPage
-analog: fixed-size pages, HUGE tuples span pages).  `redistribute`
+analog: fixed-size pages, HUGE tuples span pages).  The pack is a gather
+(ops/kernels.bucket_rows + take_rows: a bucket's slot s takes the s-th
+local row bound for that destination, the columns move as 32-bit rows; no
+argsort, no scatter).  `redistribute`
 returns an overflow count so callers size buckets (power-of-two growth,
 like the executor's batch size classes) and re-run if rows would drop.
 """
@@ -35,6 +38,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops import kernels as K
 from ..utils.hashing import splitmix64_jax
 
 
@@ -66,28 +70,14 @@ def shard_columns(mesh: Mesh, cols: dict, nrows: int):
 
 
 def _pack_for_a2a(key_hash, arrs, valid, n_dev: int, bucket: int):
-    """Inside shard_map: place each local row into its destination's
-    fixed-size bucket; count overflow."""
-    dest = (key_hash % jnp.uint64(n_dev)).astype(jnp.int32)
-    order = jnp.argsort(jnp.where(valid, dest, n_dev))
-    dst_s = jnp.where(valid, dest, n_dev)[order]
-    start = jnp.searchsorted(dst_s, jnp.arange(n_dev, dtype=jnp.int32))
-    slot = jnp.arange(dst_s.shape[0]) - start[jnp.clip(dst_s, 0,
-                                                       n_dev - 1)]
-    keep = (slot < bucket) & (dst_s < n_dev)
-    overflow = jnp.sum((slot >= bucket) & (dst_s < n_dev))
-    pack_idx = jnp.clip(dst_s, 0, n_dev - 1) * bucket + \
-        jnp.clip(slot, 0, bucket - 1)
-    packed = []
-    for a in arrs:
-        a_s = a[order]
-        shape = (n_dev * bucket, *a.shape[1:])
-        buf = jnp.zeros(shape, a.dtype).at[pack_idx].set(
-            jnp.where(keep.reshape(keep.shape[0],
-                                   *([1] * (a.ndim - 1))), a_s, 0))
-        packed.append(buf)
-    mask = jnp.zeros(n_dev * bucket, jnp.bool_).at[pack_idx].set(keep)
-    return packed, mask, overflow
+    """Inside shard_map: each slot of a destination's fixed-size bucket
+    finds its local row (`kernels.bucket_rows`), the columns come through
+    that index in one row gather (`kernels.take_rows`); count overflow.
+    The same pack as `exec/mesh_exec._a2a_batch`'s."""
+    dest = jnp.where(valid, (key_hash % jnp.uint64(n_dev)).astype(jnp.int32),
+                     n_dev)
+    src, keep, overflow = K.bucket_rows(dest, n_dev, bucket)
+    return K.take_rows(tuple(arrs), src, keep), keep, overflow
 
 
 def redistribute_program(mesh: Mesh, names: list, key_col: str,

@@ -223,7 +223,9 @@ STAT_TABLES = {
         ColumnDef("anti_joins", T.INT64), ColumnDef("outer_joins", T.INT64),
         ColumnDef("residual_semi_lanes", T.INT64),
         ColumnDef("strpred_codes", T.INT64),
-        ColumnDef("initplan_ms", T.FLOAT64)],
+        ColumnDef("initplan_ms", T.FLOAT64),
+        # the destination slots of the mesh programs' exchange packs
+        ColumnDef("pack_lanes", T.INT64)],
     # per-node guard health (net/guard.py): breaker state + failure
     # accounting for every RPC peer this coordinator talks to
     # (reference: pgxc_node health columns fed by clustermon pings;
@@ -369,7 +371,7 @@ def refresh(cluster, names: list[str]):
                     s["d2h_bytes"], s["h2d_puts"], s["h2d_bytes"],
                     s["program_calls"], s["anti_joins"], s["outer_joins"],
                     s["residual_semi_lanes"], s["strpred_codes"],
-                    s["initplan_ms"]))
+                    s["initplan_ms"], s["pack_lanes"]))
         elif name == "otb_node_health":
             from ..net.guard import health_rows
             rows = list(health_rows())

@@ -91,13 +91,16 @@ def test_exchange_counted_and_repeats(served):
         st = req.steps[0][5]
         assert st["tier"] == "mesh"
         by.setdefault(req.stmt.name, []).append(
-            (st["exchanges"], st["exchange_bytes"]))
+            (st["exchanges"], st["exchange_bytes"], st["pack_lanes"]))
     for name, seen in by.items():
         assert len(set(seen)) == 1, (name, seen)
-        exchanges, sent = seen[0]
+        exchanges, sent, lanes = seen[0]
         assert exchanges >= 1 and sent > 0
+        # every redistribute's pack has ndn buckets of at least 64 slots
+        assert lanes >= exchanges * 4 * 64 and lanes % (4 * 64) == 0
     # Q5 redistributes once more than Q3 (lineitem's rows to the suppliers)
     assert by["q5"][0][0] > by["q3_pinned"][0][0]
+    assert by["q5"][0][2] > by["q3_pinned"][0][2]
 
 
 def test_no_join_algorithm_is_chosen_by_the_data(served):
@@ -119,3 +122,22 @@ def test_no_join_algorithm_is_chosen_by_the_data(served):
     assert len(mesh_check.PROGRAMS) >= 2
     assert any("otb.agg" in c for c in conditionals), conditionals
     assert [c for c in conditionals if "otb.join_" in c] == []
+
+
+def test_the_pack_is_a_gather_and_the_collectives_stand(served):
+    """The compiled mesh programs hold NO scatter under `otb.exchange`:
+    a destination's slot finds its source row (ops/kernels.bucket_rows)
+    and the columns come through that index as 32-bit rows (take_rows);
+    the scatter a column, a null mask and the validity that packed the
+    buckets before cost a v5e ~30 ms an exchange.  The collectives are
+    what they were: one all-to-all a column, null mask and the validity,
+    9 in Q3's program (two redistributes) and 14 in Q5's (four)."""
+    scatters, a2a = [], []
+    for fn, shapes in mesh_check.PROGRAMS.values():
+        lines = fn.lower(*shapes).compile().as_text().splitlines()
+        scatters += [ln.split('op_name="', 1)[1].split('"', 1)[0]
+                     for ln in lines
+                     if " scatter(" in ln and "op_name=" in ln]
+        a2a.append(sum(" all-to-all(" in ln for ln in lines))
+    assert [s for s in scatters if "otb.exchange" in s] == []
+    assert sorted(a2a) == [9, 14]

@@ -73,6 +73,11 @@ KERNELS = [
      lambda: _lowered(K.sort_rows, (I,), B, (I,), descs=(True,), limit=8)),
     ("bucket_ids", "otb.exchange",
      lambda: _lowered(K.bucket_ids, (I,), num_buckets=4)),
+    ("bucket_rows", "otb.exchange",
+     lambda: _lowered(K.bucket_rows, (I % 3).astype(jnp.int32), ndn=2,
+                      bucket=N)),
+    ("take_rows", "otb.exchange",
+     lambda: _lowered(K.take_rows, (I, B), I.astype(jnp.int32), B)),
 ]
 
 
@@ -159,6 +164,40 @@ def test_mesh_program_on_four_devices_names_its_exchange(programs,
     assert {"otb.scan", "otb.exchange", "otb.agg", "otb.join_build",
             "otb.join_probe", "otb.join_expand"} <= _scopes(text)
     assert _scopes(text) <= VOCABULARY
+
+
+@pytest.mark.parametrize("ndn", [1, 4])
+def test_pack_lanes_counted_where_rows_move(ndn, monkeypatch):
+    """`pack_lanes`: the destination slots of the mesh program's exchange
+    packs (`ndn * bucket` a redistribute, a bucket 64 slots at least),
+    fixed when the program is traced: a key of
+    `last_query_stats()`, a column of `otb_stat_query`, a field of
+    EXPLAIN ANALYZE's `Shape:` line.  On one DataNode nothing moves and
+    it reads 0."""
+    monkeypatch.setenv("OTB_FUSE_JOIN_MIN_ROWS", "0")
+    s = ClusterSession(Cluster(n_datanodes=ndn))
+    _join_tables(s)
+    sql = ("select g, sum(price) from t, u where k = tk "
+           "group by g order by g")
+    seen = []
+    for _ in range(2):
+        assert len(s.query(sql)) == 5
+        st = s.last_query_stats()
+        seen.append((st["exchanges"], st["exchange_bytes"],
+                     st["pack_lanes"]))
+    assert seen[0] == seen[1]
+    exchanges, _sent, lanes = seen[0]
+    if ndn == 1:
+        assert (exchanges, lanes) == (0, 0)
+    else:
+        assert st["tier"] == "mesh" and exchanges >= 1
+        assert lanes >= exchanges * ndn * 64 and lanes % (ndn * 64) == 0
+    (row,) = s.query("select pack_lanes from otb_stat_query "
+                     f"where qid = {st['qid']}")
+    assert row == (lanes,)
+    text = "\n".join(r[0] for r in s.query("explain analyze " + sql))
+    line = next(ln for ln in text.splitlines() if ln.startswith("Shape: "))
+    assert line.split()[-1] == f"pack_lanes={lanes}", line
 
 
 def test_fused_program_names_its_steps(programs, monkeypatch):
