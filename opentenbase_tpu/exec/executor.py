@@ -408,8 +408,11 @@ class Executor:
         # (its INPUT's padded rows) and the largest OUTPUT class; of
         # those joins the anti ones, joins through join_expand's
         # left_outer arm, the largest class a semi or anti join with a
-        # residual EXPANDS into (0: every one was answered by a mask) and
-        # the largest code set or bitmap a string predicate brings
+        # residual EXPANDS into (0: every one was answered by a mask),
+        # the largest code set or bitmap a string predicate brings, the
+        # aggregates in `final` mode and the padded lanes the largest
+        # one's partials arrive in (`exchange_src_lanes` is the mesh
+        # tier's own: the largest source class a redistribute packs from)
         self.shape = dict.fromkeys(
             obs_trace.SHAPE_SUMS + obs_trace.SHAPE_MAXIMA, 0)
 
@@ -1628,6 +1631,16 @@ class Executor:
         no post-decode re-merge is needed.  Null masks on partial columns
         (a DN-group whose inputs were all NULL) combine through the same
         skip-null rule as raw arguments."""
+        self.shape["final_aggs"] += 1
+        self.shape["final_agg_lanes"] = max(self.shape["final_agg_lanes"],
+                                            b.padded)
+        # nested under the node's `otb.agg`, so that a trace's op names
+        # tell the final half of a two-phase aggregate from the partial
+        with jax.named_scope("otb.agg.final"):
+            return self._agg_final(node, b)
+
+    def _agg_final(self, node: P.Agg, b: DBatch) -> DBatch:
+        """`_exec_agg_final`'s combine, traced under its scope."""
         key_arrs, key_types, key_dicts, _, key_nulls = \
             self._eval_group_keys(node, b)
         kinds, inputs, out_specs = self._agg_inputs(node, b, final=True)

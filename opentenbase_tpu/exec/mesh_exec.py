@@ -1098,6 +1098,10 @@ class MeshRunner:
                             meta["exchange_bytes"] += self._a2a_sent_bytes(rb)
                             # the pack's destination slots: ndn * bucket
                             meta["pack_lanes"] += int(rb.valid.shape[0])
+                            # and the padded rows it packs from
+                            shape["exchange_src_lanes"] = max(
+                                shape.get("exchange_src_lanes", 0),
+                                b.padded)
                         overflows.append(over)
                     elif ex.kind == "broadcast":
                         with jax.named_scope("otb.exchange"):

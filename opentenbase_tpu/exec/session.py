@@ -401,7 +401,8 @@ def _trace_explain_lines() -> str:
         f"{k}={summary[k]}" for k in (
             "semi_joins", "sorted_aggs", "sorted_agg_lanes",
             "sorted_agg_groups", "initplans", "anti_joins", "outer_joins",
-            "residual_semi_lanes", "strpred_codes", "pack_lanes")))
+            "residual_semi_lanes", "strpred_codes", "final_aggs",
+            "final_agg_lanes", "exchange_src_lanes", "pack_lanes")))
     rounds = int(qt.sum_attr("exchange", "rounds"))
     if rounds:
         lines.append(

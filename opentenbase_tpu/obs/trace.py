@@ -86,9 +86,11 @@ _SUMMED = PHASES + ("wire.recv", "wire.send", "parse", "autoprep", "bind",
 # answered (`Executor.shape`, counted while it is traced): the ones that
 # add up over a program's fragments and a statement's programs, and the
 # ones that are a largest
-SHAPE_SUMS = ("semi_joins", "anti_joins", "outer_joins", "sorted_aggs")
+SHAPE_SUMS = ("semi_joins", "anti_joins", "outer_joins", "sorted_aggs",
+              "final_aggs")
 SHAPE_MAXIMA = ("residual_semi_lanes", "sorted_agg_lanes",
-                 "sorted_agg_groups", "strpred_codes")
+                 "sorted_agg_groups", "strpred_codes", "final_agg_lanes",
+                 "exchange_src_lanes")
 _BY_START = operator.attrgetter("t0_ms")
 
 
@@ -531,8 +533,12 @@ class QueryTrace:
         # join with a residual expands into (0: none does), sorted
         # aggregates, the padded rows of the largest and the largest
         # output class (group slots), the largest code set or bitmap a
-        # string predicate brings; and the scalar subqueries run before
-        # the statement, with their time
+        # string predicate brings; the final halves of two-phase
+        # aggregates (`Agg final` over redistributed partials) and the
+        # padded lanes the largest one's partials arrive in; the largest
+        # padded SOURCE class a redistribute packs from (`pack_lanes`
+        # is the destination side); and the scalar subqueries run
+        # before the statement, with their time
         for k, v in shape.items():
             d[k] = int(v)
         d["initplans"] = int(initplans)

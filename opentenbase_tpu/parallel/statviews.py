@@ -225,7 +225,13 @@ STAT_TABLES = {
         ColumnDef("strpred_codes", T.INT64),
         ColumnDef("initplan_ms", T.FLOAT64),
         # the destination slots of the mesh programs' exchange packs
-        ColumnDef("pack_lanes", T.INT64)],
+        ColumnDef("pack_lanes", T.INT64),
+        # two-phase aggregates: the final halves the programs hold, the
+        # lanes the largest one's redistributed partials arrive in; and
+        # the largest source class a redistribute packs from
+        ColumnDef("final_aggs", T.INT64),
+        ColumnDef("final_agg_lanes", T.INT64),
+        ColumnDef("exchange_src_lanes", T.INT64)],
     # per-node guard health (net/guard.py): breaker state + failure
     # accounting for every RPC peer this coordinator talks to
     # (reference: pgxc_node health columns fed by clustermon pings;
@@ -371,7 +377,8 @@ def refresh(cluster, names: list[str]):
                     s["d2h_bytes"], s["h2d_puts"], s["h2d_bytes"],
                     s["program_calls"], s["anti_joins"], s["outer_joins"],
                     s["residual_semi_lanes"], s["strpred_codes"],
-                    s["initplan_ms"], s["pack_lanes"]))
+                    s["initplan_ms"], s["pack_lanes"], s["final_aggs"],
+                    s["final_agg_lanes"], s["exchange_src_lanes"]))
         elif name == "otb_node_health":
             from ..net.guard import health_rows
             rows = list(health_rows())

@@ -28,7 +28,8 @@ SF = 0.01
 LIMITS = files.load_json("lib", "limits.json")
 SHAPE_KEYS = ("semi_joins", "sorted_aggs", "sorted_agg_lanes",
               "sorted_agg_groups", "initplans", "anti_joins", "outer_joins",
-              "residual_semi_lanes", "strpred_codes", "pack_lanes")
+              "residual_semi_lanes", "strpred_codes", "final_aggs",
+              "final_agg_lanes", "exchange_src_lanes", "pack_lanes")
 # the spec's validation values (tpch/queries.py holds them as literals)
 VALIDATION = {"q4": {"date": "1993-07-01"},
               "q17": {"brand": "Brand#23", "container": "MED BOX"},
