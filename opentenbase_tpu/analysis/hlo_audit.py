@@ -171,8 +171,11 @@ def check_kernels(report: dict):
         # class of its own (executor._agg_class): fewer slots than rows,
         # one key of host-known span (Q17's), SUM and COUNT by running
         # totals — ONE sort, no `lax.cond`, and a slot search in one pass
-        # (no `lax.map`).  The sort and the 64-bit `vals[perm]` are the
-        # kernel's own: those two rules cannot be declared of it
+        # (no `lax.map`).  Its per-ROW reads are 32-bit row gathers
+        # through an int32 perm (tests/test_tpu_compile.py holds that at
+        # the input's lanes); the sort and the per-SLOT reads of a 64-bit
+        # running sum (`(cumsum - vals)[starts]`) are the kernel's own:
+        # those two rules cannot be declared of it
         export_check(
             lambda k, m, a: K.grouped_agg_sort(
                 k, m, a, max_groups=n // 4, agg_kinds=("sum", "count"),

@@ -179,15 +179,6 @@ def _extreme(dtype, kind: str):
     return np.inf if kind == "min" else -np.inf
 
 
-def _masked_for(kind: str, vals, valid):
-    if kind in ("min", "max"):
-        return jnp.where(valid, vals,
-                         jnp.asarray(_extreme(vals.dtype, kind), vals.dtype))
-    if kind == "sum" and jnp.issubdtype(vals.dtype, jnp.integer):
-        vals = vals.astype(jnp.int64)  # SQL widens sum(int4) -> bigint
-    return jnp.where(valid, vals, jnp.zeros((), vals.dtype))
-
-
 @functools.partial(jax.jit, static_argnames=("num_groups", "agg_kinds"))
 @_scoped("otb.agg")
 def grouped_agg_dense(group_id, valid, agg_inputs: tuple,
@@ -280,9 +271,16 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
     recovers perm = word % n and the group image word // n.  The pack
     is injective exactly when prod(ranges)*n fits 62 bits, checked at
     runtime; `lax.cond` falls back to the exact multi-operand
-    comparator sort otherwise (hashed/full-range keys).  Payloads are
-    gathered once through perm.  The sorted groups are runs: a group's
-    first row is found by join_expand's search of a running count, its
+    comparator sort otherwise (hashed/full-range keys).  Both sorts put
+    the invalid rows last, so the sorted validity is a prefix mask
+    (`arange(n) < sum(valid)`, no gather), and everything read a ROW
+    through the final perm (an int32 word) comes in ONE gather of 32-bit
+    rows (`_take_rows`: every aggregate input that is not a count and,
+    after exact passes, the key words whose sorted images are compared,
+    side by side in one matrix, dead rows 0), where a scalar gather an
+    array cost the chip 50-92 ms each at 6,291,456 lanes, five of them
+    in Q17 (PERF.md section 6, PR 43).  The sorted groups are runs: a
+    group's first row is found by join_expand's search of a running count, its
     COUNT is the distance to the next group's, its integer SUM the
     difference of one running sum there; float sums, min and max reduce
     by segment (indices_are_sorted).  A group's keys are those of its
@@ -311,6 +309,7 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
     MeshRunner.run) discards the reply and replays one class up.
     """
     n = valid.shape[0]
+    _check_word("grouped_agg_sort", n)
     invalid = ~valid
     iota = jnp.arange(n, dtype=jnp.int64)
 
@@ -338,6 +337,15 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
     bits = bits + jnp.log2(jnp.float32(n + 2))
     pack_ok = bits < jnp.float32(62.0)
 
+    # both sorts put the invalid rows LAST (the pack gives them the
+    # maximal image, the exact passes sort the invalid flag as the most
+    # significant bit), so the sorted validity is a prefix: no gather
+    n_valid = jnp.sum(valid, dtype=jnp.int32)
+    s_valid = jnp.arange(n, dtype=jnp.int32) < n_valid
+    first = jnp.arange(n) == 0
+    carried = tuple(v for kind, v in zip(agg_kinds, agg_inputs)
+                    if kind != "count")
+
     def fast(_):
         acc = jnp.zeros(n, dtype=jnp.int64)
         for ki, mn, span in zip(ints, mns, spans):
@@ -347,12 +355,10 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
         top = jnp.max(jnp.where(valid, acc, 0)) + 1
         word = jnp.where(invalid, top, acc) * n + iota
         sw = jnp.sort(word)
-        perm = sw % n
+        perm = (sw % n).astype(jnp.int32)
         img = sw // n
-        s_valid = valid[perm]
-        first = jnp.arange(n) == 0
         boundary = s_valid & (first | (img != jnp.roll(img, 1)))
-        return perm, s_valid, boundary, img
+        return perm, boundary, _take_rows(carried, perm, s_valid), img
 
     def exact(words):
         # stable single-word passes, least significant word first and
@@ -361,17 +367,20 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
         # the TPU compiler's time for a sort grows with every operand
         # and key (one 6-operand sort at 163840 rows was most of Q3's
         # compile; CHANGES.md, PR 22)
-        perm = jnp.arange(n, dtype=jnp.int32)
-        for w in reversed(words):
-            perm = jax.lax.sort([w[perm], perm], num_keys=1)[1]
-        s_valid = valid[perm]
-        first = jnp.arange(n) == 0
-        differs = jnp.zeros(n, dtype=bool)
-        for w in words:
-            k = w[perm]
+        every = jnp.ones(n, dtype=bool)
+        head, perm = jax.lax.sort(
+            [words[-1], jnp.arange(n, dtype=jnp.int32)], num_keys=1)
+        for w in words[-2::-1]:
+            head, perm = jax.lax.sort(
+                [_take_rows((w,), perm, every)[0], perm], num_keys=1)
+        # the last pass leaves its own word sorted; the other words'
+        # sorted images come with the aggregates' inputs
+        got = _take_rows((*words[1:], *carried), perm, s_valid)
+        differs = head != jnp.roll(head, 1)
+        for k in got[:len(words) - 1]:
             differs = differs | (k != jnp.roll(k, 1))
         boundary = s_valid & (first | differs)
-        return perm.astype(jnp.int64), s_valid, boundary
+        return perm, boundary, got[len(words) - 1:]
 
     def packed_words():
         """The invalid flag and the keys, most significant first, in as
@@ -410,13 +419,14 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
         proven = sum(math.log2(sp + 2) for sp in key_spans) \
             + math.log2(n + 2) < 62
         if proven:
-            perm, s_valid, boundary, img = fast(None)
+            perm, boundary, rows, img = fast(None)
         else:
-            perm, s_valid, boundary = exact(packed_words())
+            perm, boundary, rows = exact(packed_words())
     else:
-        perm, s_valid, boundary = jax.lax.cond(
+        perm, boundary, rows = jax.lax.cond(
             pack_ok, lambda _: fast(None)[:3],
             lambda _: exact([invalid, *ints]), None)
+    rows = iter(rows)
     n_groups = jnp.sum(boundary)
     run = jnp.cumsum(boundary)      # groups begun up to and at a row
     # each group's first row, by the search join_expand makes of a
@@ -438,14 +448,15 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
         if kind == "count":
             # the valid rows are a prefix: a row's index counts them
             outs.append(run_totals(starts.astype(jnp.int64),
-                                   jnp.sum(valid, dtype=jnp.int64)))
+                                   n_valid.astype(jnp.int64)))
             continue
-        vals = vals[perm]
+        # in sorted order, the dead rows 0 (`_take_rows`' keep)
+        vals = next(rows)
         if kind == "sum" and jnp.issubdtype(vals.dtype, jnp.integer):
             # exact in int64 whatever wraps on the way: differences of
             # ONE running sum, one gather, where a scatter-add over
             # 6,291,456 sorted rows cost the chip 0.6 s (PR 34)
-            vals = _masked_for("sum", vals.astype(jnp.int64), s_valid)
+            vals = vals.astype(jnp.int64)   # SQL widens sum(int4) -> bigint
             below = (jnp.cumsum(vals) - vals)[starts]
             outs.append(run_totals(below, jnp.sum(vals)))
             continue
@@ -453,10 +464,10 @@ def grouped_agg_sort(key_cols: tuple, valid, agg_inputs: tuple,
         # min/max: reduced by segment
         gid = jnp.where(s_valid, run - 1, max_groups)
         if kind == "sumf":
-            vals = _masked_for("sum", vals.astype(device_float()),
-                               s_valid)
+            vals = vals.astype(device_float())
         else:
-            vals = _masked_for(kind, vals, s_valid)
+            vals = jnp.where(s_valid, vals, jnp.asarray(
+                _extreme(vals.dtype, kind), vals.dtype))
         reduce = {"min": jax.ops.segment_min,
                   "max": jax.ops.segment_max}.get(kind, jax.ops.segment_sum)
         outs.append(reduce(vals, gid, num_segments=max_groups + 1,
@@ -1107,7 +1118,9 @@ def take_rows(arrays: tuple, idx, keep):
     in its own dtype.  A scalar gather an array costs the chip three
     times a row gather a lane, six from an int64 table (PERF.md section
     6, PR 30), and an exchange moves 6 to 12 words a row.  At most
-    _MAX_LANES lanes a pass, as join_expand.
+    _MAX_LANES lanes a pass, as join_expand.  The body, `_take_rows`, is
+    also how grouped_agg_sort reads its rows in sorted order (idx its
+    perm, keep the valid rows' prefix: 2 to 10 words a row).
 
     An array whose taken rows nothing reads stays out of the matrix, and
     what made it is dead code with it, as if it had a gather of its own:

@@ -219,6 +219,163 @@ class TestGroupedAggSort:
         assert int(ng) == 0
 
 
+# --- grouped_agg_sort's read of its sorted rows (PR 43) --------------------
+# An arm of the kernel is what the host knows of the keys: (key_spans, keys).
+_I64 = np.iinfo(np.int64)
+_SORT_N = 700
+
+
+def _arm_keys(arm, n, r):
+    """Three key columns with duplicates and `key_spans` for one arm of the
+    kernel: `proven` (the bounds prove the pack: the fast sort alone),
+    `unprovable` (bounds too wide: the exact passes over two packed words),
+    `none_fast` / `none_exact` (nothing known: the `lax.cond`, whose data
+    take the pack or refuse it)."""
+    if arm == "unprovable":
+        pools = (r.integers(0, 2**40, 9), r.integers(0, 2**30, 4),
+                 np.arange(8))
+        spans = (2**40, 2**30, 7)
+    elif arm == "none_exact":
+        pools = (np.concatenate([[_I64.min, _I64.max, -1, 0],
+                                 r.integers(_I64.min, _I64.max, 6)]),
+                 r.integers(_I64.min, _I64.max, 3), np.arange(-2, 2))
+        spans = None
+    else:
+        pools = (np.arange(-20, 31), np.arange(3), np.arange(5))
+        spans = (50, 2, 4) if arm == "proven" else None
+    return tuple(r.choice(pl, n).astype(np.int64) for pl in pools), spans
+
+
+def _sorted_agg_case(case, n, r, keys):
+    """(valid, inputs, kinds, max_groups, lanes a pass) of one scenario."""
+    valid = r.random(n) > 0.25
+    ints = r.integers(-1000, 1000, n).astype(np.int64)
+    groups, lanes = 256, None
+    if case == "int64_extremes":
+        wild = r.choice(np.asarray(
+            [_I64.min, _I64.max, -1, 0, 1, _I64.min + 1, -2**40]), n)
+        inputs, kinds = (wild, wild, wild, ints.astype(np.int32)), \
+            ("sum", "min", "max", "sum")
+    elif case == "float_sum":
+        f = r.standard_normal(n)
+        f[r.random(n) < 0.2] = -0.0
+        f[np.flatnonzero(valid)[:2]] = np.nan
+        inputs, kinds = (f, ints, f.astype(np.float32)), \
+            ("sumf", "sum", "sumf")
+    elif case == "dead_group":
+        # every row of the most frequent first key is invalid
+        vals, counts = np.unique(keys[0], return_counts=True)
+        valid = valid & (keys[0] != vals[np.argmax(counts)])
+        inputs, kinds = (ints, ints, ints), ("min", "max", "sum")
+    elif case == "count_beside_sums":
+        inputs, kinds = (ints, ints, ints, ints.astype(np.int32)), \
+            ("count", "sum", "count", "sum")
+    elif case == "overflow":
+        inputs, kinds, groups = (ints, ints, ints), ("sum", "count", "min"), 8
+    elif case == "no_valid_row":
+        valid = np.zeros(n, bool)
+        inputs, kinds = (ints, ints, ints), ("sum", "min", "count")
+    elif case == "three_passes":
+        inputs, kinds, lanes = (ints, ints), ("sum", "max"), 256
+    else:
+        assert case == "second_matrix"       # 132 words of inputs
+        inputs = tuple(r.integers(_I64.min, _I64.max, n) for _ in range(66))
+        kinds = ("sum",) * 66
+    return valid, inputs, kinds, groups, lanes
+
+
+def _sorted_agg_oracle(keys, valid, inputs, kinds):
+    """A plain group-by: groups in the order of their keys, a group's rows
+    in source order; integer sums wrap in int64, float sums add one row
+    after another from 0."""
+    order = np.lexsort((*reversed(keys), ~valid))[:valid.sum()]
+    assert valid[order].all()
+    sk = np.stack([k[order] for k in keys], axis=1)
+    cut = np.flatnonzero(np.r_[True, (sk[1:] != sk[:-1]).any(axis=1)]) \
+        if len(order) else np.zeros(0, int)
+    gkeys = sk[cut].T if len(order) else np.zeros((len(keys), 0), np.int64)
+    outs = []
+    for kind, v in zip(kinds, inputs):
+        parts = np.split(v[order], cut[1:]) if len(order) else []
+        if kind == "count":
+            outs.append(np.asarray([len(p) for p in parts], np.int64))
+        elif kind == "sum":
+            with np.errstate(over="ignore"):
+                outs.append(np.asarray(
+                    [p.astype(np.int64).sum(dtype=np.int64) for p in parts],
+                    np.int64))
+        elif kind == "sumf":
+            tot = []
+            for p in parts:
+                acc = np.float64(0)
+                for x in p.astype(np.float64):
+                    acc = acc + x
+                tot.append(acc)
+            outs.append(np.asarray(tot, np.float64))
+        else:
+            outs.append(np.asarray(
+                [getattr(p, kind)() for p in parts], v.dtype))
+    return gkeys, outs
+
+
+def _scalar_take_rows(arrays, idx, keep):
+    """What `_take_rows` stands for: `vals[perm]` an array, dead rows 0."""
+    return tuple(jnp.where(keep, a[idx], jnp.zeros((), a.dtype))
+                 for a in arrays)
+
+
+class TestSortedAggReadsItsRowsOnce:
+    """`grouped_agg_sort` reads its sorted rows through ONE row gather of
+    32-bit words and takes the sorted validity as a prefix mask: against a
+    plain numpy group-by, and bit for bit against the same kernel reading
+    `vals[perm]` an array by scalar gathers."""
+
+    @pytest.mark.parametrize("case", [
+        "int64_extremes", "float_sum", "dead_group", "count_beside_sums",
+        "overflow", "no_valid_row", "three_passes", "second_matrix"])
+    @pytest.mark.parametrize("arm", ["proven", "unprovable", "none_fast",
+                                     "none_exact"])
+    def test_against_numpy_and_scalar_gathers(self, arm, case, monkeypatch):
+        r = np.random.default_rng(sum(map(ord, arm + case)))
+        n = _SORT_N
+        keys, spans = _arm_keys(arm, n, r)
+        valid, inputs, kinds, max_groups, lanes = _sorted_agg_case(
+            case, n, r, keys)
+        if lanes:
+            monkeypatch.setattr(K, "_MAX_LANES", lanes)
+
+        def run():
+            # a fresh jit: traced anew under whatever is patched in
+            fn = jax.jit(K.grouped_agg_sort.__wrapped__, static_argnames=(
+                "max_groups", "agg_kinds", "key_spans"))
+            gk, outs, ng = fn(
+                tuple(jnp.asarray(k) for k in keys), jnp.asarray(valid),
+                tuple(jnp.asarray(v) for v in inputs),
+                max_groups=max_groups, agg_kinds=kinds, key_spans=spans)
+            return [np.asarray(a) for a in gk], \
+                [np.asarray(o) for o in outs], int(ng)
+        gk, outs, ng = run()
+        want_keys, want = _sorted_agg_oracle(keys, valid, inputs, kinds)
+        assert ng == want_keys.shape[1]
+        # an overflowed call answers for the first max_groups groups; its
+        # last slot's running totals have no next group to end at
+        full = min(ng, max_groups)
+        whole = full if ng <= max_groups else full - 1
+        for g, w in zip(gk, want_keys):
+            np.testing.assert_array_equal(g[:full], w[:full])
+        for kind, o, w in zip(kinds, outs, want):
+            m = whole if kind in ("sum", "count") else full
+            assert o.dtype == w.dtype
+            np.testing.assert_array_equal(o[:m], w[:m])     # NaN == NaN
+            np.testing.assert_array_equal(np.signbit(o[:m]),
+                                          np.signbit(w[:m]))
+        monkeypatch.setattr(K, "_take_rows", _scalar_take_rows)
+        gk2, outs2, ng2 = run()
+        assert ng2 == ng
+        for a, b in zip(gk + outs, gk2 + outs2):
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
 class TestJoin:
     def _oracle_pairs(self, probe, build, pvalid, bvalid):
         out = []

@@ -230,6 +230,34 @@ def test_grouped_agg_sort_with_fewer_slots_than_rows(one_chip, slots):
     assert text.count(" sort(") == 1 and " while(" not in text
 
 
+@pytest.mark.parametrize("key_spans", [None, (SMALL - 1,), (1 << 62,)])
+def test_grouped_agg_sort_reads_its_sorted_rows_in_32_bit_rows(one_chip,
+                                                               key_spans):
+    """The kernel's per-ROW reads (the aggregates' inputs in sorted order,
+    the exact passes' key words) are row gathers of 32-bit words through
+    an int32 perm, and the sorted validity is a prefix mask: with fewer
+    slots than rows, no gather at the input's lanes reads or is indexed
+    by a 64-bit array (the chip has no 64-bit lanes: its compiler splits
+    one into two u32 gathers) and none reads `valid`.  In all three arms;
+    integer sums bring no scatter, and nothing loops."""
+    import re
+    s = one_chip
+    fn = jax.jit(lambda k, v, a: K.grouped_agg_sort(
+        (k,), v, (a, a), max_groups=SMALL // 4, agg_kinds=("sum", "sum"),
+        key_spans=key_spans))
+    args = (s(SMALL, I64), s(SMALL, BOOL), s(SMALL, I64))
+    traced = [ln for ln in fn.lower(*args).as_text().splitlines()
+              if "stablehlo.gather" in ln and f"-> tensor<{SMALL}x" in ln]
+    assert traced and not [
+        ln for ln in traced
+        if re.search(r":\s*\([^)]*(i64|i1)>[^)]*\)\s*->", ln)], traced
+    text = _compile(fn, *args)
+    per_row = [ln.split(" gather(")[0] for ln in text.splitlines()
+               if " gather(" in ln and re.search(rf"= \w+\[{SMALL}[,\]]", ln)]
+    assert per_row and all(f"= s32[{SMALL}" in g for g in per_row), per_row
+    assert " scatter(" not in text and " while(" not in text
+
+
 def test_sort_rows_top10(one_chip):
     """Payloads stay out of the variadic sort (flag + 2 keys + row index
     = 4 operands): the chip's compile time grows with every operand."""
