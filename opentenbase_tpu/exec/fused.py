@@ -64,6 +64,7 @@ from ..sql.fingerprint import struct_key
 from ..storage import codec
 from . import plancache
 from ..utils import locks
+from ..utils.dtypes import dev_dtype
 
 # one lock for this module's learned-state dicts: CN-server threads
 # share them, and the add-then-evict sequences below must be atomic
@@ -331,6 +332,33 @@ def _table_sig(stores: dict) -> tuple:
         for t, st in sorted(stores.items()))
 
 
+def _call_args(staged_arrs: dict, nrows: dict, snapshot_ts, txid,
+               params) -> tuple:
+    """The argument tree of a fused program call: the ONE way
+    `_try_fused`, `FragmentProgram.run` and `stage_fused_batch` hand a
+    program what the host knows.  `nrows` maps a table to its live row
+    count, `params` lists `(value, SqlType)` in traced order (numeric
+    parameters, then masked literals); a batch passes a list of K
+    values where a single call passes one.  Every value leaves as a
+    numpy value of the dtype the program computes in (`dev_dtype` of
+    its SQL type, what `expr_compile` casts a literal to: a 32-bit
+    literal meets its 32-bit column unwidened), as
+    `mesh_exec._call_program` hands its own: jax transfers them with
+    the program's launch, so there is no put, no eager convert and no
+    device scalar to free.  Row counts stay traced arguments
+    (`_build_program`).  A value its declared type cannot hold (an
+    `integer` parameter past 32 bits) is an error here: a cast inside
+    the program would wrap around and answer."""
+    try:
+        pvals = tuple(np.asarray(v, dtype=dev_dtype(t)) for v, t in params)
+    except OverflowError as e:
+        from .executor import ExecError
+        raise ExecError(f"value out of range for its type: {e}") from None
+    return (staged_arrs,
+            np.asarray(snapshot_ts, np.int64), np.asarray(txid, np.int64),
+            pvals, {t: np.int64(nrows[t]) for t in sorted(nrows)})
+
+
 def try_fused(executor, node) -> Optional[object]:
     """Execute `node` as one jitted program, or None if unsupported."""
     return _try_fused(executor, node, allow_mask=True)
@@ -369,17 +397,13 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
             _needed_columns(node, scan.alias))
     staged_arrs: dict = {}
     staged_ns: dict = {}
-    # `inputs`: what the program is called with, made ready on the
-    # device — here the staged arrays (a pool lookup; a miss stages
-    # under it) and each table's row count, below the parameters; every
-    # scalar is a put and an eager convert of its own (`h2d` counts
-    # them; `h2d_bytes` is for arrays: a scalar's 8 bytes are not added)
-    with obs_trace.span("inputs") as isp:
+    # `inputs`: the staged arrays (a pool lookup; a miss stages under
+    # it) with each table's row count; every host scalar of the call
+    # rides its argument tree (`_call_args`): nothing is put by itself
+    with obs_trace.span("inputs"):
         for t, need in sorted(need_by_table.items()):
-            arrs, n = ctx.cache.get(stores[t], sorted(need))
-            staged_arrs[t] = arrs
-            staged_ns[t] = jnp.int64(n)
-        isp.set(h2d=len(staged_ns))
+            staged_arrs[t], staged_ns[t] = ctx.cache.get(
+                stores[t], sorted(need))
 
     table_sig = _table_sig(stores)
     ctx = _bound_ctx(ctx, exec_node_plan)
@@ -415,11 +439,10 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
     lkey = struct_key(base_key)
     factors: dict = dict(_JOIN_LADDER.get(lkey, {}))
 
-    with obs_trace.span("inputs") as isp:
-        pvals = tuple(
-            [jnp.asarray(ctx.params[k][0]) for k in traced_names]
-            + [jnp.asarray(v) for _n, v, _t in lits])
-        isp.set(h2d=len(pvals))
+    args = _call_args(
+        staged_arrs, staged_ns, ctx.snapshot_ts, ctx.txid,
+        [ctx.params[k] for k in traced_names]
+        + [(v, t) for _n, v, t in lits])
     from .executor import bump_stat, stats_tier
 
     for _attempt in range(24):
@@ -444,10 +467,7 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
                 with stats_tier("fused"):
                     # trace-time executor counters attribute to the
                     # fused tier (re-executions don't re-trace)
-                    cols, valid, nulls, join_req = fn(
-                        staged_arrs, jnp.int64(ctx.snapshot_ts),
-                        jnp.int64(ctx.txid), pvals, staged_ns)
-                sp.set(h2d=2)                   # the snapshot, the txid
+                    cols, valid, nulls, join_req = fn(*args)
             except (jax.errors.TracerBoolConversionError,
                     jax.errors.ConcretizationTypeError,
                     jax.errors.TracerArrayConversionError):
@@ -503,17 +523,15 @@ def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint
             # what the program holds, fixed when it was traced
             sp.set(**meta.get("shape", {}))
             if EXPORT_HOOK is not None:
-                EXPORT_HOOK("fused", fn,
-                            (staged_arrs, jnp.int64(ctx.snapshot_ts),
-                             jnp.int64(ctx.txid), pvals, staged_ns))
+                EXPORT_HOOK("fused", fn, args)
             from .executor import DBatch
             out = DBatch(dict(cols), valid, dict(meta["types"]),
                          dict(meta["dicts"]), dict(nulls))
-        # `release`: the call's own device scalars (row counts,
-        # parameters, the overflow vector) are dropped here, not on the
-        # way out, so that what freeing them costs has a name
+        # `release`: the overflow vector, the one device buffer the
+        # call itself made, is dropped here, not on the way out, so
+        # that what freeing it costs has a name
         with obs_trace.span("release"):
-            del staged_ns, pvals, join_req
+            del join_req
         return out
     return None  # overflow never converged: eager fallback
 
@@ -751,9 +769,6 @@ class FragmentProgram:
         shape permanently refuses fusion (caller declines the stream)."""
         from .executor import DBatch, stats_tier
         ctx = self.ctx
-        pvals = tuple(
-            [jnp.asarray(ctx.params[k][0]) for k in self.traced_names]
-            + [jnp.asarray(v) for _n, v, _t in self.lits])
         for _attempt in range(24):
             full_key = self.base_key + (
                 self._chunk_key, tuple(sorted(self.factors.items())))
@@ -766,12 +781,16 @@ class FragmentProgram:
             fn, meta = hit
             if fn is None:
                 return None  # permanently fell back for this shape
+            # built per attempt: a refused mask re-prepares with its
+            # literals baked, and the traced list shrinks with it
+            args = _call_args(
+                staged_arrs, staged_ns, snapshot_ts, txid,
+                [ctx.params[k] for k in self.traced_names]
+                + [(v, t) for _n, v, t in self.lits])
             t0 = time.perf_counter()
             try:
                 with stats_tier("morsel"):
-                    cols, valid, nulls, join_req = fn(
-                        staged_arrs, jnp.int64(snapshot_ts),
-                        jnp.int64(txid), pvals, staged_ns)
+                    cols, valid, nulls, join_req = fn(*args)
             except (jax.errors.TracerBoolConversionError,
                     jax.errors.ConcretizationTypeError,
                     jax.errors.TracerArrayConversionError):
@@ -833,15 +852,15 @@ def _batch_class(k: int) -> int:
 
 
 class StagedBatch:
-    """A coalesced batch after the STAGE phase: keys computed, literal
-    and MVCC columns stacked, leaf tables resident on device — host work
+    """A coalesced batch after the STAGE phase: keys computed, the
+    call's arguments built (`_call_args`: literal and MVCC columns as
+    numpy vectors of K), leaf tables resident on device — host work
     only, no program launched yet.  The pipelined scheduler stages batch
     i+1 while batch i computes; `launch_fused_batch` turns one of these
     into an in-flight dispatch."""
 
-    __slots__ = ("info", "k", "kclass", "base_key", "lkey", "snaps",
-                 "txids", "pvals", "staged_arrs", "staged_ns", "bctx",
-                 "factors")
+    __slots__ = ("info", "k", "kclass", "base_key", "lkey", "args",
+                 "bctx", "factors")
 
 
 class FusedFlight:
@@ -870,9 +889,8 @@ def stage_fused_batch(info: FragSig, queries: list) \
     staged_arrs: dict = {}
     staged_ns: dict = {}
     for t, need in sorted(info.need_by_table.items()):
-        arrs, n = info.cache.get(info.stores[t], sorted(need))
-        staged_arrs[t] = arrs
-        staged_ns[t] = jnp.int64(n)
+        staged_arrs[t], staged_ns[t] = info.cache.get(
+            info.stores[t], sorted(need))
 
     # recompute the table signature at dispatch time: DML between
     # classification and dispatch can grow a TEXT dictionary, and the
@@ -892,13 +910,11 @@ def stage_fused_batch(info: FragSig, queries: list) \
     sb.k = len(queries)
     sb.kclass = _batch_class(sb.k)
     padded = list(queries) + [queries[-1]] * (sb.kclass - sb.k)
-    sb.snaps = jnp.asarray([q[0] for q in padded], jnp.int64)
-    sb.txids = jnp.asarray([q[1] for q in padded], jnp.int64)
-    sb.pvals = tuple(
-        jnp.stack([jnp.asarray(q[2][i]) for q in padded])
-        for i in range(len(info.lits)))
-    sb.staged_arrs = staged_arrs
-    sb.staged_ns = staged_ns
+    sb.args = _call_args(
+        staged_arrs, staged_ns, [q[0] for q in padded],
+        [q[1] for q in padded],
+        [([q[2][i] for q in padded], t)
+         for i, t in enumerate(info.lit_types)])
 
     with _STATE_LOCK:
         sb.factors = dict(_JOIN_LADDER.get(sb.lkey, {}))
@@ -929,9 +945,7 @@ def launch_fused_batch(sb: StagedBatch, attempt: int = 0) \
     t0 = time.perf_counter()
     try:
         with stats_tier("fused"):
-            cols, valid, nulls, join_req = fn(
-                sb.staged_arrs, sb.snaps, sb.txids, sb.pvals,
-                sb.staged_ns)
+            cols, valid, nulls, join_req = fn(*sb.args)
     except (jax.errors.TracerBoolConversionError,
             jax.errors.ConcretizationTypeError,
             jax.errors.TracerArrayConversionError):

@@ -51,8 +51,6 @@ import dataclasses
 import os
 from typing import Optional
 
-import jax.numpy as jnp
-
 from ..obs import trace as obs_trace
 from ..obs import xray as obs_xray
 from ..plan import exprs as E
@@ -485,7 +483,7 @@ class MorselDriver:
                 | _needed_cols(shape.per_plan, info.node.table.name))
             arrs, n = self.cache.get(info.store, rneed)
             resident_arrs[info.node.table.name] = arrs
-            resident_ns[info.node.table.name] = jnp.int64(n)
+            resident_ns[info.node.table.name] = n
             handle = POOL.pin_table(info.store)
             if handle is not None:
                 pins.append(handle)
@@ -542,7 +540,7 @@ class MorselDriver:
                     staged_arrs = dict(resident_arrs)
                     staged_arrs[bname] = entry.arrs
                     staged_ns = dict(resident_ns)
-                    staged_ns[bname] = jnp.int64(entry.live)
+                    staged_ns[bname] = entry.live
                     try:
                         out = prog.run(staged_arrs, staged_ns,
                                        self.snapshot_ts, self.txid)
@@ -654,7 +652,7 @@ class MorselDriver:
                 staged_arrs[bname] = {nm: entry.arrs[nm]
                                       for nm in staged_names}
                 staged_ns = dict(resident_ns)
-                staged_ns[bname] = jnp.int64(entry.live)
+                staged_ns[bname] = entry.live
                 out = prog.run(staged_arrs, staged_ns,
                                self.snapshot_ts, self.txid)
                 if out is not None:

@@ -153,11 +153,12 @@ class TestServedPathAccounting:
         assert c.query(sql.format(8)) == [(8, 80, "w2")]
         st = sessions[0].last_query_stats()     # at the reply: still open
         assert st["tier"] == "fqs"
-        # one compiled program answered; its inputs went up as scalars
-        # (row count, literal, snapshot, txid); the validity and three
-        # columns came down in ONE batched copy
+        # one compiled program answered; its host scalars (row count,
+        # literal, snapshot, txid) rode the call's own arguments as numpy
+        # values: no put of their own; the validity and three columns
+        # came down in ONE batched copy
         assert st["program_calls"] == 1
-        assert st["h2d_puts"] == 4 and st["h2d_bytes"] == 0
+        assert st["h2d_puts"] == 0 and st["h2d_bytes"] == 0
         assert st["host_syncs"] == st["finalize_fetches"] == 1
         assert st["d2h_bytes"] == st["finalize_fetch_bytes"]
         # the steps that were the root's self time have names now, and
@@ -179,8 +180,8 @@ class TestServedPathAccounting:
             time.sleep(0.01)
         qt = done[0]
         assert [s.name for s in qt.root.children if s.ms > 0] == [
-            "wire.recv", "parse", "autoprep", "bind", "inputs", "inputs",
-            "execute", "release", "finalize", "release", "wire.send"]
+            "wire.recv", "parse", "autoprep", "bind", "inputs", "execute",
+            "release", "finalize", "release", "wire.send"]
         fin = qt.summary()
         # the serving thread's CPU, read at the statement's two ends
         assert fin["cpu_ms"] == qt.cpu_ms
