@@ -98,7 +98,7 @@ _HASHABLE_CALLS = frozenset({"tuple", "frozenset", "struct_key",
 _FRESH_CALLS = frozenset({"dict", "list", "set", "object", "bytearray"})
 
 _STORM_LIMIT = 64      # class combinations per fragment signature
-_FACTOR_CAP = 4096     # exec/fused.py / mesh_exec.py ladder exhaustion
+_FACTOR_CAP = 4096     # exec/plancache.py Ladder.CAP (a test holds them equal)
 
 
 def _loads(e) -> set:

@@ -45,17 +45,6 @@ from .executor import (DBatch, ExecContext, ExecError, Executor, materialize,
                        scalars_from_batch)
 
 
-def _walk_plan(node):
-    yield node
-    for attr in ("child", "left", "right"):
-        c = getattr(node, attr, None)
-        if c is not None and hasattr(c, "__dataclass_fields__"):
-            yield from _walk_plan(c)
-    for c in getattr(node, "inputs", None) or []:
-        if hasattr(c, "__dataclass_fields__"):
-            yield from _walk_plan(c)
-
-
 @dataclasses.dataclass
 class HostBatch:
     """Exchange wire format: host numpy columns, TEXT as decoded values,
@@ -198,7 +187,7 @@ class DistExecutor:
         from ..plan import physical as P
         tables = set()
         for frag in dp.fragments:
-            for nd in _walk_plan(frag.plan):
+            for nd in P.walk(frag.plan):
                 if isinstance(nd, P.SeqScan):
                     tables.add(nd.table.name)
         for t in tables:
@@ -348,14 +337,14 @@ class DistExecutor:
         # combine feeding a redistribution, execRemote.c merge then
         # re-ship).  Slower than a true per-DN pipeline but correct for
         # every plan shape; the mesh tier declines these plans.
-        needed = {n.index for n in _walk_plan(frag.plan)
+        needed = {n.index for n in P.walk(frag.plan)
                   if isinstance(n, ExchangeRef)}
         ndn = self.cluster.ndn
         cn_only = {i for i in needed
                    if (i, "cn") in ex_out
                    and not any((i, d) in ex_out for d in range(ndn))}
         scans_tables = any(isinstance(n, P.SeqScan)
-                           for n in _walk_plan(frag.plan))
+                           for n in P.walk(frag.plan))
         if cn_only and scans_tables:
             # the fragment must run on the DNs (it scans shards) but an
             # input was gathered to the CN: replicate that input to

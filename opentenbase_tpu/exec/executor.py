@@ -347,6 +347,28 @@ def bind_text_params(exprs, params: dict, stores: dict, tier: str) -> dict:
     return out
 
 
+# what jax raises where a traced value feeds a host branch (`bool()`,
+# `int()`, `np.asarray` of a tracer): how a compiled tier learns that a
+# plan its screen let through still syncs
+TRACE_HOST_SYNC = (jax.errors.TracerBoolConversionError,
+                   jax.errors.ConcretizationTypeError,
+                   jax.errors.TracerArrayConversionError)
+
+
+def split_params(params: dict) -> tuple:
+    """`(traced_names, baked)` of a compiled tier's `params` (bound by
+    `bind_text_params` first): a number rides its program as a traced
+    argument, named here in sorted order, the order of the call's
+    values; everything else (a string no column took, a bool, a NULL:
+    they change a program's structure) is a constant of the program and
+    belongs in its key."""
+    traced_names = tuple(sorted(
+        k for k, (v, _t) in params.items()
+        if isinstance(v, (int, float)) and not isinstance(v, bool)))
+    return traced_names, {k: params[k] for k in params
+                          if k not in traced_names}
+
+
 @dataclasses.dataclass
 class ExecContext:
     stores: dict[str, TableStore]
@@ -715,13 +737,12 @@ class Executor:
                       spans=spans)
 
     # Index scans never fuse: neither tier's screen admits P.IndexScan
-    # (fused._key_of returns None; mesh _ALLOWED excludes it).
+    # (outside fused._KINDS and mesh _ALLOWED: plan_key gives None).
     def _exec_indexscan(self, node: P.IndexScan) -> DBatch:  # otblint: eager-only
         """Index scan: host binary search -> gather only the candidate
         rows -> the regular fused scan path over that staged subset
         (reference: ExecIndexScan; visibility/filters re-verify on the
         subset, so a stale bound can only over-select, never miss)."""
-        from .fused import _needed_columns
         seq = P.SeqScan(node.table, node.alias, node.filters,
                         node.outputs)
         store = self.ctx.stores.get(node.table.name)
@@ -733,8 +754,8 @@ class Executor:
                                  node.lo_strict, node.hi_strict)
         if pos is None:
             return self._exec_seqscan(seq)  # index dropped: full scan
-        needed = sorted((_needed_columns(seq, node.alias)
-                         | _needed_columns(seq, node.table.name))
+        needed = sorted((P.needed_columns(seq, node.alias)
+                         | P.needed_columns(seq, node.table.name))
                         & set(store.td.column_names))
         host = store.gather_rows(pos, needed)
         from ..storage.batch import stage_padded
@@ -1186,7 +1207,7 @@ class Executor:
         return node.batch
 
     # SetOps size their output with host syncs (int(ng), int(total));
-    # P.SetOp is outside fused._key_of and mesh _ALLOWED, so this
+    # P.SetOp is outside fused._KINDS and mesh _ALLOWED, so this
     # operator only ever runs on the eager tier.
     def _exec_setop(self, node: P.SetOp) -> DBatch:  # otblint: eager-only
         """INTERSECT/EXCEPT [ALL]: side-tagged merge, per-group per-side

@@ -20,35 +20,41 @@ the SAME static size-class ladder the mesh tier runs under shard_map
 (exec/executor.py _exec_hashjoin `_traced` branch): a join's output
 class starts at a quarter of its larger input, the program reports
 per-join required totals, and the host retraces one step up on
-overflow — the learned factors persist in _JOIN_LADDER keyed by the
-literal-masked fragment shape, so steady state is one program call with
-ZERO per-join device→host syncs (the eager path pays one `int(total)`
-sync per join per query).  A sorted aggregate whose keys' ranges do not
-bound its groups rides the same ladder under an id of the same sequence
-(executor._agg_class): a quarter of its input's rows, its groups
-reported beside the joins' totals.
+overflow — the learned factors persist in the tier's ladder (`_LADDER`,
+a plancache.Ladder: the one rule of growth both compiled tiers share)
+keyed by the literal-masked fragment shape, so steady state is one
+program call with ZERO per-join device→host syncs (the eager path pays
+one `int(total)` sync per join per query).  A sorted aggregate whose
+keys' ranges do not bound its groups rides the same ladder under an id
+of the same sequence (executor._agg_class): a quarter of its input's
+rows, its groups reported beside the joins' totals.
 
 Compiled programs live in the shared program cache (exec/plancache.py
-FUSED tier) under a CANONICAL FRAGMENT SIGNATURE: numeric/date literals
-in scan filters and quals are masked out of the plan and ride as traced
-program inputs instead, as does the dictionary code of a text parameter
-compared with a column (`_bound_ctx`), so `WHERE l_shipdate <= X` with a
-different constant reuses the compiled executable (the reference's
-generic-plan arm, taken further: the plan cache there saves planning,
-this saves the XLA compile).  Multi-table fragments key per-table components (store
+FUSED tier) under a CANONICAL FRAGMENT SIGNATURE whose plan part is
+`plan/physical.plan_key` (the one spelling both compiled tiers key a
+plan by): numeric/date literals in scan filters and quals are masked
+out of the plan and ride as traced program inputs instead, as does the
+dictionary code of a text parameter compared with a column
+(`_bound_ctx`), so `WHERE l_shipdate <= X` with a different constant
+reuses the compiled executable (the reference's generic-plan arm, taken
+further: the plan cache there saves planning, this saves the XLA
+compile).  Multi-table fragments key per-table components (store
 identity + TEXT dictionary lengths — dictionaries are trace constants).
 jax re-traces per array shape automatically — the pow2/quarter-step
 size classes bound that — and the cache's global live-executable budget
 evicts LRU programs deterministically.
+
+What names a fragment's programs and how one is called is `_Prepared`,
+for a statement (`_try_fused`), a morsel stream's chunks
+(`FragmentProgram`) and the scheduler's coalesced batch alike.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import os
-import threading
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -66,8 +72,8 @@ from . import plancache
 from ..utils import locks
 from ..utils.dtypes import dev_dtype
 
-# one lock for this module's learned-state dicts: CN-server threads
-# share them, and the add-then-evict sequences below must be atomic
+# the lock of this module's learned-state dict: CN-server threads
+# share it, and the add-then-evict sequence below must be atomic
 _STATE_LOCK = locks.Lock("exec.fused._STATE_LOCK")
 
 # plan shapes whose literal-masked trace host-synced (a masked value
@@ -79,12 +85,14 @@ _MASK_REFUSED: dict = {}    # guarded_by: _STATE_LOCK
 _MASK_REFUSED_MAX = 512
 
 # learned size-class ladder: literal-masked fragment shape -> {id of a
-# traced join or laddered sorted aggregate: factor} — the single-device
-# twin of MeshRunner._ladder, so a fragment's second statement (any
-# literal binding) starts at the right output class instead of
-# replaying the overflow walk
-_JOIN_LADDER: dict = {}     # guarded_by: _STATE_LOCK
-_JOIN_LADDER_MAX = 512
+# traced join or laddered sorted aggregate: factor}, so a fragment's
+# second statement (any literal binding) starts at the right output
+# class instead of replaying the overflow walk
+_LADDER = plancache.Ladder(512)
+
+# the plan nodes a fragment may hold (`physical.plan_key`'s `kinds`)
+_KINDS = (P.SeqScan, P.Filter, P.Project, P.Agg, P.Sort, P.Limit,
+          P.HashJoin)
 
 # Observability hook: when set, called as EXPORT_HOOK(tag, fn, args)
 # after each successful fused execution — the TPU lowering proof
@@ -93,74 +101,11 @@ _JOIN_LADDER_MAX = 512
 EXPORT_HOOK = None
 
 
-def _fuse_join_min_rows() -> int:
-    """Row floor (summed across the fragment's leaf tables) below which
-    join subtrees stay on the eager path — read per call so tests and
-    operators can flip it live."""
-    try:
-        return int(os.environ.get("OTB_FUSE_JOIN_MIN_ROWS", "8192"))
-    except ValueError:
-        return 8192
-
-
 def _mask_refused_add(k):
     with _STATE_LOCK:
         _MASK_REFUSED[k] = True
         while len(_MASK_REFUSED) > _MASK_REFUSED_MAX:
             _MASK_REFUSED.pop(next(iter(_MASK_REFUSED)))
-
-
-def _mask_key(base_key):
-    """Codec-free fingerprint of a fragment key.  The batching
-    signature and the _MASK_REFUSED ledger must be STABLE across the
-    staging boundary — codec classes are chosen at stage time, so a
-    signature read at classification (before the table ever staged)
-    would differ from the same fragment's post-stage signature,
-    splitting quarantine accounting and coalescing groups in two.
-    Mask refusal is a property of the plan structure + dtypes, not of
-    the encodings, so stripping the codec component loses nothing.
-    The PROGRAM keys keep the full _table_sig: encodings change traced
-    avals, and key and avals must agree."""
-    plan_key, tsig, baked_key, types_key, lit_types = base_key
-    return struct_key((plan_key, tuple(e[:3] for e in tsig),
-                       baked_key, types_key, lit_types))
-
-
-def _key_of_expr(e) -> tuple:
-    return e  # Expr dataclasses are frozen/hashable
-
-
-def _key_of(node) -> Optional[tuple]:
-    """Structural key for a physical subtree (None = unsupported)."""
-    t = type(node).__name__
-    if isinstance(node, P.SeqScan):
-        return (t, node.table.name, node.alias,
-                tuple(node.filters), tuple(node.outputs or ()))
-    if isinstance(node, P.Filter):
-        c = _key_of(node.child)
-        return None if c is None else (t, tuple(node.quals), c)
-    if isinstance(node, P.Project):
-        c = _key_of(node.child)
-        return None if c is None else (t, tuple(node.outputs), c)
-    if isinstance(node, P.Agg):
-        c = _key_of(node.child)
-        return None if c is None else (
-            t, node.mode, tuple(node.group_keys), tuple(node.aggs), c)
-    if isinstance(node, P.Sort):
-        c = _key_of(node.child)
-        return None if c is None else (
-            t, tuple((k, bool(d)) for k, d in node.keys), node.limit, c)
-    if isinstance(node, P.Limit):
-        c = _key_of(node.child)
-        return None if c is None else (t, node.count, node.offset, c)
-    if isinstance(node, P.HashJoin):
-        lk, rk = _key_of(node.left), _key_of(node.right)
-        if lk is None or rk is None:
-            return None
-        return (t, node.kind, tuple(node.left_keys),
-                tuple(node.right_keys), tuple(node.residual or ()),
-                lk, rk)
-    return None
 
 
 def _find_scans(node) -> Optional[list]:
@@ -199,36 +144,37 @@ def _find_scans(node) -> Optional[list]:
 
 
 def _plan_has_join(node) -> bool:
-    if isinstance(node, P.HashJoin):
-        return True
-    for attr in ("child", "left", "right"):
-        c = getattr(node, attr, None)
-        if isinstance(c, P.PhysNode) and _plan_has_join(c):
-            return True
-    return False
+    return any(isinstance(nd, P.HashJoin) for nd in P.walk(node))
 
 
-def _has_transformed_dup_dict(node, store) -> bool:
+def _below_join_floor(stores: dict) -> bool:
+    """Tiny JOIN fragments stay on the eager path: its per-join host
+    sync costs microseconds while a fresh XLA compile costs seconds, so
+    fusing only pays above a row floor, summed across the fragment's
+    leaf tables (0 = always fuse; read per call so tests and operators
+    can flip it live)."""
+    try:
+        floor = int(os.environ.get("OTB_FUSE_JOIN_MIN_ROWS", "8192"))
+    except ValueError:
+        floor = 8192
+    return sum(st.row_count() for st in stores.values()) < floor
+
+
+def _has_transformed_dup_dict(node, stores: dict) -> bool:
     """True when a group key is a TextExpr whose transformed dictionary
-    maps several codes to one string — key canonicalization builds a
-    host LUT per batch (executor._eval_group_keys), which is fine eager
-    but not worth special-casing under the trace: fall back."""
+    (in one of `stores`) maps several codes to one string — key
+    canonicalization builds a host LUT per batch
+    (executor._eval_group_keys), which is fine eager but not worth
+    special-casing under the trace: fall back."""
     for x in P.walk_exprs(node):
         if isinstance(x, E.TextExpr):
-            base = store.dicts.get(x.col.name.split(".", 1)[-1])
-            if base is not None:
-                vals = [x.apply(v) for v in base.values]
-                if len(set(vals)) < len(vals):
-                    return True
+            for store in stores.values():
+                base = store.dicts.get(x.col.name.split(".", 1)[-1])
+                if base is not None:
+                    vals = [x.apply(v) for v in base.values]
+                    if len(set(vals)) < len(vals):
+                        return True
     return False
-
-
-def _needed_columns(node, alias: str) -> set[str]:
-    need = set()
-    for x in P.walk_exprs(node):
-        if isinstance(x, E.Col) and x.name.startswith(alias + "."):
-            need.add(x.name.split(".", 1)[1])
-    return need
 
 
 # literal kinds that mask out of the fragment signature and ride as
@@ -292,30 +238,31 @@ def _bound_ctx(ctx, node):
 
 
 def _screen_fragment(ctx, node):
-    """Shared fusability screen: `(scans, stores)` when `node` is a
-    traceable fragment over live SeqScan leaves, else None.  Used by
-    the serial path (`_try_fused`) and the serving tier's batch
-    classification (`batch_signature`) so both agree on what can run
-    as one program."""
-    if not isinstance(node, (P.Agg, P.Project, P.Filter, P.Sort,
-                             P.Limit, P.HashJoin)):
+    """Shared fusability screen: `(stores, need_by_table)` when `node`
+    is a traceable fragment over live SeqScan leaves (`_find_scans`
+    admits `_KINDS` alone, so it has a plan key), else None; a
+    self-join's scans share one staged entry per table with the union
+    of their columns.  Used by the serial path (`_try_fused`) and the
+    serving tier's batch classification (`batch_signature`) so both
+    agree on what can run as one program."""
+    if isinstance(node, P.SeqScan) or not isinstance(node, _KINDS):
         return None   # bare SeqScan gains nothing
     scans = _find_scans(node)
     if not scans:
         return None
     stores: dict = {}
+    need_by_table: dict = {}
     for scan in scans:
         store = ctx.stores.get(scan.table.name)
         if store is None or \
                 (ctx.staged and scan.table.name in ctx.staged):
             return None
         stores[scan.table.name] = store
-    if _key_of(node) is None:
+        need_by_table.setdefault(scan.table.name, set()).update(
+            P.needed_columns(node, scan.alias))
+    if _has_transformed_dup_dict(node, stores):
         return None
-    for store in stores.values():
-        if _has_transformed_dup_dict(node, store):
-            return None
-    return scans, stores
+    return stores, need_by_table
 
 
 def _table_sig(stores: dict) -> tuple:
@@ -359,188 +306,260 @@ def _call_args(staged_arrs: dict, nrows: dict, snapshot_ts, txid,
             pvals, {t: np.int64(nrows[t]) for t in sorted(nrows)})
 
 
+def _stage(cache, stores: dict, need_by_table: dict) -> tuple:
+    """`(arrays, row counts)` by table from the device cache (a pool
+    lookup, version-keyed; a miss stages under it), ONCE, outside the
+    trace and BEFORE the key is made (`_table_sig`): a cold start must
+    mint the key the warm repeat will see, or the census sanitizer
+    would count a phantom recompile."""
+    arrs: dict = {}
+    ns: dict = {}
+    for t, need in sorted(need_by_table.items()):
+        arrs[t], ns[t] = cache.get(stores[t], sorted(need))
+    return arrs, ns
+
+
+def _dbatch(meta, cols, valid, nulls):
+    from .executor import DBatch
+    return DBatch(dict(cols), valid, dict(meta["types"]),
+                  dict(meta["dicts"]), dict(nulls))
+
+
+# _Prepared.call: a traced value fed a host branch, and the shape is
+# in _MASK_REFUSED from now on: its literals bake
+_REFUSED = object()
+
+
+class _Prepared:
+    """A fragment ready to call: what names its compiled programs and
+    how one is called, written ONCE for `_try_fused`, `FragmentProgram`
+    and the scheduler's batch.  `base_key` is (plan key, table
+    signature, baked values, traced types, masked literals' types); a
+    program's key is `base_key + suffix + (factors,)`
+    (plancache._census_classes reads it by position).  Spans, a tier's
+    counters, and what a refusal or an error means stay the callers'."""
+
+    __slots__ = ("ctx", "plan", "lits", "traced_names", "baked",
+                 "base_key", "suffix", "batch", "lkey", "factors",
+                 "_mkey")
+
+    @classmethod
+    def of(cls, ctx, plan, lits: list, stores: dict, suffix: tuple = (),
+           batch: bool = False) -> Optional["_Prepared"]:
+        """`plan` is the fragment as it will be traced (`lits` its
+        masked literals, none where it runs baked), `ctx.params` are
+        bound (`_bound_ctx`) and `stores` staged.  `suffix` is what the
+        caller's programs add to the key between `base_key` and the
+        factors: nothing, `("__morsel", chunk class)` or `("__batch",
+        batch class)`; `batch` builds the program mapped over a batch
+        axis.  None where the fragment cannot be keyed."""
+        from .executor import split_params
+        key = P.plan_key(plan, _KINDS)
+        if key is None:
+            return None
+        self = cls()
+        self.ctx, self.plan, self.lits = ctx, plan, lits
+        self.suffix, self.batch = suffix, batch
+        # numeric params ride as traced inputs beside the masked
+        # literals (a re-planned scalar subquery value must not
+        # recompile the fragment either); the rest is baked and keyed
+        self.traced_names, self.baked = split_params(ctx.params)
+        baked_key = tuple(sorted(
+            (k, v) for k, (v, _t) in self.baked.items()
+            if isinstance(v, (str, bool, type(None)))))
+        if len(baked_key) != len(self.baked):
+            return None  # non-scalar param: don't risk a stale closure
+        self.base_key = (
+            key, _table_sig(stores), baked_key,
+            tuple((k, ctx.params[k][1]) for k in self.traced_names),
+            tuple(t for _n, _v, t in lits))
+        try:
+            hash(self.base_key)
+        except TypeError:
+            return None  # unhashable plan content (e.g. an unrewritten link)
+        self._mkey = self.lkey = self.factors = None
+        return self
+
+    def mask_key(self):
+        """Codec-free fingerprint of `base_key`: the batching signature
+        and the _MASK_REFUSED ledger must be STABLE across the staging
+        boundary — codec classes are chosen at stage time, so a
+        signature read at classification (before the table ever staged)
+        would differ from the same fragment's post-stage signature,
+        splitting quarantine accounting and coalescing groups in two.
+        Mask refusal is a property of the plan structure + dtypes, not
+        of the encodings, so stripping the codec component loses
+        nothing.  The PROGRAM keys keep the full _table_sig: encodings
+        change traced avals, and key and avals must agree."""
+        if self._mkey is None:
+            key, tsig, *rest = self.base_key
+            self._mkey = struct_key(
+                (key, tuple(e[:3] for e in tsig), *rest))
+        return self._mkey
+
+    def mask_refused(self) -> bool:
+        """Did this shape's literal-masked trace host-sync before?  (A
+        membership test of a dict is atomic: no lock on a read's path.)"""
+        return self.mask_key() in _MASK_REFUSED
+
+    def recall(self) -> None:
+        """Start from the factors the last statement of this shape (any
+        literal binding) ended on."""
+        self.lkey = struct_key(self.base_key)
+        (self.factors,) = _LADDER.recall(self.lkey) or ({},)
+
+    def traced_params(self) -> list:
+        """`(value, SqlType)` of what one call hands over, in traced
+        order: numeric parameters, then masked literals."""
+        return [self.ctx.params[k] for k in self.traced_names] \
+            + [(v, t) for _n, v, t in self.lits]
+
+    def program(self) -> tuple:
+        """`(full_key, fn, meta, fresh)`: the program of the current
+        factors, built (`fresh`) where the cache does not hold it; `fn`
+        is None where the shape fell back for good."""
+        full_key = self.base_key + self.suffix \
+            + (tuple(sorted(self.factors.items())),)
+        hit = plancache.FUSED.get(full_key)
+        fresh = hit is None
+        if fresh:
+            hit = plancache.FUSED.put(
+                full_key, _build_program(
+                    self.ctx, self.plan, self.baked, self.traced_names,
+                    self.lits, self.factors, batch=self.batch))
+        return (full_key, *hit, fresh)
+
+    def call(self, full_key, fn, args, tier: str):
+        """One call of `fn`: its `(cols, valid, nulls, join_req)`.
+        `_REFUSED` where a MASKED literal fed a host sync (value-
+        dependent program structure): the shape is remembered, under
+        the key `mask_refused` probes, and its program dropped; the
+        caller retries baked (a batch, which cannot, is refused with
+        or without a literal and goes serial).  None where there was no literal to
+        blame (a host sync slipped through the fusability screen): the
+        program's key keeps a `(None, None)` for good.  Any other error
+        drops the program and is the caller's."""
+        from .executor import TRACE_HOST_SYNC, stats_tier
+        t0 = time.perf_counter()
+        try:
+            with stats_tier(tier):
+                # trace-time executor counters attribute to the caller's
+                # tier (re-executions don't re-trace)
+                out = fn(*args)
+        except TRACE_HOST_SYNC:
+            if self.lits or self.batch:
+                _mask_refused_add(self.mask_key())
+                plancache.FUSED.pop(full_key)
+                return _REFUSED
+            plancache.FUSED.replace(full_key, (None, None))
+            return None
+        except Exception:
+            plancache.FUSED.pop(full_key)
+            raise
+        plancache.FUSED.record_call(fn, t0)
+        return out
+
+    def settle(self, meta, join_req, note) -> Optional[bool]:  # otblint: sync-boundary
+        """The size-class ladder's one read: the program reports each
+        traced join's required output rows (and each laddered sorted
+        aggregate's groups, executor._agg_class) and an overflow grows
+        exactly that operator's factor: ONE host sync a program call,
+        never one a join, told to `note` (`d2h`, `d2h_bytes`).  True:
+        every class held; False: one grew, call again at the new
+        factors; None: the ladder is exhausted.  What was learned
+        persists per shape."""
+        caps = meta.get("join_caps") or ()
+        if not caps:
+            return True
+        req = np.asarray(jax.device_get(join_req))
+        note(d2h=1, d2h_bytes=req.nbytes)
+        if self.batch:
+            # required totals arrive stacked (K, njoins): grow to the
+            # most any batch element needs
+            req = req.max(axis=0)
+        grew = False
+        for (jid, cap), r in zip(caps, req):
+            if r > cap:
+                if not plancache.Ladder.grow(self.factors, jid, cap, r):
+                    return None
+                grew = True
+        _LADDER.remember(self.lkey, self.factors)
+        return not grew
+
+
 def try_fused(executor, node) -> Optional[object]:
     """Execute `node` as one jitted program, or None if unsupported."""
     return _try_fused(executor, node, allow_mask=True)
 
 
-def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:  # otblint: sync-boundary
+def _try_fused(executor, node, allow_mask: bool) -> Optional[object]:
     ctx = executor.ctx
     screened = _screen_fragment(ctx, node)
     if screened is None:
         return None
-    scans, stores = screened
+    stores, need_by_table = screened
 
-    # canonical fragment signature: literal-masked plan + per-table
-    # components (store identity + dictionary lengths — dictionaries
-    # are baked trace constants) + dtypes; the masked literals ride as
-    # traced inputs alongside numeric init-plan params (re-planned
-    # scalar subquery values must not recompile the fragment either);
-    # everything else (strings, NULLs — they change program structure)
-    # is baked and keyed
     lits: list = []
-    exec_node_plan = _mask_node(node, lits) if allow_mask else node
-    key = _key_of(exec_node_plan)
-    if key is None:
-        return None
-
-    # stage ONCE outside the trace (device cache, version-keyed) and
-    # BEFORE computing the key: staging chooses/validates the codec
-    # descriptors whose quantized classes are part of _table_sig — a
-    # cold start must mint the same key the warm repeat will see, or
-    # the census sanitizer would count a phantom recompile.  A
-    # self-join's scans share one staged entry per table with the
-    # union of their needed columns.
-    need_by_table: dict = {}
-    for scan in scans:
-        need_by_table.setdefault(scan.table.name, set()).update(
-            _needed_columns(node, scan.alias))
-    staged_arrs: dict = {}
-    staged_ns: dict = {}
-    # `inputs`: the staged arrays (a pool lookup; a miss stages under
-    # it) with each table's row count; every host scalar of the call
-    # rides its argument tree (`_call_args`): nothing is put by itself
+    plan = _mask_node(node, lits) if allow_mask else node
+    # `inputs`: the staged arrays with each table's row count; every
+    # host scalar of the call rides its argument tree (`_call_args`):
+    # nothing is put by itself
     with obs_trace.span("inputs"):
-        for t, need in sorted(need_by_table.items()):
-            staged_arrs[t], staged_ns[t] = ctx.cache.get(
-                stores[t], sorted(need))
-
-    table_sig = _table_sig(stores)
-    ctx = _bound_ctx(ctx, exec_node_plan)
-    traced_names = tuple(sorted(
-        k for k, (v, _t) in ctx.params.items()
-        if isinstance(v, (int, float)) and not isinstance(v, bool)))
-    baked = {k: ctx.params[k] for k in ctx.params
-             if k not in traced_names}
-    baked_key = tuple(sorted(
-        (k, v) for k, (v, _t) in baked.items()
-        if isinstance(v, (str, bool, type(None)))))
-    if len(baked_key) != len(baked):
-        return None  # non-scalar param: don't risk a stale closure
-    types_key = tuple((k, ctx.params[k][1]) for k in traced_names)
-    lit_types = tuple(t for _n, _v, t in lits)
-    base_key = (key, table_sig, baked_key, types_key, lit_types)
-    try:
-        hash(base_key)
-    except TypeError:
-        return None  # unhashable plan content (e.g. an unrewritten link)
-    if lits and _mask_key(base_key) in _MASK_REFUSED:
+        staged_arrs, staged_ns = _stage(ctx.cache, stores, need_by_table)
+    prep = _Prepared.of(_bound_ctx(ctx, plan), plan, lits, stores)
+    if prep is None:
+        return None
+    if lits and prep.mask_refused():
         return _try_fused(executor, node, allow_mask=False)
 
-    has_join = _plan_has_join(exec_node_plan)
-    if has_join and sum(
-            st.row_count() for st in stores.values()) \
-            < _fuse_join_min_rows():
-        # tiny join fragments: the eager path's per-join host sync
-        # costs microseconds while a fresh XLA compile costs seconds —
-        # fusing only pays above a row floor (0 = always fuse)
+    has_join = _plan_has_join(plan)
+    if has_join and _below_join_floor(stores):
         return None
 
-    lkey = struct_key(base_key)
-    factors: dict = dict(_JOIN_LADDER.get(lkey, {}))
+    prep.recall()
+    args = _call_args(staged_arrs, staged_ns, ctx.snapshot_ts, ctx.txid,
+                      prep.traced_params())
+    from .executor import bump_stat
 
-    args = _call_args(
-        staged_arrs, staged_ns, ctx.snapshot_ts, ctx.txid,
-        [ctx.params[k] for k in traced_names]
-        + [(v, t) for _n, v, t in lits])
-    from .executor import bump_stat, stats_tier
-
-    for _attempt in range(24):
-        full_key = base_key + (tuple(sorted(factors.items())),)
-        hit = plancache.FUSED.get(full_key)
-        if hit is None:
-            hit = plancache.FUSED.put(
-                full_key, _build_program(ctx, exec_node_plan, baked,
-                                         traced_names, lits, factors))
-        elif has_join and hit[0] is not None:
+    for _attempt in range(plancache.Ladder.ATTEMPTS):
+        full_key, fn, meta, fresh = prep.program()
+        if has_join and not fresh and fn is not None:
             bump_stat("fused", "fused_join_hits")
-        fn, meta = hit
         if fn is None:
             return None  # permanently fell back for this plan shape
-        t0 = time.perf_counter()
-        # the execute span covers the program call AND the join-overflow
-        # device_get below — that device read is the tier's ONE legal
-        # sync boundary, so the span's wall time includes device work
+        # the execute span covers the program call AND settle's
+        # device_get of the overflow vector — that device read is the
+        # tier's ONE legal sync boundary, so the span's wall time
+        # includes device work
         with (obs_trace.span("execute", tier="fused")
               if obs_trace.ENABLED else obs_trace.NULL_SPAN) as sp:
-            try:
-                with stats_tier("fused"):
-                    # trace-time executor counters attribute to the
-                    # fused tier (re-executions don't re-trace)
-                    cols, valid, nulls, join_req = fn(*args)
-            except (jax.errors.TracerBoolConversionError,
-                    jax.errors.ConcretizationTypeError,
-                    jax.errors.TracerArrayConversionError):
-                if lits:
-                    # a MASKED literal fed a host-sync (value-dependent
-                    # program structure): remember and retry with
-                    # literals baked
-                    _mask_refused_add(_mask_key(base_key))
-                    plancache.FUSED.pop(full_key)
-                    return _try_fused(executor, node, allow_mask=False)
-                # a host-sync slipped through the fusability screen:
-                # permanently fall back for this plan shape
-                plancache.FUSED.replace(full_key, (None, None))
+            got = prep.call(full_key, fn, args, "fused")
+            if got is _REFUSED:
+                return _try_fused(executor, node, allow_mask=False)
+            if got is None:
                 return None
-            except Exception:
-                plancache.FUSED.pop(full_key)
-                raise
-            plancache.FUSED.record_call(fn, t0)
-
-            # size-class ladder: the program reports each traced join's
-            # required output rows (and each laddered sorted aggregate's
-            # groups, executor._agg_class); overflow grows exactly that
-            # operator's factor and retraces (one host sync per program
-            # call — never per join).  Learned factors persist per shape.
-            caps = meta.get("join_caps") or ()
-            if caps:
-                req = np.asarray(jax.device_get(join_req))
-                sp.set(d2h=1, d2h_bytes=req.nbytes)
-                grew = False
-                for (jid, cap), r in zip(caps, req):
-                    if r <= cap:
-                        continue
-                    # the program reports the EXACT required rows
-                    # (unlike the mesh tier's overflow bit): jump the
-                    # factor straight to the class that fits — ONE
-                    # retrace, not a doubling walk of compiles
-                    mult = 1
-                    while cap * mult < r:
-                        mult *= 2
-                    factors[jid] = factors.get(jid, 1) * mult
-                    if factors[jid] > 4096:
-                        return None  # ladder exhausted: eager fallback
-                    grew = True
-                if grew:
-                    _ladder_remember(lkey, factors)
-                    # this call's output overflowed a join class: the
-                    # statement replays one class up (`retraces` of
-                    # summary() sums these)
-                    sp.set(retraces=1)
-                    continue
-            if caps:
-                _ladder_remember(lkey, factors)
+            cols, valid, nulls, join_req = got
+            held = prep.settle(meta, join_req, sp.set)
+            if held is None:
+                return None  # ladder exhausted: eager fallback
+            if not held:
+                # this call's output overflowed a class: the statement
+                # replays one class up (`retraces` of summary() sums
+                # these)
+                sp.set(retraces=1)
+                continue
             # what the program holds, fixed when it was traced
             sp.set(**meta.get("shape", {}))
             if EXPORT_HOOK is not None:
                 EXPORT_HOOK("fused", fn, args)
-            from .executor import DBatch
-            out = DBatch(dict(cols), valid, dict(meta["types"]),
-                         dict(meta["dicts"]), dict(nulls))
+            out = _dbatch(meta, cols, valid, nulls)
         # `release`: the overflow vector, the one device buffer the
         # call itself made, is dropped here, not on the way out, so
         # that what freeing it costs has a name
         with obs_trace.span("release"):
-            del join_req
+            del join_req, got
         return out
     return None  # overflow never converged: eager fallback
-
-
-def _ladder_remember(lkey, factors: dict):
-    with _STATE_LOCK:
-        _JOIN_LADDER[lkey] = dict(factors)
-        while len(_JOIN_LADDER) > _JOIN_LADDER_MAX:
-            _JOIN_LADDER.pop(next(iter(_JOIN_LADDER)))
 
 
 def _build_program(ctx, frag_plan, baked, traced_names, lits, factors,
@@ -622,14 +641,12 @@ class FragSig:
     `sig` run the same compiled program and differ only in their
     (snapshot, txid, literal-value) bindings — exactly the batching
     the serving tier exploits."""
-    sig: object            # hashable canonical signature (struct_key)
+    sig: object            # hashable canonical signature (_mask_key)
     plan: object           # literal-masked physical plan
     lits: list             # this query's [(name, value, type)] bindings
     stores: dict           # table name -> TableStore
     cache: object          # DeviceTableCache handle for staging
     need_by_table: dict    # table name -> needed column set
-    plan_key: tuple        # _key_of(masked plan)
-    lit_types: tuple
 
     def version_key(self) -> tuple:
         """Per-table store-version tuple over this fragment's scanned
@@ -654,38 +671,20 @@ def batch_signature(ctx, node) -> Optional[FragSig]:
     screened = _screen_fragment(ctx, node)
     if screened is None:
         return None
-    scans, stores = screened
+    stores, need_by_table = screened
 
     lits: list = []
     masked = _mask_node(node, lits)
-    plan_key = _key_of(masked)
-    if plan_key is None:
-        return None
-    lit_types = tuple(t for _n, _v, t in lits)
-    base_key = (plan_key, _table_sig(stores), (), (), lit_types)
-    try:
-        hash(base_key)
-    except TypeError:
-        return None
-    sig = _mask_key(base_key)   # stable pre/post staging (codec-free)
-    with _STATE_LOCK:
-        refused = sig in _MASK_REFUSED
-    if refused:
+    prep = _Prepared.of(ctx, masked, lits, stores)
+    if prep is None or prep.mask_refused():
         return None  # masked trace host-synced before: literals bake
-
-    if _plan_has_join(masked) \
-            and sum(st.row_count() for st in stores.values()) \
-            < _fuse_join_min_rows():
+    if _plan_has_join(masked) and _below_join_floor(stores):
         return None
-
-    need_by_table: dict = {}
-    for scan in scans:
-        need_by_table.setdefault(scan.table.name, set()).update(
-            _needed_columns(node, scan.alias))
-    return FragSig(sig=sig, plan=masked, lits=lits,
+    # the signature is the mask ledger's key: stable before and after
+    # staging (codec-free)
+    return FragSig(sig=prep.mask_key(), plan=masked, lits=lits,
                    stores=stores, cache=ctx.cache,
-                   need_by_table=need_by_table,
-                   plan_key=plan_key, lit_types=lit_types)
+                   need_by_table=need_by_table)
 
 
 # ---------------------------------------------------------------------------
@@ -701,350 +700,193 @@ class FragmentProgram:
     chunk's padded shape (`chunk_rows`, chunk_class-quantized) is part
     of the cache key (`("__morsel", class)`), the chunk COUNT and row
     offsets are not, so a thousand-chunk stream is one compile.  Mask
-    fallback and the learned join-size ladder work exactly as on the
-    serial path: a masked literal that host-syncs rebuilds baked, a
+    fallback and the size-class ladder are the serial path's
+    (`_Prepared`): a masked literal that host-syncs rebuilds baked, a
     join overflow re-runs the SAME chunk one factor class up."""
 
     def __init__(self, ctx, plan, chunk_rows: int):
         from ..storage.batch import chunk_class
         self.ctx = _bound_ctx(ctx, plan)
         self.plan = plan
-        self.chunk_rows = int(chunk_rows)
-        self._chunk_key = ("__morsel", chunk_class(int(chunk_rows)))
-        self._ok = self._prepare(allow_mask=True)
+        self._suffix = (("__morsel", chunk_class(int(chunk_rows))),)
+        self._prep = self._prepare(allow_mask=True)
 
-    def _prepare(self, allow_mask: bool) -> bool:
-        ctx = self.ctx
+    def _prepare(self, allow_mask: bool) -> Optional[_Prepared]:
         lits: list = []
-        exec_plan = _mask_node(self.plan, lits) if allow_mask \
-            else self.plan
-        key = _key_of(exec_plan)
-        if key is None:
-            return False
-        stores = {nd.table.name: ctx.stores[nd.table.name]
-                  for nd in _morsel_walk(self.plan)
+        plan = _mask_node(self.plan, lits) if allow_mask else self.plan
+        stores = {nd.table.name: self.ctx.stores[nd.table.name]
+                  for nd in P.walk(self.plan)
                   if isinstance(nd, P.SeqScan)}
-        for store in stores.values():
-            if _has_transformed_dup_dict(self.plan, store):
-                return False
-        self.traced_names = tuple(sorted(
-            k for k, (v, _t) in ctx.params.items()
-            if isinstance(v, (int, float)) and not isinstance(v, bool)))
-        baked = {k: ctx.params[k] for k in ctx.params
-                 if k not in self.traced_names}
-        baked_key = tuple(sorted(
-            (k, v) for k, (v, _t) in baked.items()
-            if isinstance(v, (str, bool, type(None)))))
-        if len(baked_key) != len(baked):
-            return False  # non-scalar param: don't risk a stale closure
-        types_key = tuple((k, ctx.params[k][1])
-                          for k in self.traced_names)
-        lit_types = tuple(t for _n, _v, t in lits)
-        base_key = (key, _table_sig(stores), baked_key, types_key,
-                    lit_types)
-        try:
-            hash(base_key)
-        except TypeError:
-            return False
-        if lits and _mask_key(base_key) in _MASK_REFUSED:
+        if _has_transformed_dup_dict(self.plan, stores):
+            return None
+        prep = _Prepared.of(self.ctx, plan, lits, stores, self._suffix)
+        if prep is None:
+            return None
+        if lits and prep.mask_refused():
             return self._prepare(allow_mask=False)
-        self.exec_plan = exec_plan
-        self.lits = lits
-        self.baked = baked
-        self.base_key = base_key
-        self.lkey = struct_key(base_key)
-        with _STATE_LOCK:
-            self.factors = dict(_JOIN_LADDER.get(self.lkey, {}))
-        return True
+        prep.recall()
+        return prep
 
     def ok(self) -> bool:
-        return self._ok
+        return self._prep is not None
 
-    def run(self, staged_arrs: dict, staged_ns: dict, snapshot_ts,
-            txid):  # otblint: sync-boundary
+    def run(self, staged_arrs: dict, staged_ns: dict, snapshot_ts, txid):
         """One chunk through the compiled fragment.  `staged_arrs` maps
         every leaf table to its traced arrays — the streamed table's
         window plus the resident (pinned) sides — and `staged_ns` to
         its live row count.  Returns a device DBatch, or None when the
-        shape permanently refuses fusion (caller declines the stream)."""
-        from .executor import DBatch, stats_tier
-        ctx = self.ctx
-        for _attempt in range(24):
-            full_key = self.base_key + (
-                self._chunk_key, tuple(sorted(self.factors.items())))
-            hit = plancache.FUSED.get(full_key)
-            if hit is None:
-                hit = plancache.FUSED.put(
-                    full_key, _build_program(
-                        ctx, self.exec_plan, self.baked,
-                        self.traced_names, self.lits, self.factors))
-            fn, meta = hit
+        shape permanently refuses fusion (caller declines the stream);
+        an error (OOM among them: the driver's downshift ladder wants
+        it) is raised."""
+        for _attempt in range(plancache.Ladder.ATTEMPTS):
+            prep = self._prep
+            full_key, fn, meta, _fresh = prep.program()
             if fn is None:
                 return None  # permanently fell back for this shape
             # built per attempt: a refused mask re-prepares with its
             # literals baked, and the traced list shrinks with it
-            args = _call_args(
-                staged_arrs, staged_ns, snapshot_ts, txid,
-                [ctx.params[k] for k in self.traced_names]
-                + [(v, t) for _n, v, t in self.lits])
-            t0 = time.perf_counter()
-            try:
-                with stats_tier("morsel"):
-                    cols, valid, nulls, join_req = fn(*args)
-            except (jax.errors.TracerBoolConversionError,
-                    jax.errors.ConcretizationTypeError,
-                    jax.errors.TracerArrayConversionError):
-                plancache.FUSED.pop(full_key)
-                if self.lits:
-                    # a masked literal fed value-dependent structure:
-                    # remember, rebuild baked, re-run this chunk
-                    _mask_refused_add(struct_key(self.base_key))
-                    if self._prepare(allow_mask=False):
-                        continue
+            args = _call_args(staged_arrs, staged_ns, snapshot_ts, txid,
+                              prep.traced_params())
+            got = prep.call(full_key, fn, args, "morsel")
+            if got is _REFUSED:
+                # rebuild baked, re-run this chunk
+                self._prep = self._prepare(allow_mask=False)
+                if self._prep is None:
                     return None
-                plancache.FUSED.replace(full_key, (None, None))
+                continue
+            if got is None:
                 return None
-            except Exception:
-                plancache.FUSED.pop(full_key)
-                raise  # OOM must reach the driver's downshift ladder
-            plancache.FUSED.record_call(fn, t0)
-
-            caps = meta.get("join_caps") or ()
-            if caps:
-                req = np.asarray(jax.device_get(join_req))
-                obs_trace.count(d2h=1, d2h_bytes=req.nbytes)
-                grew = False
-                for (jid, cap), r in zip(caps, req):
-                    if r <= cap:
-                        continue
-                    mult = 1
-                    while cap * mult < r:
-                        mult *= 2
-                    self.factors[jid] = self.factors.get(jid, 1) * mult
-                    if self.factors[jid] > 4096:
-                        return None  # ladder exhausted
-                    grew = True
-                if grew:
-                    _ladder_remember(self.lkey, self.factors)
-                    continue  # SAME chunk, one factor class up
-            if caps:
-                _ladder_remember(self.lkey, self.factors)
-            return DBatch(dict(cols), valid, dict(meta["types"]),
-                          dict(meta["dicts"]), dict(nulls))
+            cols, valid, nulls, join_req = got
+            held = prep.settle(meta, join_req, obs_trace.count)
+            if held is None:
+                return None  # ladder exhausted
+            if held:
+                return _dbatch(meta, cols, valid, nulls)
+            # else the SAME chunk, one factor class up
         return None  # overflow never converged
 
 
-def _morsel_walk(node):
-    yield node
-    for attr in ("child", "left", "right"):
-        c = getattr(node, attr, None)
-        if isinstance(c, P.PhysNode):
-            yield from _morsel_walk(c)
+class StagedBatch(NamedTuple):
+    """A coalesced batch after the STAGE phase: keys and learned
+    factors made, the call's arguments built (literal and MVCC columns
+    as numpy vectors of the batch class), leaf tables resident on
+    device — host work only, no program launched yet.  The pipelined
+    scheduler stages batch i+1 while batch i computes."""
+    prep: _Prepared
+    k: int                 # live queries (the class pads the rest)
+    args: tuple
 
 
-def _batch_class(k: int) -> int:
-    """Pad batch size to a power of two so K concurrent arrivals hit a
-    bounded set of compiled batch classes."""
-    c = 1
-    while c < k:
-        c *= 2
-    return c
-
-
-class StagedBatch:
-    """A coalesced batch after the STAGE phase: keys computed, the
-    call's arguments built (`_call_args`: literal and MVCC columns as
-    numpy vectors of K), leaf tables resident on device — host work
-    only, no program launched yet.  The pipelined scheduler stages batch
-    i+1 while batch i computes; `launch_fused_batch` turns one of these
-    into an in-flight dispatch."""
-
-    __slots__ = ("info", "k", "kclass", "base_key", "lkey", "args",
-                 "bctx", "factors")
-
-
-class FusedFlight:
+class FusedFlight(NamedTuple):
     """One launched (asynchronously dispatched) coalesced batch.  The
     device arrays here are futures — JAX async dispatch returned before
     compute finished; `finish_fused_batch` performs the only host sync
     (the join-ladder check) and demuxes per-query views."""
-
-    __slots__ = ("sb", "fn", "meta", "cols", "valid", "nulls",
-                 "join_req", "attempt")
+    sb: StagedBatch
+    meta: dict
+    out: tuple             # the program's (cols, valid, nulls, join_req)
 
 
 def stage_fused_batch(info: FragSig, queries: list) \
         -> Optional[StagedBatch]:
-    """STAGE phase of a coalesced dispatch: recompute the dispatch-time
-    key, stack per-query MVCC/literal columns, and upload every needed
-    table through the device cache.  Returns None when the batched path
-    refuses this group (mask-refused shape, empty batch)."""
+    """STAGE phase of a coalesced dispatch: upload every needed table
+    through the device cache, recompute the dispatch-time key, and
+    stack per-query MVCC/literal columns.  Returns None when the
+    batched path refuses this group (mask-refused shape, empty batch)."""
+    from ..storage.batch import next_pow2
     from .executor import ExecContext
 
     if not queries:
         return None
-    # stage ONCE for the whole batch (device cache, version-keyed) —
-    # BEFORE the key: staging chooses/validates the codec descriptors
-    # whose quantized classes ride _table_sig (serial-path property)
-    staged_arrs: dict = {}
-    staged_ns: dict = {}
-    for t, need in sorted(info.need_by_table.items()):
-        staged_arrs[t], staged_ns[t] = info.cache.get(
-            info.stores[t], sorted(need))
-
-    # recompute the table signature at dispatch time: DML between
-    # classification and dispatch can grow a TEXT dictionary, and the
-    # dictionaries are baked trace constants — the key must match what
-    # the program will actually bake (same property as the serial path)
-    base_key = (info.plan_key, _table_sig(info.stores), (), (),
-                info.lit_types)
-    with _STATE_LOCK:
-        refused = _mask_key(base_key) in _MASK_REFUSED
-    if refused:
+    staged_arrs, staged_ns = _stage(info.cache, info.stores,
+                                    info.need_by_table)
+    # the key is made at dispatch time: DML between classification and
+    # dispatch can grow a TEXT dictionary, and the dictionaries are
+    # baked trace constants — the key must match what the program will
+    # actually bake (same property as the serial path)
+    # the batch pads to a power of two: K concurrent arrivals hit a
+    # bounded set of compiled batch classes
+    k = len(queries)
+    kclass = next_pow2(k, 1)
+    prep = _Prepared.of(ExecContext(info.stores, 0, 0, info.cache),
+                        info.plan, info.lits, info.stores,
+                        (("__batch", kclass),), batch=True)
+    if prep is None or prep.mask_refused():
         return None
-
-    sb = StagedBatch()
-    sb.info = info
-    sb.base_key = base_key
-    sb.lkey = struct_key(base_key)
-    sb.k = len(queries)
-    sb.kclass = _batch_class(sb.k)
-    padded = list(queries) + [queries[-1]] * (sb.kclass - sb.k)
-    sb.args = _call_args(
+    prep.recall()
+    padded = list(queries) + [queries[-1]] * (kclass - k)
+    return StagedBatch(prep, k, _call_args(
         staged_arrs, staged_ns, [q[0] for q in padded],
         [q[1] for q in padded],
         [([q[2][i] for q in padded], t)
-         for i, t in enumerate(info.lit_types)])
-
-    with _STATE_LOCK:
-        sb.factors = dict(_JOIN_LADDER.get(sb.lkey, {}))
-    sb.bctx = ExecContext(info.stores, 0, 0, info.cache)
-    return sb
+         for i, (_n, _v, t) in enumerate(info.lits)]))
 
 
-def launch_fused_batch(sb: StagedBatch, attempt: int = 0) \
-        -> Optional[FusedFlight]:
+def launch_fused_batch(sb: StagedBatch) -> Optional[FusedFlight]:
     """LAUNCH phase: program lookup/compile + ONE asynchronous dispatch.
     No host sync happens here — the returned flight's arrays are device
-    futures.  Returns None when the program permanently declined this
-    shape (caller falls back to serial); re-raises device OOM so the
-    scheduler's pressure ladder can respond."""
-    from .executor import stats_tier
-
-    full_key = sb.base_key + (("__batch", sb.kclass),
-                              tuple(sorted(sb.factors.items())))
-    hit = plancache.FUSED.get(full_key)
-    if hit is None:
-        hit = plancache.FUSED.put(
-            full_key, _build_program(sb.bctx, sb.info.plan, {}, (),
-                                     sb.info.lits, sb.factors,
-                                     batch=True))
-    fn, meta = hit
+    futures.  Returns None when the program declined this shape (a
+    masked literal fed value-dependent program structure: it bakes its
+    literals and is never batchable) or failed (caller falls back to
+    serial, which reproduces and attributes the error per query).  A
+    device allocation failure must REACH the scheduler: its pressure
+    ladder (evict-coldest + retry, then degrade to spill) is the
+    correct response — a serial fallback would just re-discover the
+    same OOM K times."""
+    full_key, fn, meta, _fresh = sb.prep.program()
     if fn is None:
         return None
-    t0 = time.perf_counter()
     try:
-        with stats_tier("fused"):
-            cols, valid, nulls, join_req = fn(*sb.args)
-    except (jax.errors.TracerBoolConversionError,
-            jax.errors.ConcretizationTypeError,
-            jax.errors.TracerArrayConversionError):
-        # a masked literal fed value-dependent program structure:
-        # this shape bakes its literals — never batchable
-        _mask_refused_add(struct_key(sb.base_key))
-        plancache.FUSED.pop(full_key)
-        return None
+        got = sb.prep.call(full_key, fn, sb.args, "fused")
     except Exception as e:
         from . import shield
         if shield.is_oom(e):
-            # device allocation failure must REACH the scheduler:
-            # its pressure ladder (evict-coldest + retry, then
-            # degrade to spill) is the correct response — a serial
-            # fallback would just re-discover the same OOM K times
-            plancache.FUSED.pop(full_key)
             raise
-        # fall back to serial execution, which reproduces (and
-        # attributes) the error per query
-        plancache.FUSED.pop(full_key)
         return None
-    plancache.FUSED.record_call(fn, t0)
-
-    fl = FusedFlight()
-    fl.sb = sb
-    fl.fn, fl.meta = fn, meta
-    fl.cols, fl.valid, fl.nulls = cols, valid, nulls
-    fl.join_req = join_req
-    fl.attempt = attempt
-    return fl
+    if got is None or got is _REFUSED:
+        return None
+    return FusedFlight(sb, meta, got)
 
 
-def finish_fused_batch(flight: FusedFlight) -> Optional[list]:  # otblint: sync-boundary
+def finish_fused_batch(flight: FusedFlight) -> Optional[list]:
     """FINISH phase: the ONLY host sync of a coalesced dispatch — the
     join-ladder overflow check reads `join_req` back (which also
     surfaces any deferred device error from the async launch), growing
     factors and relaunching until the batch converges.  Returns the
     per-query DBatch device views, or None when the batched path gave
     up (caller falls back to serial)."""
-    from .executor import DBatch
-
-    while True:
-        sb = flight.sb
-        caps = flight.meta.get("join_caps") or ()
-        if caps:
-            # per-join required totals arrive stacked (K, njoins):
-            # grow to the max any batch element needs
-            req = np.asarray(jax.device_get(flight.join_req))
-            obs_trace.count(d2h=1, d2h_bytes=req.nbytes)
-            req = req.max(axis=0)
-            grew = False
-            for (jid, cap), r in zip(caps, req):
-                if r <= cap:
-                    continue
-                mult = 1
-                while cap * mult < r:
-                    mult *= 2
-                sb.factors[jid] = sb.factors.get(jid, 1) * mult
-                if sb.factors[jid] > 4096:
-                    return None
-                grew = True
-            if grew:
-                _ladder_remember(sb.lkey, sb.factors)
-                if flight.attempt + 1 >= 24:
-                    return None  # overflow never converged
-                flight = launch_fused_batch(sb, attempt=flight.attempt + 1)
-                if flight is None:
-                    return None
-                continue
-        if caps:
-            _ladder_remember(sb.lkey, sb.factors)
-
-        # demux: per-query device views into the stacked output (the
-        # padded tail, if any, is discarded)
-        out = []
-        for i in range(sb.k):
-            out.append(DBatch(
-                {n: a[i] for n, a in flight.cols.items()},
-                flight.valid[i],
-                dict(flight.meta["types"]), dict(flight.meta["dicts"]),
-                {n: a[i] for n, a in flight.nulls.items()}))
-        return out
+    sb = flight.sb
+    for attempt in range(plancache.Ladder.ATTEMPTS):
+        if attempt:     # the last call overflowed: one class up
+            flight = launch_fused_batch(sb)
+            if flight is None:
+                return None
+        cols, valid, nulls, join_req = flight.out
+        held = sb.prep.settle(flight.meta, join_req, obs_trace.count)
+        if held:
+            # demux: per-query device views into the stacked output
+            # (the padded tail, if any, is discarded)
+            return [_dbatch(flight.meta,
+                            {n: a[i] for n, a in cols.items()}, valid[i],
+                            {n: a[i] for n, a in nulls.items()})
+                    for i in range(sb.k)]
+        if held is None:
+            return None
+    return None  # overflow never converged
 
 
-def run_fused_batch(info: FragSig, queries: list) -> Optional[list]:  # otblint: sync-boundary
-    """Run K same-signature queries as ONE compiled dispatch.
+def run_fused_batch(info: FragSig, queries: list) -> Optional[list]:
+    """Run K same-signature queries as ONE compiled dispatch: the
+    synchronous composition of the three phases (the pipelined
+    scheduler calls them separately so the finish-phase host sync lands
+    on its drainer thread instead of the dispatch loop).
 
     `queries` is [(snapshot_ts, txid, [literal values])] — one entry
     per query, literal order matching `info.lits`.  Returns a list of
     per-query DBatch results (device views into the stacked program
-    output — materialization happens on the caller's thread, which is
-    what lets the scheduler overlap the next batch's staging with this
-    batch's device compute), or None when the batched path can't serve
-    this group (caller falls back to serial execution).
-
-    This is the synchronous composition of the three pipeline phases
-    (stage → launch → finish); the pipelined scheduler calls them
-    separately so the finish-phase host sync lands on its drainer
-    thread instead of the dispatch loop."""
+    output — materialization happens on the caller's thread), or None
+    when the batched path can't serve this group (caller falls back to
+    serial execution)."""
     sb = stage_fused_batch(info, queries)
     if sb is None:
         return None

@@ -134,7 +134,10 @@ class MeshRunner:
         # runner; _programs is this runner's build registry — the
         # observability surface (did THIS query compile or reuse?)
         self._programs: dict = {}
-        self._ladder: dict = {}
+        # the learned size classes of this runner's plan shapes: join
+        # and aggregate factors, exchange bucket multipliers, gather
+        # classes (four sessions of a CN share one runner: it locks)
+        self._ladder = plancache.Ladder(256)
 
     # ------------------------------------------------------------------
     # plan screening
@@ -176,7 +179,7 @@ class MeshRunner:
             if frag.index == dp.top_fragment:
                 continue
             needs[frag.index] = {
-                n.index for n in self._walk(frag.plan)
+                n.index for n in P.walk(frag.plan)
                 if isinstance(n, ExchangeRef)}
         excluded: set = set()
         changed = True
@@ -203,35 +206,15 @@ class MeshRunner:
         return included
 
     @staticmethod
-    def _walk(node):
-        yield node
-        for attr in ("child", "left", "right"):
-            c = getattr(node, attr, None)
-            if c is not None and hasattr(c, "__dataclass_fields__"):
-                yield from MeshRunner._walk(c)
-        for c in getattr(node, "inputs", None) or []:
-            if hasattr(c, "__dataclass_fields__"):
-                yield from MeshRunner._walk(c)
-
-    def _screen_node(self, node):
-        if not isinstance(node, _ALLOWED):
-            raise MeshUnsupported(type(node).__name__)
-        if isinstance(node, P.HashJoin):
-            if node.kind == "cross":
+    def _screen_node(node):
+        for nd in P.walk(node):
+            if not isinstance(nd, _ALLOWED):
+                raise MeshUnsupported(type(nd).__name__)
+            if isinstance(nd, P.HashJoin) and nd.kind == "cross":
                 raise MeshUnsupported("cross join sizing")
-            self._screen_node(node.left)
-            self._screen_node(node.right)
-            return
-        if isinstance(node, P.SeqScan) and node.table.name.startswith(
-                "otb_"):
-            raise MeshUnsupported("stat view scan")
-        for attr in ("child", "left", "right"):
-            c = getattr(node, attr, None)
-            if isinstance(c, P.PhysNode):
-                self._screen_node(c)
-        for c in getattr(node, "inputs", None) or []:
-            if isinstance(c, P.PhysNode):
-                self._screen_node(c)
+            if isinstance(nd, P.SeqScan) and nd.table.name.startswith(
+                    "otb_"):
+                raise MeshUnsupported("stat view scan")
 
     # ------------------------------------------------------------------
     # staging: per-DN host chunks -> sharded device arrays + union dicts
@@ -697,14 +680,14 @@ class MeshRunner:
         """Execute the DN side of `dp` on the mesh; returns a dict of
         {gather exchange index: DBatch} — every CN-bound exchange output,
         host-reachable."""
-        from .executor import DBatch, ExecContext, Executor
+        from .executor import TRACE_HOST_SYNC, DBatch
 
         included = self._screen(dp)
         tables = set()
         for frag in dp.fragments:
             if frag.index not in included:
                 continue
-            for nd in self._walk(frag.plan):
+            for nd in P.walk(frag.plan):
                 if isinstance(nd, P.SeqScan):
                     tables.add(nd.table.name)
         for t in tables:
@@ -728,58 +711,41 @@ class MeshRunner:
         # gather classes) LEARNED on a previous execution of the same
         # plan shape are remembered, so steady state runs the compiled
         # program exactly once — no overflow replay per query
-        lkey = self._ladder_key(dp, table_names := sorted(staged),
-                                staged, included)
-        remembered = self._ladder.get(lkey)
-        if remembered is not None:
-            factors, mults, gathers = (dict(remembered[0]),
-                                       dict(remembered[1]),
-                                       dict(remembered[2]))
-            for ex in dp.exchanges:
-                if ex.kind == "redistribute":
-                    mults.setdefault(ex.index, 1)
-                elif ex.kind in ("gather", "gather_one"):
-                    gathers.setdefault(ex.index, min(base_pad, 1 << 16))
-        else:
-            mults = {ex.index: 1 for ex in dp.exchanges
-                     if ex.kind == "redistribute"}
-            # per-gather output size classes: traced fragment outputs
-            # are padded to static classes (a join's or a laddered
-            # aggregate's buffer is a quarter of its input), but the rows
-            # that actually cross to the CN are usually few — start
-            # small, compact in-program, grow on overflow (the same
-            # ladder joins, aggregates and redistributes ride)
-            gathers = {ex.index: min(base_pad, 1 << 16)
-                       for ex in dp.exchanges
-                       if ex.kind in ("gather", "gather_one")}
-            factors = {}
-        for _attempt in range(24):
+        skey = self._shape_key(dp, staged, included)
+        lkey = self._ladder_key(skey)
+        factors, mults, gathers = \
+            self._ladder.recall(lkey) or ({}, {}, {})
+        for ex in dp.exchanges:
+            if ex.kind == "redistribute":
+                mults.setdefault(ex.index, 1)
+            elif ex.kind in ("gather", "gather_one"):
+                # per-gather output size classes: traced fragment
+                # outputs are padded to static classes (a join's or a
+                # laddered aggregate's buffer is a quarter of its
+                # input), but the rows that actually cross to the CN
+                # are usually few — start small, compact in-program,
+                # grow on overflow (the same ladder joins, aggregates
+                # and redistributes ride)
+                gathers.setdefault(ex.index, min(base_pad, 1 << 16))
+        for _attempt in range(plancache.Ladder.ATTEMPTS):
             try:
                 out, meta, over_jids, a2a_over, g_over = self._execute(
                     dp, staged, snapshot_ts, txid, params,
                     dict(factors), dict(mults), dict(gathers),
-                    included)
-            except (jax.errors.TracerBoolConversionError,
-                    jax.errors.ConcretizationTypeError,
-                    jax.errors.TracerArrayConversionError) as e:
+                    included, skey)
+            except TRACE_HOST_SYNC as e:
                 raise MeshUnsupported(f"host sync in plan: {e}") from None
-            grew = False
+            # the program reports an overflow as a bit: each class that
+            # overflowed doubles and the statement replays
             for ei in a2a_over:
-                mults[ei] = mults.get(ei, 1) * 2
-                grew = True
+                plancache.Ladder.grow(mults, ei)
             for jid in over_jids:
-                factors[jid] = factors.get(jid, 1) * 2
-                if factors[jid] > 4096:
+                if not plancache.Ladder.grow(factors, jid):
                     raise MeshUnsupported("join size ladder exhausted")
-                grew = True
             for gi in g_over:
-                gathers[gi] *= 2
-                grew = True
-            if not grew:
-                self._ladder[lkey] = (dict(factors), dict(mults),
-                                      dict(gathers))
-                if len(self._ladder) > 256:
-                    self._ladder.pop(next(iter(self._ladder)))
+                plancache.Ladder.grow(gathers, gi)
+            if not (a2a_over or over_jids or g_over):
+                self._ladder.remember(lkey, factors, mults, gathers)
                 result = {}
                 # the gather span is the host side of every CN-bound
                 # exchange output, which _call_program brought down with
@@ -837,25 +803,50 @@ class MeshRunner:
         except MeshUnsupported:
             return False
 
-    def _ladder_key(self, dp, table_names, staged, included):
+    @staticmethod
+    def _shape_key(dp, staged, included) -> tuple:
+        """`(fragments, exchanges, tables)`: what a statement's program
+        key and its ladder key both hold of the plan and the staged
+        tables, computed ONCE a statement (`physical.plan_key` walks
+        every included fragment)."""
+        frags = []
+        for f in dp.fragments:
+            if f.index in included:
+                key = P.plan_key(f.plan, _ALLOWED)
+                if key is None:
+                    raise MeshUnsupported("plan node outside the mesh")
+                frags.append((f.index, key))
+        return (
+            tuple(frags),
+            tuple((ex.index, ex.kind, tuple(ex.keys or ()),
+                   ex.source_fragment,
+                   tuple(getattr(ex, "sort_keys", None) or ()),
+                   getattr(ex, "limit", None))
+                  for ex in dp.exchanges),
+            tuple((t, staged[t].padded,
+                   tuple(sorted((c, len(d.values)) for c, d in
+                         staged[t].view.dicts.items())),
+                   # the staged-array namespace: a null column appearing
+                   # after DML adds a __null input, which must recompile
+                   # (the flat-arg list and in_specs grow with it)
+                   tuple(sorted(staged[t].arrs)),
+                   # quantized codec classes (storage/codec.py): an enc
+                   # family/width/LUT-capacity change alters aux avals,
+                   # so the class token must be key-visible
+                   codec.codec_classes(staged[t].view))
+                  for t in sorted(staged)))
+
+    @staticmethod
+    def _ladder_key(skey):
         """Identity of a plan shape + data scale, independent of the
         ladder values themselves — the key under which learned join
-        factors / bucket multipliers / gather classes persist."""
+        factors / bucket multipliers / gather classes persist.  Of a
+        table it holds the padded class, the staged names and the codec
+        classes: a dictionary's length is the program key's alone."""
+        frags, exchanges, tables = skey
         try:
-            return hash((
-                tuple((f.index, self._plan_key(f.plan))
-                      for f in dp.fragments
-                      if f.index in included),
-                tuple((ex.index, ex.kind, tuple(ex.keys or ()),
-                       ex.source_fragment,
-                       tuple(getattr(ex, "sort_keys", None) or ()),
-                       getattr(ex, "limit", None))
-                      for ex in dp.exchanges),
-                tuple((t, staged[t].padded,
-                       tuple(sorted(staged[t].arrs)),
-                       codec.codec_classes(staged[t].view))
-                      for t in table_names),
-            ))
+            return hash((frags, exchanges,
+                         tuple(e[:2] + e[3:] for e in tables)))
         except TypeError:
             raise MeshUnsupported("unhashable plan content") from None
 
@@ -921,45 +912,10 @@ class MeshRunner:
                      for i, n in enumerate(nnames)}
         return new_cols, s_valid, new_nulls
 
-    @staticmethod
-    def _plan_key(node):
-        t = type(node).__name__
-        if isinstance(node, ExchangeRef):
-            return (t, node.index)
-        if isinstance(node, P.SeqScan):
-            return (t, node.table.name, node.alias, tuple(node.filters),
-                    tuple(node.outputs or ()))
-        if isinstance(node, P.HashJoin):
-            return (t, node.kind, tuple(node.left_keys),
-                    tuple(node.right_keys), tuple(node.residual or ()),
-                    MeshRunner._plan_key(node.left),
-                    MeshRunner._plan_key(node.right))
-        if isinstance(node, P.Filter):
-            return (t, tuple(node.quals),
-                    MeshRunner._plan_key(node.child))
-        if isinstance(node, P.Project):
-            return (t, tuple(node.outputs),
-                    MeshRunner._plan_key(node.child))
-        if isinstance(node, P.Agg):
-            return (t, node.mode, tuple(node.group_keys),
-                    tuple(node.aggs), MeshRunner._plan_key(node.child))
-        if isinstance(node, P.Sort):
-            return (t, tuple((k, bool(d)) for k, d in node.keys),
-                    node.limit, MeshRunner._plan_key(node.child))
-        if isinstance(node, P.Limit):
-            return (t, node.count, node.offset,
-                    MeshRunner._plan_key(node.child))
-        if isinstance(node, P.Window):
-            return (t, tuple(node.calls),
-                    MeshRunner._plan_key(node.child))
-        if isinstance(node, P.Append):
-            return (t, tuple(MeshRunner._plan_key(c)
-                             for c in node.inputs))
-        raise MeshUnsupported(t)
-
     def _execute(self, dp, staged, snapshot_ts, txid, params, factors,
-                 mults, gathers, included):
-        from .executor import ExecContext, Executor, bind_text_params
+                 mults, gathers, included, skey):
+        from .executor import (ExecContext, Executor, bind_text_params,
+                               split_params)
 
         table_names = sorted(staged)
         gather_ex = [ex for ex in dp.exchanges
@@ -979,53 +935,30 @@ class MeshRunner:
             (x for f in dp.fragments if f.index in included
              for x in P.walk_exprs(f.plan)),
             params, {t: staged[t].view for t in table_names}, "mesh")
-        traced_names = tuple(sorted(
-            k for k, (v, _t) in params.items()
-            if isinstance(v, (int, float)) and not isinstance(v, bool)))
+        traced_names, baked = split_params(params)
         # a literal string predicate with more verdicts than a program
         # unrolls arrives as a bitmap over its dictionary's codes, an
         # ARGUMENT like the parameters above: which predicates those are
         # follows from the plan and the dictionaries' lengths, both in
         # the key, and the verdicts are the host's (exec/strtable.py)
         str_tables = self._str_tables(dp, included, staged)
-        baked = {k: params[k] for k in params if k not in traced_names}
+        # nine parts, read by position (plancache._census_classes):
+        # runner, fragments, exchanges, tables, the three ladders'
+        # classes, baked values, traced types; hashable, since
+        # `_ladder_key` hashed the plan's parts and `run` admits scalar
+        # parameters alone
         prog_key = (
-            id(self),
-            tuple((f.index, self._plan_key(f.plan))
-                  for f in dp.fragments
-                  if f.index in included),
-            tuple((ex.index, ex.kind, tuple(ex.keys or ()),
-                   ex.source_fragment,
-                   tuple(getattr(ex, "sort_keys", None) or ()),
-                   getattr(ex, "limit", None))
-                  for ex in dp.exchanges),
-            tuple((t, staged[t].padded,
-                   tuple(sorted((c, len(d.values)) for c, d in
-                         staged[t].view.dicts.items())),
-                   # the staged-array namespace: a null column appearing
-                   # after DML adds a __null input, which must recompile
-                   # (the flat-arg list and in_specs grow with it)
-                   tuple(sorted(staged[t].arrs)),
-                   # quantized codec classes (storage/codec.py): an enc
-                   # family/width/LUT-capacity change alters aux avals,
-                   # so the class token must be key-visible
-                   codec.codec_classes(staged[t].view))
-                  for t in table_names),
+            id(self), *skey,
             tuple(sorted(factors.items())),
             tuple(sorted(mults.items())),
             tuple(sorted(gathers.items())),
             tuple(sorted((k, v) for k, (v, _t) in baked.items())),
             tuple((k, params[k][1]) for k in traced_names),
         )
-        try:
-            hash(prog_key)
-        except TypeError:
-            raise MeshUnsupported("unhashable plan content") from None
-
         has_join = any(
             isinstance(n, P.HashJoin)
             for f in dp.fragments if f.index in included
-            for n in self._walk(f.plan))
+            for n in P.walk(f.plan))
         cached = plancache.MESH.get(prog_key)
         if cached is not None:
             fn, meta = cached
@@ -1180,7 +1113,7 @@ class MeshRunner:
             for f in dp.fragments:
                 if f.index not in included:
                     continue
-                for nd in self._walk(f.plan):
+                for nd in P.walk(f.plan):
                     if isinstance(nd, P.SeqScan):
                         scans[nd.alias] = nd.table.name
                 for x in P.walk_exprs(f.plan):

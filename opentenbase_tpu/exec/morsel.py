@@ -60,8 +60,8 @@ from ..storage import codec
 from ..storage.batch import chunk_class, size_class
 from ..utils import locks, snapcheck
 from . import share as workshare
-from .spill import (_walk_nodes, _clone_replacing, _needed_cols,
-                    _ScanInfo, has_order_sensitive, node_contains,
+from .spill import (_clone_replacing, _ScanInfo,
+                    has_order_sensitive, node_contains,
                     sliced_side_ok, staged_host_columns)
 
 _LOCK = locks.Lock("exec.morsel._LOCK")
@@ -186,10 +186,10 @@ def _prune_scan_outputs(plan):
     the executor's own contract."""
     import copy
     plan = copy.deepcopy(plan)
-    refs = {x.name for nd in _walk_nodes(plan)
+    refs = {x.name for nd in P.walk(plan)
             for x in _node_exprs(nd) if isinstance(x, E.Col)}
     surface = _surface_scan_ids(plan)
-    for nd in _walk_nodes(plan):
+    for nd in P.walk(plan):
         if not isinstance(nd, P.SeqScan) or id(nd) in surface:
             continue
         outs = nd.outputs
@@ -248,7 +248,7 @@ class MorselDriver:
     # -- shape analysis ------------------------------------------------
     def _scan_infos(self, plan) -> Optional[list]:
         infos = []
-        for nd in _walk_nodes(plan):
+        for nd in P.walk(plan):
             if isinstance(nd, P.SeqScan):
                 st = self.stores.get(nd.table.name)
                 if st is None:
@@ -266,11 +266,11 @@ class MorselDriver:
         names = [i.node.table.name for i in infos]
         if len(set(names)) != len(names):
             return None   # self-joins: staging is keyed by table name
-        joins = [nd for nd in _walk_nodes(plan)
+        joins = [nd for nd in P.walk(plan)
                  if isinstance(nd, P.HashJoin)]
         if any(j.kind == "cross" for j in joins):
             return None   # output sized by a host count: spill's BNL
-        aggs = [nd for nd in _walk_nodes(plan) if isinstance(nd, P.Agg)]
+        aggs = [nd for nd in P.walk(plan) if isinstance(nd, P.Agg)]
         if len(aggs) > 1 or any(a.mode not in ("single", "partial")
                                 for a in aggs):
             return None
@@ -280,8 +280,8 @@ class MorselDriver:
 
         # the dominant scan streams; everything else must be resident
         def est(i):
-            needed = (_needed_cols(plan, i.node.alias)
-                      | _needed_cols(plan, i.node.table.name))
+            needed = (P.needed_columns(plan, i.node.alias)
+                      | P.needed_columns(plan, i.node.table.name))
             return _est_staged_bytes(i.rows, len(needed))
         big = max(infos, key=est)
         if big.rows <= self.chunk_rows:
@@ -326,7 +326,7 @@ class MorselDriver:
                 return None, None, False
             return agg, agg, False
         if joins:
-            top = next(nd for nd in _walk_nodes(plan)
+            top = next(nd for nd in P.walk(plan)
                        if isinstance(nd, P.HashJoin))
             if has_order_sensitive(top):
                 return None, None, False
@@ -375,8 +375,8 @@ class MorselDriver:
         if not self.forced:
             from ..storage import bufferpool
             hi = max(_est_staged_bytes(
-                i.rows, len(_needed_cols(plan, i.node.alias)
-                            | _needed_cols(plan, i.node.table.name)))
+                i.rows, len(P.needed_columns(plan, i.node.alias)
+                            | P.needed_columns(plan, i.node.table.name)))
                 for i in infos)
             if hi <= stream_fraction() * bufferpool._budget():
                 return False   # fits comfortably even un-pruned
@@ -403,9 +403,9 @@ class MorselDriver:
         from ..storage.bufferpool import POOL
 
         big = shape.big
-        needed = sorted(_needed_cols(shape.per_plan, big.node.alias)
-                        | _needed_cols(shape.per_plan,
-                                       big.node.table.name))
+        needed = sorted(P.needed_columns(shape.per_plan, big.node.alias)
+                        | P.needed_columns(shape.per_plan,
+                                           big.node.table.name))
         host = staged_host_columns(big.store, needed)
         # codec descriptors for the streamed table, ensured against the
         # FULL host columns BEFORE the fragment program is built: every
@@ -479,8 +479,8 @@ class MorselDriver:
         pins = []
         for info in shape.resident:
             rneed = sorted(
-                _needed_cols(shape.per_plan, info.node.alias)
-                | _needed_cols(shape.per_plan, info.node.table.name))
+                P.needed_columns(shape.per_plan, info.node.alias)
+                | P.needed_columns(shape.per_plan, info.node.table.name))
             arrs, n = self.cache.get(info.store, rneed)
             resident_arrs[info.node.table.name] = arrs
             resident_ns[info.node.table.name] = n

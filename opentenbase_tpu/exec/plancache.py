@@ -4,7 +4,7 @@ Reference analog: CachedPlanSource (utils/cache/plancache.c) generalized
 to the thing that actually costs seconds here — compiled XLA programs.
 The round-5 ladder paid 11-12s of XLA compile against <1s of engine
 time per cold mesh query, and an unmanaged live-executable population
-segfaulted XLA:CPU at a few hundred programs.  Four pieces:
+segfaulted XLA:CPU at a few hundred programs.  Its pieces:
 
 1. ProgramCache — a bounded LRU of live compiled programs, shared by
    the fused tier (exec/fused.py) and the mesh tier (exec/mesh_exec.py),
@@ -33,7 +33,12 @@ segfaulted XLA:CPU at a few hundred programs.  Four pieces:
 4. Telemetry — per-tier hit/miss/compile/compile_ms/eviction counters
    surfaced by the otb_plancache stat view (parallel/statviews.py).
 
-5. Retrace sanitizer — OTB_TRACECHECK=1 records every jit-tier put's
+5. Ladder — the learned size classes of a program shape (join and
+   aggregate factors, exchange multipliers, gather classes): one
+   bounded, locked map a tier and the one rule by which a class grows
+   on overflow.
+
+6. Retrace sanitizer — OTB_TRACECHECK=1 records every jit-tier put's
    quantized class components (join factors, size classes, batch
    classes) into a program census; save_census() merges it into
    analysis/program_census.json, where the retrace-witness lint pass
@@ -90,6 +95,15 @@ def _entry_fns(value):
     bare fn); tolerant of None tombstones."""
     vals = value if isinstance(value, (tuple, list)) else (value,)
     return [v for v in vals if hasattr(v, "clear_cache")]
+
+
+def _release(value) -> None:
+    """Drop the XLA executables a cache value holds."""
+    for fn in _entry_fns(value):
+        try:
+            fn.clear_cache()
+        except Exception:
+            pass
 
 
 class ProgramCache:
@@ -164,11 +178,7 @@ class ProgramCache:
         with _LOCK:
             ent = self._d.get(key)
             if ent is not None:
-                for fn in _entry_fns(ent[1]):
-                    try:
-                        fn.clear_cache()
-                    except Exception:
-                        pass
+                _release(ent[1])
                 ent[1] = value
                 if self.jit and tracecheck_enabled():
                     _census_forget(self, key)
@@ -179,11 +189,7 @@ class ProgramCache:
             if ent is not None and self.jit and tracecheck_enabled():
                 _census_forget(self, key)
         if ent is not None:
-            for fn in _entry_fns(ent[1]):
-                try:
-                    fn.clear_cache()
-                except Exception:
-                    pass
+            _release(ent[1])
 
     # -- accounting -----------------------------------------------------
     def note_compile(self, n: int = 1, ms: float = 0.0):
@@ -234,11 +240,7 @@ class ProgramCache:
         self.evictions += 1
         if self.jit and tracecheck_enabled():
             _census_forget(self, key)
-        for fn in _entry_fns(value):
-            try:
-                fn.clear_cache()
-            except Exception:
-                pass
+        _release(value)
 
 
 def trim_live():
@@ -266,11 +268,70 @@ def trim_live():
             c.evictions += 1
             if tracecheck_enabled():
                 _census_forget(c, k)   # _REGISTRY holds jit caches only
-            for fn in _entry_fns(value):
-                try:
-                    fn.clear_cache()
-                except Exception:
-                    pass
+            _release(value)
+
+
+class Ladder:
+    """The learned size classes of compiled program shapes: ONE bounded,
+    insertion-ordered, locked map from a shape's key (a program's key
+    less its classes) to the class maps a statement of that shape ended
+    on, so that the next one starts there instead of replaying the
+    overflow walk.  Each compiled tier holds one (exec/fused.py one a
+    process: `{slot: factor}` of its traced joins and laddered sorted
+    aggregates; a MeshRunner its own: those factors, the exchanges'
+    bucket multipliers and the gathers' classes) and the growth rule
+    lives here alone: a program reports what overflowed, its caller
+    takes each such slot one step up with `grow` and calls again, at
+    most `ATTEMPTS` times a statement."""
+
+    CAP = 4096        # a factor past this: the ladder is exhausted
+    ATTEMPTS = 24     # program calls a statement may spend climbing
+
+    def __init__(self, max_entries: int):
+        self.max_entries = max_entries
+        self._lock = locks.Lock("exec.plancache.Ladder._lock")
+        self._d: dict = {}            # guarded_by: _lock
+
+    def recall(self, key) -> Optional[tuple]:
+        """Copies of the class maps remembered under `key`, else None."""
+        with self._lock:
+            maps = self._d.get(key)
+        return None if maps is None else tuple(dict(m) for m in maps)
+
+    def remember(self, key, *maps) -> None:
+        """Keep copies of `maps` under `key`; past the bound the oldest
+        key goes, one at a time (a key learned again keeps its place)."""
+        with self._lock:
+            self._d[key] = tuple(dict(m) for m in maps)
+            while len(self._d) > self.max_entries:
+                self._d.pop(next(iter(self._d)))
+
+    def snapshot(self) -> dict:
+        """`{key: class maps}` as of now, oldest first (tests, views)."""
+        with self._lock:
+            return dict(self._d)
+
+    def __len__(self):
+        return len(self._d)
+
+    @classmethod
+    def grow(cls, classes: dict, slot, have=None, need=None) -> bool:
+        """Take `classes[slot]` (1 where absent) one step up, in place.
+        Where the program reported the rows it needed (`need`, against
+        the `have` its class held: the fused tier) the step is the
+        power of two that fits, so ONE retrace and no walk of compiles;
+        where it reported a bit (the mesh tier's psum of overflow
+        flags) the class doubles.  False where it has passed `CAP`: a
+        factor's caller gives up there (an exchange's multiplier and a
+        gather's class are bounded by what their source holds, so their
+        caller does not ask)."""
+        mult = 2
+        if need is not None:
+            mult = 1
+            while have * mult < need:
+                mult *= 2
+        classes[slot] = classes.get(slot, 1) * mult
+        return classes[slot] <= cls.CAP
 
 
 # ---------------------------------------------------------------------------

@@ -40,17 +40,6 @@ from ..storage.batch import next_pow2, stage_padded
 from ..utils.hashing import hash_columns_np, hash_string
 
 
-def _walk_nodes(node):
-    yield node
-    for attr in ("child", "left", "right"):
-        c = getattr(node, attr, None)
-        if isinstance(c, P.PhysNode):
-            yield from _walk_nodes(c)
-    for c in getattr(node, "inputs", None) or []:
-        if isinstance(c, P.PhysNode):
-            yield from _walk_nodes(c)
-
-
 def _clone_replacing(node, target, replacement):
     if node is target:
         return replacement
@@ -60,11 +49,6 @@ def _clone_replacing(node, target, replacement):
         if isinstance(c, P.PhysNode):
             setattr(clone, attr, _clone_replacing(c, target, replacement))
     return clone
-
-
-def _needed_cols(subtree, alias):
-    from .fused import _needed_columns
-    return _needed_columns(subtree, alias)
 
 
 def _host_key_hash(store, key: E.Expr, alias: str) -> Optional[np.ndarray]:
@@ -98,7 +82,7 @@ class _ScanInfo:
 
 # -- shared slice-decomposition predicates (spill + morsel tiers) -------
 def node_contains(node, target) -> bool:
-    return any(nd is target for nd in _walk_nodes(node))
+    return any(nd is target for nd in P.walk(node))
 
 
 def sliced_side_ok(plan, big_nodes, exclude=None) -> bool:
@@ -109,7 +93,7 @@ def sliced_side_ok(plan, big_nodes, exclude=None) -> bool:
     hash keeps matches partition-aligned, so its join semantics survive
     on both sides (reference: the hybrid hash join's nbatch
     partitioning, nodeHash.c)."""
-    for nd in _walk_nodes(plan):
+    for nd in P.walk(plan):
         if not isinstance(nd, P.HashJoin) or nd is exclude:
             continue
         if nd.kind == "full" and any(
@@ -125,7 +109,7 @@ def has_order_sensitive(subtree) -> bool:
     """A Limit or Sort INSIDE the per-pass subtree would re-apply per
     slice/chunk — those plans are not slice-decomposable."""
     return any(isinstance(nd, (P.Limit, P.Sort))
-               for nd in _walk_nodes(subtree))
+               for nd in P.walk(subtree))
 
 
 # version-gate: snap
@@ -166,7 +150,7 @@ class SpillDriver:
     # -- shape analysis ------------------------------------------------
     def _scan_infos(self, plan) -> Optional[list[_ScanInfo]]:
         infos = []
-        for nd in _walk_nodes(plan):
+        for nd in P.walk(plan):
             if isinstance(nd, P.SeqScan):
                 st = self.stores.get(nd.table.name)
                 if st is None:
@@ -193,9 +177,9 @@ class SpillDriver:
         names = [i.node.table.name for i in infos]
         if len(set(names)) != len(names):
             return None   # self-joins: staging is keyed by table name
-        joins = [nd for nd in _walk_nodes(plan)
+        joins = [nd for nd in P.walk(plan)
                  if isinstance(nd, P.HashJoin)]
-        aggs = [nd for nd in _walk_nodes(plan) if isinstance(nd, P.Agg)]
+        aggs = [nd for nd in P.walk(plan) if isinstance(nd, P.Agg)]
         # 'single' aggs slab in partial mode and re-merge in final mode;
         # a 'partial' agg (the DN side of a distributed split) slabs
         # as-is and CONCATENATES -- the CN's final aggregate merges the
@@ -257,8 +241,8 @@ class SpillDriver:
         and sliced per pass."""
         staged = {}
         for info, sel in infos_sel.items():
-            needed = sorted(_needed_cols(subtree, info.node.alias)
-                            | _needed_cols(subtree, info.node.table.name))
+            needed = sorted(P.needed_columns(subtree, info.node.alias)
+                            | P.needed_columns(subtree, info.node.table.name))
             hkey = (id(info.store), info.store.version, tuple(needed))
             host = self._host_cache.get(hkey)
             if host is None:
@@ -349,7 +333,7 @@ class SpillDriver:
                               combined)
 
     def _info_for_side(self, side_plan, infos) -> Optional[_ScanInfo]:
-        scans = [nd for nd in _walk_nodes(side_plan)
+        scans = [nd for nd in P.walk(side_plan)
                  if isinstance(nd, P.SeqScan)]
         if len(scans) != 1:
             return None
@@ -366,7 +350,7 @@ class SpillDriver:
         return sliced_side_ok(plan, big_nodes, exclude)
 
     def _top_join(self, plan, joins):
-        for nd in _walk_nodes(plan):
+        for nd in P.walk(plan):
             if isinstance(nd, P.HashJoin):
                 return nd
         return joins[0]

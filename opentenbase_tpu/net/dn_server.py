@@ -87,6 +87,9 @@ class DnServer:
                                     # chooses/validates codec
                                     # descriptors:
                                     # may-acquire: storage.codec._STATE_LOCK
+                                    # a fused fragment recalls its
+                                    # learned size classes:
+                                    # may-acquire: exec.plancache.Ladder._lock
                                     # execution parks at named wait
                                     # points (gts-grant, lockmgr, ...)
                                     # whose enter/exit touch the wait

@@ -49,6 +49,10 @@ class ExchangeRef(P.PhysNode):
     def title(self):
         return f"ExchangeRef #{self.index}"
 
+    def plan_key_leaf(self) -> tuple:
+        """What `physical.plan_key` holds of this leaf: which exchange."""
+        return (self.index,)
+
 
 @dataclasses.dataclass
 class BatchSource(P.PhysNode):

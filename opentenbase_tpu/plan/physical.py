@@ -254,6 +254,15 @@ class Result(PhysNode):
     outputs: list[tuple[str, E.Expr]] = dataclasses.field(default_factory=list)
 
 
+def walk(node: PhysNode):
+    """`node` and every plan node below it, a parent before its
+    children, those in their own order."""
+    yield node
+    for c in node.children():
+        if isinstance(c, PhysNode):
+            yield from walk(c)
+
+
 def walk_exprs(node: PhysNode):
     """Every expression node a plan holds: scan filters and outputs,
     quals, group keys and aggregates, sort keys, join keys and
@@ -281,6 +290,57 @@ def walk_exprs(node: PhysNode):
     for c in node.children():
         if isinstance(c, PhysNode):
             yield from walk_exprs(c)
+
+
+def plan_key(node, kinds) -> Optional[tuple]:
+    """The structural key of a physical subtree: the ONE spelling of
+    what names a plan inside a compiled program's key, for every tier
+    that compiles plans (exec/fused.py's fragments, exec/mesh_exec.py's
+    shard_map programs).  `kinds` is the tuple of node classes the
+    asking tier runs; a node outside it, here or below, makes the whole
+    key None.  A field that shapes what a node computes belongs here and
+    nowhere else: a key that misses one serves a cached program for
+    another plan.  A leaf this module does not define (distribute's
+    ExchangeRef) answers through its own `plan_key_leaf()`."""
+    if not isinstance(node, kinds):
+        return None
+    t = type(node).__name__
+    if isinstance(node, SeqScan):
+        return (t, node.table.name, node.alias, tuple(node.filters),
+                tuple(node.outputs or ()))
+    if isinstance(node, Append):
+        below = tuple(plan_key(c, kinds) for c in node.inputs)
+        return None if None in below else (t, below)
+    if isinstance(node, Filter):
+        own = (tuple(node.quals),)
+    elif isinstance(node, Project):
+        own = (tuple(node.outputs),)
+    elif isinstance(node, Agg):
+        own = (node.mode, tuple(node.group_keys), tuple(node.aggs))
+    elif isinstance(node, Sort):
+        own = (tuple((k, bool(d)) for k, d in node.keys), node.limit)
+    elif isinstance(node, Limit):
+        own = (node.count, node.offset)
+    elif isinstance(node, HashJoin):
+        own = (node.kind, tuple(node.left_keys), tuple(node.right_keys),
+               tuple(node.residual or ()))
+    elif isinstance(node, Window):
+        own = (tuple(node.calls),)
+    else:
+        leaf = getattr(node, "plan_key_leaf", None)
+        return None if leaf is None else (t, *leaf())
+    below = tuple(plan_key(c, kinds) for c in node.children())
+    return None if None in below else (t, *own, *below)
+
+
+def needed_columns(node: PhysNode, alias: str) -> set[str]:
+    """The plain names of the columns `node`, and all below it, read of
+    the scan called `alias`: what a tier stages of that table."""
+    need = set()
+    for x in walk_exprs(node):
+        if isinstance(x, E.Col) and x.name.startswith(alias + "."):
+            need.add(x.name.split(".", 1)[1])
+    return need
 
 
 def explain(node: PhysNode, indent: int = 0, out: Optional[list] = None,

@@ -12,7 +12,7 @@ LUT gather that XLA fuses into the consuming kernel, so most payload
 columns never materialize decoded.
 
 Three codec families, chosen per column at stage time from the actual
-values, persisted like the join ladder (exec/fused.py _JOIN_LADDER):
+values, persisted like the join ladder (exec/plancache.py Ladder):
 
 - pack (uint8/16/32): direct downcast, proven 0 <= v <= 2^w - 1.
   Zero-padding decodes to 0 exactly (matches raw staging).

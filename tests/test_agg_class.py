@@ -143,7 +143,7 @@ def test_fused_tier_answers_after_one_retrace_and_remembers(monkeypatch):
     monkeypatch.undo()
     assert eager == expected_many_groups(cols, 0)
 
-    ladder0 = dict(fused._JOIN_LADDER)
+    ladder0 = fused._LADDER.snapshot()
     misses = plancache.FUSED.misses
     assert s.query(MANY_GROUPS.format(v=0)) == eager
     st = s.last_query_stats()
@@ -153,8 +153,8 @@ def test_fused_tier_answers_after_one_retrace_and_remembers(monkeypatch):
     assert st["retraces"] == 1 and st["sorted_aggs"] == 1
     assert plancache.FUSED.misses - misses == 2
     assert st["sorted_agg_groups"] == st["sorted_agg_lanes"] >= ROWS
-    (learned,) = [f for k, f in fused._JOIN_LADDER.items()
-                  if k not in ladder0]
+    ((learned,),) = [f for k, f in fused._LADDER.snapshot().items()
+                     if k not in ladder0]
     assert learned == {("__fused", 0): 4}
     # another literal, the same programs: no second retrace
     misses = plancache.FUSED.misses
@@ -202,7 +202,7 @@ def test_mesh_tier_answers_after_at_most_two_retraces_and_remembers(
         # doubles its factor twice, a quarter of the rows to all of them
         assert st["retraces"] == 2
         factors = [f for f, _m, _g in mesh_runner_for(s.cluster)
-                   ._ladder.values() if f]
+                   ._ladder.snapshot().values() if f]
         assert {(0, 0): 4} in factors
     else:
         assert 1 <= st["retraces"] <= 4     # the exchange's classes too

@@ -23,9 +23,10 @@ from benchmarks.lib.traffic import Mix, Request
 from opentenbase_tpu.catalog import types as T
 from opentenbase_tpu.exec import plancache
 from opentenbase_tpu.exec.dist_session import ClusterSession
-from opentenbase_tpu.exec.mesh_exec import MeshRunner, mesh_runner_for
+from opentenbase_tpu.exec.mesh_exec import _ALLOWED, mesh_runner_for
 from opentenbase_tpu.obs import trace as obs_trace
 from opentenbase_tpu.parallel.cluster import Cluster
+from opentenbase_tpu.plan.physical import plan_key
 from opentenbase_tpu.sql.parser import parse_sql
 
 SEED = 20260929
@@ -278,14 +279,14 @@ def test_stats_and_explain_report_the_binding(edge):
 
 def test_no_key_holds_a_lifted_value(edge):
     """Two literals, one template, one plan key, one ladder entry, one
-    program: `_plan_key` (which `prog_key` and `_ladder_key` are built
+    program: `plan_key` (which `prog_key` and `_ladder_key` are built
     from) shows the parameter's name and no value."""
     sqls = [f"select a, count(*) from ft where b = '{v}' and d < date "
             f"'{d}' + interval '{n}' month group by a order by a"
             for v, d, n in (("x", "1996-01-31", 13), ("z", "1996-05-05", 2))]
     preps = [edge._autoprep_template(parse_sql(q)[0])[0] for q in sqls]
     assert preps[0] is preps[1] and preps[0].mode == "plan"
-    pkeys = repr([MeshRunner._plan_key(f.plan)
+    pkeys = repr([plan_key(f.plan, _ALLOWED)
                   for f in preps[0].dp.fragments if f.location != "cn"])
     assert "__bindparam1" in pkeys and "__bindparam2" in pkeys
     for value in ("'x'", "'z'", str(T.date_to_days("1997-02-28")),

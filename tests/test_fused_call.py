@@ -369,6 +369,119 @@ def test_refused_mask_bakes_and_answers(node, calls, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# a refused shape is remembered under the key its readers probe, whoever
+# the caller (ISSUE 46: the morsel and the batch path wrote a key no one
+# read, so every later statement traced masked, failed and fell back)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture()
+def refusing(monkeypatch):
+    """Arms `_build_program` so that every literal-MASKED program
+    refuses as a masked literal feeding a host branch does, and counts
+    the builds by kind; the mask ledger is put back afterwards."""
+    built = {"masked": 0, "baked": 0}
+    orig = fused._build_program
+
+    def refuses(*_args):
+        return jax.jit(lambda x: 1 if x > 0 else 0)(np.int64(1))
+
+    def build(ctx, plan, baked, traced, lits, factors, batch=False):
+        built["masked" if lits else "baked"] += 1
+        if lits:
+            return refuses, {}
+        return orig(ctx, plan, baked, traced, lits, factors, batch)
+
+    def arm():
+        monkeypatch.setattr(fused, "_build_program", build)
+        return built
+
+    saved = dict(fused._MASK_REFUSED)
+    yield arm
+    with fused._STATE_LOCK:
+        fused._MASK_REFUSED.clear()
+        fused._MASK_REFUSED.update(saved)
+
+
+def _big_table():
+    n = LocalNode()
+    s = Session(n)
+    s.execute("create table f (k bigint, q integer, v decimal(8,2))")
+    ks = np.arange(30000) % 5000
+    s._insert_rows(n.catalog.table("f"), n.stores["f"],
+                   {"k": ks, "q": (ks % 50).astype(np.int32),
+                    "v": (ks % 100).astype(float)}, 30000)
+    return n, s
+
+
+def _refused_serial(refusing):
+    sql = "select count(*), sum(v) from f where q < {} and k >= 100"
+    # (another node's programs: this one's cache must be cold)
+    want = [_big_table()[1].query(sql.format(q)) for q in (25, 26)]
+    n, s = _big_table()
+    built = refusing()
+    assert s.query(sql.format(25)) == want[0]
+    first = dict(built)
+    assert s.query(sql.format(26)) == want[1]
+    return first, built
+
+
+def _refused_morsel(refusing):
+    n, s = _big_table()
+    sql = "select count(*), sum(v) from f where q < {} and k >= 100"
+    want = [s.query(sql.format(q)) for q in (25, 26)]
+    s.execute("set morsel = on")
+    s.execute("set morsel_chunk_rows = 4096")
+    built = refusing()
+    try:
+        assert s.query(sql.format(25)) == want[0]
+        first = dict(built)
+        assert s.query(sql.format(26)) == want[1]
+    finally:
+        s.execute("set morsel = auto")
+    return first, built
+
+
+def _refused_batch(refusing):
+    from opentenbase_tpu.exec.executor import ExecContext
+    from opentenbase_tpu.sql.parser import parse_sql
+    sql = "select count(*), sum(v) from f where q < {} and k >= 100"
+    want = _big_table()[1].query(sql.format(26))
+    n, s = _big_table()
+    ctx = ExecContext(n.stores, 0, 0, n.cache)
+
+    def classify(q):
+        return fused.batch_signature(
+            ctx, s._plan_select(parse_sql(sql.format(q))[0]).plan)
+
+    built = refusing()
+    info = classify(25)
+    queries = [(1 << 60, 0, [v for _n, v, _t in info.lits])] * 2
+    # the masked batch program refuses: the group goes serial
+    assert fused.run_fused_batch(info, queries) is None
+    first = dict(built)
+    # the NEXT statement of the shape is not batchable at once, and its
+    # serial run bakes at once
+    assert classify(26) is None
+    assert fused.stage_fused_batch(info, queries) is None
+    assert s.query(sql.format(26)) == want
+    return first, built
+
+
+@pytest.mark.parametrize("caller", [_refused_serial, _refused_morsel,
+                                    _refused_batch],
+                         ids=["serial", "morsel_chunk", "coalesced_batch"])
+def test_a_refused_mask_is_remembered_for_the_next_statement(
+        caller, refusing):
+    first, built = caller(refusing)
+    # the first statement traced masked ONCE and was refused ...
+    assert first["masked"] == 1, first
+    # ... and the next one of that shape, another literal, probed
+    # _MASK_REFUSED and built baked at once: no second masked trace
+    assert built["masked"] == 1, built
+    assert built["baked"] > first["baked"]
+
+
+# ---------------------------------------------------------------------------
 # FragmentProgram.run (morsels) and stage_fused_batch (the scheduler)
 # ---------------------------------------------------------------------------
 

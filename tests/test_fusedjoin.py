@@ -127,11 +127,11 @@ class TestFusedJoinFragment:
         s._insert_rows(node.catalog.table("pb"), node.stores["pb"],
                        {"k": np.ones(n, np.int64),
                         "w": np.arange(n)}, n)
-        lad0 = dict(fused._JOIN_LADDER)
+        lad0 = fused._LADDER.snapshot()
         rows = s.query("select count(*) as c from pa, pb "
                        "where pa.k = pb.k")
         assert rows == [(n * n,)]
-        learned = [v for k, v in fused._JOIN_LADDER.items()
+        learned = [v for k, (v,) in fused._LADDER.snapshot().items()
                    if k not in lad0]
         assert learned and any(f > 1 for d in learned
                                for f in d.values()), \
